@@ -135,7 +135,12 @@ from .io import dump_forest, dump_uniform, load_checkpoint, save_checkpoint
 from .cache import enable_compilation_cache
 
 
-def main(argv=None) -> int:
+def main(argv=None, *, sim_out=None) -> int:
+    """Run one case from ``argv`` (default ``sys.argv[1:]``); returns
+    the process exit code. ``sim_out``: an in-process caller's list
+    that receives the driver object once it is built, so a smoke
+    (chip_smoke.py) can inspect the final state and its placement
+    without a disk round trip — not a command-line feature."""
     enable_compilation_cache()
     argv = sys.argv[1:] if argv is None else argv
     p = CommandlineParser(argv)
@@ -290,6 +295,8 @@ def main(argv=None) -> int:
         else:
             from .amr import AMRSim
             sim = AMRSim(cfg)
+    if sim_out is not None:
+        sim_out.append(sim)
     if p.has("restart"):
         load_checkpoint(p("restart").asString(), sim)
     if p.has("profile"):
@@ -395,11 +402,19 @@ def main(argv=None) -> int:
             event_log=log,
             clients_dir=os.path.join(outdir, "clients"),
             clients_rotate_mb=rotate_mb, latency=serving_lat)
-        # the session ladder: Taylor-Green at geometrically decaying
-        # amplitudes (per-session umax -> per-session dt) with horizons
-        # staggered across [tend/2, tend] so retirements interleave
-        # with admissions (real churn, not one synchronized wave)
-        ens = taylor_green_fleet(sim.grid, serve_n)
+        # the session ladder: horizons staggered across [tend/2, tend]
+        # so retirements interleave with admissions (real churn, not
+        # one synchronized wave). A -case pool serves the CASE's own
+        # initial states (turb2d: seed + slot, so sessions differ; the
+        # free-slip Taylor-Green below is discontinuous across a
+        # periodic wrap — served into a periodic pool at 512^2 it went
+        # non-finite inside ten steps); plain flags keep Taylor-Green
+        # at geometrically decaying amplitudes (per-session umax ->
+        # per-session dt)
+        if case_name is not None:
+            ens = make_sim(case_name, **{**kw, "members": serve_n}).state
+        else:
+            ens = taylor_green_fleet(sim.grid, serve_n)
         for i in range(serve_n):
             t_end = cfg.end_time * (0.5 + 0.5 * (i + 1) / serve_n)
             server.submit(FleetRequest(

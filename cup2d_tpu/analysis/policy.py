@@ -69,9 +69,6 @@ ENV_LATCH_SITES = {
     # windowed device tracing: latched once by the CLI before the run
     # loop (a mid-run mutation must not re-arm a finished window)
     ("profiling.py", "TraceWindow.from_env"): {"CUP2D_TRACE"},
-    # enable-once process knobs (cache paths, not numerics gates)
-    ("cache.py", "enable_compilation_cache"): {"CUP2D_CACHE"},
-    ("native/__init__.py", "_load"): {"CUP2D_NATIVE_CACHE"},
     # flight-recorder span-ring latch (ISSUE 18): read once at
     # construction; the installed recorder stores spans_on/max_spans,
     # so a mid-run env mutation can never flip the span instrument of

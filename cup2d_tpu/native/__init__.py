@@ -3,10 +3,13 @@
 The reference implements its regrid bookkeeping in C++ inside adapt()
 (main.cpp:4717-4861); `amr_host.c` is this build's native equivalent.
 No pybind11 exists in the image, so the shared object is compiled
-lazily with the system compiler into a content-hashed cache path and
-bound with ctypes; any failure (no compiler, sandboxed tmp, exotic
-platform) degrades silently to the pure-Python implementations in
-amr.py, which are semantically identical (tests assert equality).
+lazily with the system compiler into a content-hashed file under the
+in-checkout cache root (cache.CACHE_ROOT) and bound with ctypes; any
+failure (no compiler, read-only checkout, exotic platform) degrades to
+the pure-Python implementations in amr.py, which are semantically
+identical (tests assert equality). The degrade is quiet for library
+users; ``available()`` says which path is live, and chip_smoke.py
+fails when it is not the native one.
 
 Measured honestly: at 2.7k blocks the Python sweep already costs only
 ~7 ms, so the native path wins ~1.2x there (marshalling-bound); the
@@ -23,6 +26,8 @@ import os
 import subprocess
 
 import numpy as np
+
+from ..cache import CACHE_ROOT
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "amr_host.c")
@@ -44,9 +49,7 @@ def _load():
         with open(_SRC, "rb") as f:
             src = f.read()
         key = hashlib.sha256(src).hexdigest()[:16]
-        cache = os.environ.get(
-            "CUP2D_NATIVE_CACHE",
-            os.path.expanduser("~/.cache/cup2d_tpu_native"))
+        cache = os.path.join(CACHE_ROOT, "native")
         os.makedirs(cache, exist_ok=True)
         so = os.path.join(cache, f"amr_host_{key}.so")
         if not os.path.exists(so):

@@ -74,9 +74,9 @@ def _weno5_weights(b1, b2, b3, g1, g2, g3):
     # measured objection (f32 blow-up at b ~ 2e9) that kept round 2 on
     # the ratio form. The weights are scale-invariant in b, so this is
     # the same algebra, just evaluated at a safe scale. Advection is
-    # VPU-divide-bound at 8192^2 (10x above the HBM roofline,
-    # BASELINE.md r3 trace), so trading 2 divides for ~8 multiplies is
-    # the single biggest lever on the step.
+    # arithmetic- (divide-) bound, far above the HBM roofline, so
+    # trading 2 divides for ~8 multiplies is the biggest lever on the
+    # step.
     #
     # Degenerate tail: if b_max/b_min exceeds ~1e19 every cross
     # product underflows to 0 (TPU flushes denormals); the 0/0 is
@@ -271,13 +271,15 @@ def _zshift(p: jnp.ndarray, dy: int, dx: int,
 
     Default form: pad(0)+slice — XLA folds the resulting negative-pad
     into the consumer fusion (fastest single-device form, measured
-    3.8 vs 5.8 ms for the 8192^2 Laplacian against the edge-pad
-    original). This image's GSPMD partitioner MISCOMPILES that
-    negative-pad pattern when the sliced axis is sharded (compositions
-    return garbage at small shard widths — caught by the sharded-
-    equality test); ``spmd_safe=True`` switches to slice-then-pad,
-    which the partitioner handles exactly (to 1 ulp) at a ~2x cost the
-    sharded paths accept."""
+    against the edge-pad original). The SPMD partitioner MISCOMPILES
+    that negative-pad pattern when the sliced axis is sharded
+    (compositions return garbage at small shard widths — caught by
+    tests/test_poisson.py::test_mg_solve_sharded_matches_single_device;
+    re-tested on jax 0.9.0: a single Laplacian partitions correctly,
+    a whole jitted V-cycle of them is off by O(1));
+    ``spmd_safe=True`` switches to slice-then-pad, which the
+    partitioner handles exactly (to 1 ulp) at a cost the sharded paths
+    accept."""
     ny, nx = p.shape[-2], p.shape[-1]
     if spmd_safe:
         ys = slice(max(dy, 0), ny + min(dy, 0))

@@ -142,7 +142,6 @@ def init_distributed(coordinator_address: Optional[str] = None,
     and the elastic re-init path (:func:`reinit_distributed`) takes its
     OWN budget rather than inheriting this first-launch one.
     """
-    from ..resilience import note_distributed_initialized
     if _dist_initialized():
         rank = jax.process_index()
     else:
@@ -161,7 +160,6 @@ def init_distributed(coordinator_address: Optional[str] = None,
                 num_processes=num_processes,
                 process_id=process_id),
             attempts=connect_attempts, backoff=connect_backoff)
-        note_distributed_initialized()   # version-safe probe latch
         rank = jax.process_index()
     if expected_processes and jax.process_count() != expected_processes:
         raise RuntimeError(
@@ -194,7 +192,7 @@ def reinit_distributed(coordinator_address: str,
     Exercised by the slow-marked 2-process drill
     (tests/_multihost_worker.py); environment-broken in this container
     like the rest of the multi-process harness (ROADMAP)."""
-    from ..resilience import note_distributed_initialized, record_event
+    from ..resilience import record_event
     if _dist_initialized():
         try:
             jax.distributed.shutdown()
@@ -211,7 +209,6 @@ def reinit_distributed(coordinator_address: str,
             num_processes=num_processes,
             process_id=process_id),
         attempts=connect_attempts, backoff=connect_backoff)
-    note_distributed_initialized()
     record_event(event="reinit_distributed",
                  num_processes=num_processes, process_id=process_id)
     return jax.process_index()

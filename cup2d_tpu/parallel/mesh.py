@@ -165,10 +165,7 @@ class ShardedUniformSim(UniformSim):
 # identity to realign the mirror.
 # ---------------------------------------------------------------------------
 
-try:                                   # stable API (jax >= 0.5)
-    from jax import shard_map as _shard_map
-except ImportError:                    # this image's 0.4.x line
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 # executable cache: one compiled shift per (mesh, host count, rank) —
 # the capture path runs per snapshot, so the jit must be reused, never

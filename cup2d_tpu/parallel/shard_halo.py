@@ -58,10 +58,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..halo import HaloTables, _bucket, _paint_regions, filter_face_rows
 
-try:                                   # stable API (jax >= 0.5)
-    from jax import shard_map as _shard_map
-except ImportError:                    # this image's 0.4.x line
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 class ShardTables(NamedTuple):
@@ -601,7 +598,7 @@ def overlap_jacobi_sweeps(e: jnp.ndarray, r: jnp.ndarray,
 
 def _overlap_jacobi_sweeps_strip(e: jnp.ndarray, r: jnp.ndarray,
                                  omega: float, n: int,
-                                 mesh: Mesh) -> jnp.ndarray:
+                                 mesh: Mesh, interpret=None) -> jnp.ndarray:
     """Strip-tier body of ``overlap_jacobi_sweeps``: per sweep, issue
     the two edge-column ppermutes FIRST, then dispatch the fused halo
     strip kernel over the local slab (one read of (e, r), one write per
@@ -612,14 +609,15 @@ def _overlap_jacobi_sweeps_strip(e: jnp.ndarray, r: jnp.ndarray,
     tiers agree to reordering roundoff."""
     from ..ops import pallas_kernels as pk
     D = mesh.devices.size
-    interpret = not pk._on_accel()
+    if interpret is None:
+        interpret = pk._interpret_default()
     pad_w = 2 * pk._GX - 2
 
-    # check_rep=False: shard_map has no replication rule for
+    # check_vma=False: shard_map has no replication rule for
     # pallas_call (the fused_advect_heun_sharded precedent)
     @partial(_shard_map, mesh=mesh,
              in_specs=(P(None, "x"),) * 2, out_specs=P(None, "x"),
-             check_rep=False)
+             check_vma=False)
     def run(e_loc, r_loc):
         idx = jax.lax.axis_index("x")
         i32 = jnp.int32
@@ -693,7 +691,7 @@ def fused_advect_heun_sharded(vel, h, nu, dt, mesh: Mesh, *, bc=None,
     facs = jnp.stack([-dtv * hh, nu * dtv, dtv], axis=-1)   # [L, 3] f32
     ih2 = 1.0 / (hh * hh)
     if interpret is None:
-        interpret = not pk._on_accel()
+        interpret = pk._interpret_default()
     D = int(mesh.devices.size)
     nx = v.shape[-1]
     if nx % D:
@@ -704,11 +702,11 @@ def fused_advect_heun_sharded(vel, h, nu, dt, mesh: Mesh, *, bc=None,
     g = pk._G
     pad_w = 2 * pk._GX - 2 * g   # halo operand lane-padded to 128
 
-    # check_rep=False: shard_map has no replication rule for
+    # check_vma=False: shard_map has no replication rule for
     # pallas_call; every output is explicitly sharded on "x" anyway
     @partial(_shard_map, mesh=mesh,
              in_specs=(P(None, None, None, "x"), P(None, None)),
-             out_specs=P(None, None, None, "x"), check_rep=False)
+             out_specs=P(None, None, None, "x"), check_vma=False)
     def run(vb, facsb):
         idx = jax.lax.axis_index("x")
         i32 = jnp.int32
@@ -900,11 +898,11 @@ def overlap_block_jacobi_sweeps(e: jnp.ndarray, r: jnp.ndarray,
     if tier != "xla":
         from ..ops import pallas_kernels as pk
         use_fused = pk.block_update_supported(e.dtype)
-        interpret = not pk._on_accel()
+        interpret = pk._interpret_default()
 
     @partial(_shard_map, mesh=t.mesh,
              in_specs=(P("x"),) * 10 + (P(),) * 6, out_specs=P("x"),
-             check_rep=not use_fused)
+             check_vma=not use_fused)
     def run(e0, r_loc, pack, nba, nbb, ms, mc, mf, mw, par,
             p_inv_r, wc0, wc1, mcl, mfr, d2own):
         pack = tuple(p[0] for p in pack)

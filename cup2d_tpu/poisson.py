@@ -104,7 +104,12 @@ class MultigridPreconditioner:
         self.nu1 = nu1
         self.nu2 = nu2
         self.omega = omega
-        self.spmd_safe = spmd_safe
+        # a mesh-aware hierarchy always hands x-split operands to the
+        # partitioner below its overlapped levels, so it carries the
+        # fenced shift form itself rather than trusting the caller to
+        # pass both (re-tested on jax 0.9.0: the fast pad+slice form
+        # inside a partitioned cycle still returns garbage)
+        self.spmd_safe = spmd_safe or mesh is not None
         # periodic (px, py) — bc.periodic_axes (ISSUE 20): the cycle's
         # operator uses wrap (roll) shifts along periodic axes at EVERY
         # level (periodicity persists under 2x coarsening) and the
@@ -171,7 +176,7 @@ class MultigridPreconditioner:
         # while mg_solve's outer loop keeps the f32 true residual:
         # iterative refinement, so the bf16 legs cannot floor the
         # achievable residual the way a fully-bf16 solver does
-        # (BASELINE: ~2e-4 rel floor). Takes precedence over
+        # (~2e-4 rel floor). Takes precedence over
         # cycle_dtype when set.
         self.leg_dtype = leg_dtype
         self.dtype = leg_dtype or cycle_dtype or (

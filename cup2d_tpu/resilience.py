@@ -131,39 +131,14 @@ from . import tracing
 
 
 # ---------------------------------------------------------------------------
-# version-safe distributed-runtime probe (no backend touch, no private API)
+# distributed-runtime probe (no backend touch, no private API)
 # ---------------------------------------------------------------------------
 
-# latch set by parallel.launch.init_distributed after a successful
-# bring-up — the fallback evidence on jax builds whose public
-# `jax.distributed.is_initialized` accessor does not exist yet (the
-# image's 0.4.x line). The former fallback read
-# `jax._src.distributed.global_state.client`, a private attribute that
-# moves between versions; this latch is version-proof and still never
-# touches the XLA backend (a backend probe would make a later
-# initialize() impossible). Library users on old jax who bypass
-# `launch.init_distributed` and call `jax.distributed.initialize`
-# directly should call :func:`note_distributed_initialized` too.
-_DIST_NOTED = False
-
-
-def note_distributed_initialized() -> None:
-    """Record that the jax distributed runtime is up (called by
-    ``parallel.launch.init_distributed``; see :func:`dist_initialized`)."""
-    global _DIST_NOTED
-    _DIST_NOTED = True
-
-
 def dist_initialized() -> bool:
-    """True when the jax distributed runtime is initialized — the
-    public ``jax.distributed.is_initialized`` accessor where the build
-    has it, else the ``init_distributed`` latch above. Never probes the
-    backend (safe to call before a later ``initialize()``)."""
+    """True when the jax distributed runtime is initialized. Never
+    probes the backend (safe to call before a later ``initialize()``)."""
     import jax
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    return _DIST_NOTED
+    return bool(jax.distributed.is_initialized())
 
 
 # ---------------------------------------------------------------------------

@@ -137,17 +137,17 @@ class UniformGrid:
     projection-correction chain for the fused Pallas megakernel tier
     (ops/pallas_kernels.fused_advect_heun): one HBM read, one write per
     RK substage. CUP2D_PREC=bf16 additionally stores the advection
-    operands bf16 (f32 accumulation). On non-TPU hosts the tier runs
-    in Pallas interpret mode — validation, not speed. XLA remains the
-    default tier."""
+    operands bf16 (f32 accumulation). On a CPU run the tier is in
+    Pallas interpret mode — validation, not speed; on a TPU it is
+    always compiled. XLA remains the default tier."""
 
     def __init__(self, cfg: SimConfig, level: Optional[int] = None,
                  use_pallas: Optional[bool] = None,
                  spmd_safe: bool = False,
                  bc: Optional[BCTable] = None):
         # spmd_safe: the fused-BC stencil forms have a fast pad+slice
-        # variant this image's GSPMD partitioner miscompiles on sharded
-        # axes (see ops/stencil._zshift); sharded sims set True
+        # variant the SPMD partitioner miscompiles on sharded axes
+        # (see ops/stencil._zshift); sharded sims set True
         self.spmd_safe = spmd_safe
         self.cfg = cfg
         # per-face boundary-condition table (bc.py, ISSUE 12): the
@@ -193,9 +193,13 @@ class UniformGrid:
                 raise ValueError(
                     f"CUP2D_PREC=bf16 unsupported for this grid "
                     f"({cfg.dtype} {ny}x{nx}): the bf16 tier needs f32 "
-                    "state and sublane-aligned strips (ny % 16 == 0)")
-            # f32 shape/dtype misses keep the historical silent-XLA
-            # fallback (the tier is an optimization, not a semantic)
+                    "state, sublane-aligned strips (ny % 16 == 0) and "
+                    "a row inside the kernel's VMEM budget "
+                    "(pallas_kernels.fused_tier_supported)")
+            # an f32 shape/dtype miss runs the XLA tier instead (the
+            # tier is an optimization, not a semantic) — visibly: the
+            # stamped kernel_tier says "xla", and chip_smoke.py asserts
+            # the stamp equals the tier it asked for
         self._kernel_tier = tier
         self.use_pallas = tier != "xla"   # back-compat bool alias
         # device mesh of the sharded x-split (attach_mesh): routes the

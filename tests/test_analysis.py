@@ -66,12 +66,12 @@ def test_env_latch_clean_twin_passes():
 def test_env_latch_sanctioned_site_passes():
     # the same read of a policy-listed var at its (file, scope) latch
     src = ENV_BAD.replace("def refresh(self):",
-                          "def enable_compilation_cache():") \
-        .replace("CUP2D_POIS", "CUP2D_CACHE")
+                          "def _exchange_mode():") \
+        .replace("CUP2D_POIS", "CUP2D_SHARD_EXCHANGE")
     # note: finalize will flag the OTHER policy vars as stale for
-    # cache.py; restrict to the read check by asserting no finding on
-    # the read's line
-    fs = _findings({"cache.py": src}, only=["env-latch"])
+    # this file; restrict to the read check by asserting no finding
+    # on the read's line
+    fs = _findings({"parallel/forest_mesh.py": src}, only=["env-latch"])
     assert not [f for f in fs if "outside the sanctioned" in f.message]
 
 

@@ -1,0 +1,164 @@
+"""The Pallas kernels, compiled for a described TPU v5e at real widths.
+
+Interpret mode (every other kernel test in this suite) cannot see what
+the chip's compiler refuses: a slice off the (8, 128) tiling, more
+scoped VMEM than a kernel may use, a kernel that cannot be partitioned.
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED, not attached (``jax.experimental.topologies``), so each case
+below lowers one kernel wrapper with ``interpret=False`` at the width
+chip_smoke.py drives it and asserts the Mosaic custom call is in the
+executable. Nothing runs: these say nothing about results or speed —
+run-time parity lives in chip_smoke.py phase 5.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file), everything built from it is built in
+fixtures/tests, and the persistent compilation cache is off around
+them (an executable for an unattached chip is written but can never be
+read back). ONE file on purpose: a second file could land on another
+worker, whose fixture would then skip every case in silence.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from cup2d_tpu.cases import cavity_table
+from cup2d_tpu.ops import pallas_kernels as pk
+from cup2d_tpu.parallel import shard_halo as sh
+
+N = 8192           # bench's primary uniform width
+NB = 2048          # canonical levelStart-5 block bucket (2 x 32 x 32)
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices), ("x",))
+
+
+def _compiles_to_mosaic(fn, *args):
+    # Mosaic has no f64: the kernels are an f32 contract, compiled the
+    # way the chip runs them (the suite's x64 default is a CPU
+    # validation setting)
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _advect(bf16, bc):
+    def fn(v, dt):
+        return pk.fused_advect_heun(v, 1.0 / N, 1e-3, dt, bc=bc,
+                                    bf16=bf16, interpret=False)
+    return fn, [((2, N, N), F32), ((), F32)]
+
+
+def _correction():
+    def fn(x, p, v, mx, mp, pf):
+        return pk.fused_correction(x, p, v, mx, mp, pf, 1.0,
+                                   interpret=False)
+    return fn, [((1, N, N), F32), ((1, N, N), F32), ((1, 2, N, N), F32),
+                ((1,), F32), ((1,), F32), ((1,), F32)]
+
+
+def _jacobi(dtype):
+    def fn(e, r):
+        return pk.fused_jacobi_sweeps(e, r, 0.8, 2, interpret=False)
+    return fn, [((N, N), dtype), ((N, N), dtype)]
+
+
+def _lab_rhs():
+    def fn(lab, h, dt):
+        return pk.fused_lab_rhs(lab, h, 4e-5, dt, interpret=False)
+    return fn, [((NB, 2, 14, 14), F32), ((NB, 1, 1, 1), F32), ((), F32)]
+
+
+def _block_update():
+    def fn(e, r, lap, p_inv):
+        return pk.fused_block_jacobi_update(e, r, lap, p_inv,
+                                            interpret=False)
+    return fn, [((NB, 8, 8), F32)] * 3 + [((64, 64), F32)]
+
+
+def _round4_rhs():
+    # tests/test_pallas.py's always-skipped parity test, as the compile
+    # case (its run-time bit-parity is chip_smoke.py phase 5)
+    def fn(lab, dt):
+        return pk.advect_diffuse_rhs_pallas(lab, 1.0 / N, 4e-5, dt, N)
+    return fn, [((2, N + 6, N + 6), F32), ((), F32)]
+
+
+ONE_CHIP_CASES = {
+    "fused_advect_heun-f32": lambda: _advect(False, None),
+    "fused_advect_heun-bf16": lambda: _advect(True, None),
+    "fused_advect_heun-f32-cavity": lambda: _advect(False,
+                                                    cavity_table(1.0)),
+    "fused_correction": _correction,
+    "fused_jacobi_sweeps-f32": lambda: _jacobi(F32),
+    "fused_jacobi_sweeps-bf16": lambda: _jacobi(BF16),
+    "fused_lab_rhs": _lab_rhs,
+    "fused_block_jacobi_update": _block_update,
+    "advect_diffuse_rhs_pallas": _round4_rhs,
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_CHIP_CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    fn, shapes = ONE_CHIP_CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    _compiles_to_mosaic(fn, *args)
+
+
+def test_sharded_substage_compiles_on_4_chip_mesh(mesh4):
+    """_fused_substage_sharded through its shard_map wrapper at the
+    per-shard width nxl = 8192/4, halo exchange included."""
+    def fn(v, dt):
+        return sh.fused_advect_heun_sharded(
+            v, 1.0 / N, 1e-3, dt, mesh4, bc=cavity_table(1.0),
+            interpret=False)
+    compiled = _compiles_to_mosaic(
+        fn,
+        jax.ShapeDtypeStruct((2, N, N), F32,
+                             sharding=NamedSharding(mesh4,
+                                                    P(None, None, "x"))),
+        jax.ShapeDtypeStruct((), F32, sharding=NamedSharding(mesh4, P())))
+    assert "collective-permute" in compiled.as_text()
+
+
+def test_jacobi_halo_sweep_compiles_on_4_chip_mesh(mesh4):
+    """fused_jacobi_halo_sweep through the strip-tier overlap wrapper."""
+    def fn(e, r):
+        return sh._overlap_jacobi_sweeps_strip(e, r, 0.8, 2, mesh4,
+                                               interpret=False)
+    split = NamedSharding(mesh4, P(None, "x"))
+    compiled = _compiles_to_mosaic(
+        fn, jax.ShapeDtypeStruct((N, N), F32, sharding=split),
+        jax.ShapeDtypeStruct((N, N), F32, sharding=split))
+    assert "collective-permute" in compiled.as_text()

@@ -23,8 +23,8 @@ def test_latch_allowlist_matches_reality(monkeypatch):
     # the finalize pass flags policy rows that stopped matching the
     # code — prove it by planting a row for a latch that doesn't exist
     bogus = dict(policy.ENV_LATCH_SITES)
-    bogus[("cache.py", "enable_compilation_cache")] = (
-        bogus[("cache.py", "enable_compilation_cache")]
+    bogus[("parallel/forest_mesh.py", "_exchange_mode")] = (
+        bogus[("parallel/forest_mesh.py", "_exchange_mode")]
         | {"CUP2D_NO_SUCH_GATE"})
     monkeypatch.setattr(policy, "ENV_LATCH_SITES", bogus)
     report = lint_package(only=["env-latch"])

@@ -225,8 +225,9 @@ def test_fft_mode_cuts_cold_production_iters(monkeypatch):
     uniform-level forest with a cold multi-scale RHS, the always-on
     fft two-grid path converges the first production solve in <= half
     the block-Jacobi default's iterations at the same tolerance
-    criterion. (The developed-regime 1e4-block record lives in
-    BASELINE.md round 6; iteration counts are platform-independent.)"""
+    criterion. (The developed-regime 1e4-block record is
+    validation/poisson_ab_r6.json; iteration counts are
+    platform-independent.)"""
     from validation.poisson_ab import build_forest_sim
 
     monkeypatch.delenv("CUP2D_POIS", raising=False)
@@ -249,7 +250,7 @@ def test_fft_mode_cuts_cold_production_iters(monkeypatch):
     assert a.poisson_mode == "bicgstab+jacobi"
 
 
-@pytest.mark.slow   # ~2-4 min: the BASELINE round-6 1e4-block probe
+@pytest.mark.slow   # ~2-4 min: the round-6 1e4-block probe
 #                     itself (10.5k blocks over levels 6-8 — the
 #                     synthetic builder STARTS at 8,192 level-6
 #                     blocks, so the target must exceed that for the
@@ -257,7 +258,7 @@ def test_fft_mode_cuts_cold_production_iters(monkeypatch):
 #                     regime where the base-level correction is
 #                     genuinely approximate) — duplicative coverage
 #                     of the tier-1 256-block A/B above, pinning the
-#                     acceptance numbers recorded in BASELINE.md r6
+#                     acceptance numbers of validation/poisson_ab_r6.json
 #                     (additive 10/9/8 -> mg2 4/4/4 iters/step).
 def test_fft_mode_multilevel_regime_iters(monkeypatch):
     from validation.poisson_ab import run_path
@@ -306,7 +307,7 @@ def test_forest_fas_matches_krylov_pressure():
     assert bool(da["poisson_converged"]) and bool(db["poisson_converged"])
     # the full-solver cycle train beats the Krylov iteration count at
     # the same (deep) target — the ISSUE-13 acceptance shape; the
-    # 1e4-block record is the slow drill below + BASELINE round 10
+    # 1e4-block record is the slow drill below + poisson_ab_r10.json
     assert int(db["poisson_iters"]) <= int(da["poisson_iters"]), (da, db)
     assert int(db["precond_cycles"]) == int(db["poisson_iters"])
     va = sa._ordered_state()
@@ -324,7 +325,7 @@ def test_forest_fas_matches_krylov_pressure():
 
 
 @pytest.mark.slow   # ~4-6 min: the ISSUE-13 acceptance drill at the
-#                     BASELINE 1e4-block probe itself (10.5k blocks,
+#                     1e4-block probe itself (10.5k blocks,
 #                     levels 6-8 — a multi-RUNG window ladder, the
 #                     regime that exposed the Dirichlet-ghost
 #                     instability) — duplicative of the tier-1

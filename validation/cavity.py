@@ -11,8 +11,7 @@ solvers.
     python -m validation.cavity          # Re=100 at 128^2, ~minutes
 
 Passes when both centerline profiles match Ghia to within 2% of the
-lid speed (the acceptance bar in ISSUE 12). Measured numbers live in
-BASELINE.md.
+lid speed (the acceptance bar in ISSUE 12).
 """
 
 from __future__ import annotations

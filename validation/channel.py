@@ -13,7 +13,6 @@ free-slip box cannot do.
 Published references, same as the towed twin: Cd(Re=40) ~ 1.5-1.6
 unbounded (Tritton 1959); St(Re=200) ~ 0.19-0.20 (Williamson 1989).
 The acceptance bar (ISSUE 12) is St within 5% of the literature band.
-Measured numbers live in BASELINE.md.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Comm-scaling audit at REALISTIC occupancy (VERDICT r4 #6).
 
-The r4 ppermute-vs-allgather table (BASELINE.md) was measured on the
+The r4 ppermute-vs-allgather table was measured on the
 ~20-block disk case — under one block per shard at 32 devices, so the
 "near-flat per-device bytes" row was dominated by fragmentation, not a
 real boundary-to-volume ratio. This audit re-measures on the 1e4-block
@@ -59,7 +59,9 @@ def grow(target: int, levelmax: int):
 
 def audit_one(n_dev: int, mode: str, levelmax: int,
               two_level: bool) -> dict:
-    """Run in a SUBPROCESS (backend flags must be set pre-init)."""
+    """Run in a SUBPROCESS (backend flags must be set pre-init). The
+    child is CPU-forced (env AND config), so it never needs a chip the
+    parent might hold; phase B's parent does not touch jax itself."""
     code = f"""
 import os, json
 os.environ["CUP2D_SHARD_EXCHANGE"] = {mode!r}
@@ -101,7 +103,7 @@ print(json.dumps({{"n_blocks": len(f.blocks),
     with tempfile.TemporaryDirectory(prefix="hlo_scale_") as dump:
         env = dict(os.environ)
         env["AUDIT_DUMP"] = dump
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True,
                            cwd="/root/repo", timeout=3600)

@@ -13,7 +13,7 @@ body, exactly like the reference's self-propelled fish.
 
 Published references: Cd(Re=40) ~ 1.5-1.6 unbounded (Tritton 1959);
 St(Re=200) ~ 0.19-0.20 (Williamson 1989). Blockage inflates both a few
-percent. Measured on a v5e chip: see BASELINE.md.
+percent.
 """
 
 from __future__ import annotations

@@ -34,7 +34,7 @@ only meaningful on the production rig. Usage:
 
 Prints one JSON line per path: {path, n_blocks, iters (per step),
 residual, converged}; ``--out`` additionally records the arms + probe
-metadata as one provenance JSON (the BASELINE round-10 record at the
+metadata as one provenance JSON (the round-10 record at the
 1e4-block probe is validation/poisson_ab_r10.json).
 """
 
@@ -159,7 +159,7 @@ def build_multilevel_sim(bpd: int = 4, level_start: int = 1,
 
 
 def build_synthetic_sim(target: int, levelmax: int = 8):
-    """The BASELINE.md 1e4-block-regime forest (scale_proof's synthetic
+    """The 1e4-block-regime forest (scale_proof's synthetic
     vortices on the canonical domain, levelStart 6), adapted until
     ``target`` blocks are active — the same topology class the r4/r5
     production-iteration numbers were measured on."""
@@ -243,7 +243,7 @@ def main():
                     default="jacobi,additive,mult,mg2,fas,fas-f,"
                             "fas-bf16leg")
     ap.add_argument("--synthetic", type=int, default=0,
-                    help="use the BASELINE 1e4-regime synthetic forest "
+                    help="use the 1e4-regime synthetic forest "
                          "adapted to >= this many blocks")
     ap.add_argument("--levelmax", type=int, default=8)
     ap.add_argument("--multilevel", action="store_true",

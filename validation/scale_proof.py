@@ -10,7 +10,7 @@ round 2 only ever measured ~500. Two modes:
   after 300 steps) — wakes need thousands of steps to demand 1e4.
 * --synthetic: dense start — uniform levelStart-6 grid (8,192 blocks)
   + strong seeded vortices refining past 1e4 immediately. This is the
-  mode that produced the BASELINE.md 1e4-regime table; the machinery
+  mode that produced the 1e4-regime table; the machinery
   whose scaling is in question (halo-table rebuild, regrid commit,
   pad-bucket growth, step at 16k-pad) doesn't care where blocks came
   from. Compression is disabled there: --ctol is rejected, --target
