@@ -59,17 +59,19 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(ROOT, "chip_smoke_out")
 
-# parity bands. Two f32 runs of one case that take different Krylov
-# paths (another tier's rounding, another device count's reduction
-# order) agree to what the case's own Poisson tolerance lets through,
-# so the f32 band is the case's ABSOLUTE tolerance x scale: 1e-4 for
-# the catalog cases (the bar tests/test_strip_smoother.py holds; the
-# per-op bars of tests/test_megakernel.py — 2e-6 per Heun, 5e-6 per
-# correction — sit well inside it over a dozen steps), 1e-3 for the
-# canonical flags (-poissonTol 1e-3). The tests' own 1e-11/1e-12
-# sharded bars are f64 bars on identical arithmetic and do not
-# transfer to f32 on chips. bf16 storage: tests/test_megakernel.py's
-# trajectory band.
+# parity bands: bars the tests already hold, never a bar fitted to a
+# run. f32: 1e-4 x scale, the trajectory bar of
+# tests/test_strip_smoother.py (the per-op bars of
+# tests/test_megakernel.py — 2e-6 per Heun, 5e-6 per correction — sit
+# well inside it over a dozen steps). It also stands in for the
+# sharded-vs-single bars of tests/test_mesh.py and
+# tests/test_forest_mesh.py, which are f64 bars (1e-11/1e-12) on
+# identical arithmetic and have no f32 twin. The canonical pair of
+# ``--chips 4`` is KNOWN TO MISS it (4.1e-4 on four chips; PERF.md,
+# Findings PR 21, says what is known about why) and stays failing
+# until a four-chip run settles the cause. bf16 storage:
+# tests/test_megakernel.py's trajectory band.
+F32_BAND = 1e-4
 BF16_BAND = 2e-2
 # tests/test_fftd.py::test_tgv_periodic_ke_decay_within_1pct
 TGV_KE_BAR = 0.01
@@ -172,13 +174,11 @@ class Run:
         return {tuple(int(x) for x in k): vel[n]
                 for n, k in enumerate(keys)}
 
-    def parity(self, ref, versus: str, band=None) -> tuple:
+    def parity(self, ref, versus: str, band: float = F32_BAND) -> tuple:
         """(check, line fields): max |final velocity - ref| against
-        ``band`` (default: the case's Poisson tolerance) x scale.
-        Forest block dicts must hold the same blocks; a missing
-        reference (its phase failed) is infinitely far."""
-        if band is None:
-            band = self.sim.cfg.poisson_tol
+        ``band`` x scale. Forest block dicts must hold the same
+        blocks; a missing reference (its phase failed) is infinitely
+        far."""
         a, b = self.final_vel(), ref
         if b is None or (isinstance(a, dict) and set(a) != set(b)):
             diff, scale = float("inf"), 1.0
@@ -479,11 +479,11 @@ def phase5(sz: dict, refs: dict) -> None:
     pallas, bf16 = {"CUP2D_PALLAS": "1"}, {"CUP2D_PREC": "bf16"}
     fas = {"CUP2D_POIS": "fas"}
     tiers = (
-        ("5a-cavity-pallas", pallas, "pallas-fused", "xla", None),
+        ("5a-cavity-pallas", pallas, "pallas-fused", "xla", F32_BAND),
         ("5b-cavity-pallas-bf16", {**pallas, **bf16},
          "pallas-fused-bf16", "xla", BF16_BAND),
         ("5c-cavity-pallas-fas", {**pallas, **fas},
-         "pallas-fused", "strip", None),
+         "pallas-fused", "strip", F32_BAND),
         ("5d-cavity-pallas-fas-bf16", {**pallas, **fas, **bf16},
          "pallas-fused-bf16", "strip+bf16", BF16_BAND),
     )
@@ -606,7 +606,7 @@ def mesh_forest(sz: dict, chips: int) -> None:
     """ShardedAMRSim against AMRSim on the canonical case."""
     solo = Run("m3-canonical-1dev", _canonical_argv(sz), {})
     checks, extra = _canonical_checks(solo, sz)
-    ref = solo.final_vel()
+    ref, solo_records = solo.final_vel(), solo.records
     solo.finish(checks, **extra)
     run = Run("m4-canonical-mesh",
               _canonical_argv(sz) + ["-mesh", str(chips)], {})
@@ -614,10 +614,18 @@ def mesh_forest(sz: dict, chips: int) -> None:
     n_dev = _spans(run.sim._ordered_state()["vel"])
     checks["blocks_span_all_devices"] = n_dev == chips
     checks["matches_single_device"], par = run.parity(ref, "1dev")
+    # where the two trajectories part, for whoever reads a failed
+    # parity: the first step whose umax or iteration count differs,
+    # and both clocks at the end (dt follows umax)
+    parted = [a["step"] for a, b in zip(solo_records, run.records)
+              if (a["umax"], a["poisson_iters"])
+              != (b["umax"], b["poisson_iters"])]
     last = run.records[-1]
     run.finish(checks, devices=n_dev,
                halo_real_bytes=last["halo_real_bytes"],
                halo_padded_bytes=last["halo_padded_bytes"],
+               first_step_apart_from_1dev=parted[0] if parted else None,
+               t_final=last["t"], t_final_1dev=solo_records[-1]["t"],
                **par, **extra)
 
 

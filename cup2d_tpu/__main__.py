@@ -216,7 +216,7 @@ def main(argv=None, *, sim_out=None) -> int:
         # validation-case catalog (cases.py): the case supplies its own
         # SimConfig + BCTable + initial/obstacle state; -level overrides
         # the validation resolution, -fleet serves fleet-capable cases
-        from .cases import REGISTRY, make_sim
+        from .cases import REGISTRY, initial_states, make_sim
         spec = REGISTRY.get(case_name)
         if spec is None:
             names = ", ".join(c for c in REGISTRY)
@@ -412,7 +412,7 @@ def main(argv=None, *, sim_out=None) -> int:
         # at geometrically decaying amplitudes (per-session umax ->
         # per-session dt)
         if case_name is not None:
-            ens = make_sim(case_name, **{**kw, "members": serve_n}).state
+            ens = initial_states(case_name, sim.grid, serve_n)
         else:
             ens = taylor_green_fleet(sim.grid, serve_n)
         for i in range(serve_n):

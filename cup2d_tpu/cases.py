@@ -76,13 +76,17 @@ class CaseSpec:
     """One catalog entry: ``build(**kw)`` returns a ready-to-step
     driver with ``sim.case`` set; ``default_level`` is the validation
     resolution (CLI ``-level`` overrides); ``fleet_ok`` marks cases
-    whose obstacle-free state can ride the fleet slot pool."""
+    whose obstacle-free state can ride the fleet slot pool;
+    ``initial_vel(grid, m) -> [2, Ny, Nx]`` (numpy) is member ``m``'s
+    starting velocity at the case's default parameters (None: fluid at
+    rest) — what ``build`` installs and ``initial_states`` serves."""
 
     name: str
     describe: str
     build: Callable
     default_level: int
     fleet_ok: bool = False
+    initial_vel: Optional[Callable] = None
 
 
 def cavity_table(lid_u: float = 1.0) -> BCTable:
@@ -124,16 +128,16 @@ def _periodic_sim(cfg: SimConfig, lvl: int, mesh, members: int):
 
 
 def _install_vel(sim, members: int, vel_fn):
-    """Overwrite the zero-state velocity with ``vel_fn(m) ->
-    [2, Ny, Nx]`` (numpy), broadcast/stacked over fleet slots."""
+    """Overwrite the zero-state velocity with ``vel_fn(grid, m) ->
+    [2, Ny, Nx]`` (numpy), stacked over fleet slots."""
     import jax.numpy as jnp
     import numpy as np
 
     g = sim.grid
     if members > 0:
-        v = np.stack([vel_fn(m) for m in range(members)])
+        v = np.stack([vel_fn(g, m) for m in range(members)])
     else:
-        v = vel_fn(0)
+        v = vel_fn(g, 0)
     sim.state = sim.state._replace(
         vel=jnp.asarray(v, dtype=g.dtype))
 
@@ -155,15 +159,18 @@ def build_tgv_periodic(level: Optional[int] = None, nu: float = 1e-3,
                     extent=1.0, dtype=dtype, nu=nu, cfl=cfl,
                     poisson_tol=1e-4, poisson_tol_rel=1e-3)
     sim = _periodic_sim(cfg, lvl, mesh, members)
-
-    import numpy as np
-    x, y = sim.grid.cell_centers()
-    k = 2.0 * np.pi / cfg.extent
-    u = u0 * np.sin(k * x) * np.cos(k * y)
-    v = -u0 * np.cos(k * x) * np.sin(k * y)
-    _install_vel(sim, members, lambda m: np.stack([u, v]))
+    _install_vel(sim, members, lambda g, m: tgv_periodic_vel(g, m, u0))
     sim.case = "tgv_periodic"
     return sim
+
+
+def tgv_periodic_vel(grid, m: int = 0, u0: float = 1.0):
+    """Every member the same vortex (the analytic anchor)."""
+    import numpy as np
+    x, y = grid.cell_centers()
+    k = 2.0 * np.pi / grid.cfg.extent
+    return np.stack([u0 * np.sin(k * x) * np.cos(k * y),
+                     -u0 * np.cos(k * x) * np.sin(k * y)])
 
 
 def build_shear_layer(level: Optional[int] = None, nu: float = 2e-4,
@@ -179,17 +186,22 @@ def build_shear_layer(level: Optional[int] = None, nu: float = 2e-4,
                     extent=1.0, dtype=dtype, nu=nu, cfl=cfl,
                     poisson_tol=1e-4, poisson_tol_rel=1e-3)
     sim = _periodic_sim(cfg, lvl, mesh, members)
+    _install_vel(sim, members,
+                 lambda g, m: shear_layer_vel(g, m, rho, delta, u0))
+    sim.case = "shear_layer"
+    return sim
 
+
+def shear_layer_vel(grid, m: int = 0, rho: float = 30.0,
+                    delta: float = 0.05, u0: float = 1.0):
+    """Every member the same pair of layers."""
     import numpy as np
-    x, y = sim.grid.cell_centers()
-    L = cfg.extent
+    x, y = grid.cell_centers()
+    L = grid.cfg.extent
     u = u0 * np.where(y <= 0.5 * L,
                       np.tanh(rho * (y / L - 0.25)),
                       np.tanh(rho * (0.75 - y / L)))
-    v = delta * u0 * np.sin(2.0 * np.pi * x / L)
-    _install_vel(sim, members, lambda m: np.stack([u, v]))
-    sim.case = "shear_layer"
-    return sim
+    return np.stack([u, delta * u0 * np.sin(2.0 * np.pi * x / L)])
 
 
 def build_turb2d(level: Optional[int] = None, nu: float = 1e-4,
@@ -210,38 +222,38 @@ def build_turb2d(level: Optional[int] = None, nu: float = 1e-4,
                     extent=1.0, dtype=dtype, nu=nu, cfl=cfl,
                     poisson_tol=1e-4, poisson_tol_rel=1e-3)
     sim = _periodic_sim(cfg, lvl, mesh, members)
-
-    import numpy as np
-    g = sim.grid
-    ny, nx, h = g.ny, g.nx, g.h
-
-    def vel_for(m: int):
-        rng = np.random.default_rng(seed + m)
-        kx = np.fft.fftfreq(nx, d=1.0 / nx)
-        ky = np.fft.fftfreq(ny, d=1.0 / ny)
-        KX, KY = np.meshgrid(kx, ky, indexing="xy")
-        kk = np.sqrt(KX ** 2 + KY ** 2)
-        with np.errstate(divide="ignore", invalid="ignore"):
-            # E(k) ~ k/(1+(k/k0)^4); psi-hat amplitude
-            # ~ sqrt(E(k)/k)/k (vorticity = k^2 psi-hat)
-            amp = np.where(
-                kk > 0,
-                np.sqrt(kk / (1.0 + (kk / k0) ** 4)) / (kk ** 1.5),
-                0.0)
-        phase = np.exp(2j * np.pi * rng.random((ny, nx)))
-        psi = np.fft.ifft2(amp * phase).real
-        # centered differences on the wrap: discretely div-free
-        u = (np.roll(psi, -1, axis=0) - np.roll(psi, 1, axis=0)) \
-            / (2.0 * h)
-        v = -(np.roll(psi, -1, axis=1) - np.roll(psi, 1, axis=1)) \
-            / (2.0 * h)
-        rms = np.sqrt(np.mean(u ** 2 + v ** 2))
-        s = urms / rms if rms > 0 else 1.0
-        return np.stack([u * s, v * s])
-
-    _install_vel(sim, members, vel_for)
+    _install_vel(sim, members,
+                 lambda g, m: turb2d_vel(g, m, seed, k0, urms))
     sim.case = "turb2d"
     return sim
+
+
+def turb2d_vel(grid, m: int = 0, seed: int = 0, k0: float = 6.0,
+               urms: float = 1.0):
+    """Member ``m`` draws seed + m, so members (and served sessions)
+    are different flows."""
+    import numpy as np
+    ny, nx, h = grid.ny, grid.nx, grid.h
+    rng = np.random.default_rng(seed + m)
+    kx = np.fft.fftfreq(nx, d=1.0 / nx)
+    ky = np.fft.fftfreq(ny, d=1.0 / ny)
+    KX, KY = np.meshgrid(kx, ky, indexing="xy")
+    kk = np.sqrt(KX ** 2 + KY ** 2)
+    with np.errstate(divide="ignore", invalid="ignore"):
+        # E(k) ~ k/(1+(k/k0)^4); psi-hat amplitude
+        # ~ sqrt(E(k)/k)/k (vorticity = k^2 psi-hat)
+        amp = np.where(
+            kk > 0,
+            np.sqrt(kk / (1.0 + (kk / k0) ** 4)) / (kk ** 1.5),
+            0.0)
+    phase = np.exp(2j * np.pi * rng.random((ny, nx)))
+    psi = np.fft.ifft2(amp * phase).real
+    # centered differences on the wrap: discretely div-free
+    u = (np.roll(psi, -1, axis=0) - np.roll(psi, 1, axis=0)) / (2.0 * h)
+    v = -(np.roll(psi, -1, axis=1) - np.roll(psi, 1, axis=1)) / (2.0 * h)
+    rms = np.sqrt(np.mean(u ** 2 + v ** 2))
+    s = urms / rms if rms > 0 else 1.0
+    return np.stack([u * s, v * s])
 
 
 def build_cavity(level: Optional[int] = None, re: float = 100.0,
@@ -334,13 +346,16 @@ CASES: Tuple[CaseSpec, ...] = (
              build_cylinder, default_level=5),
     CaseSpec("tgv_periodic",
              "doubly-periodic Taylor-Green vortex (analytic KE decay)",
-             build_tgv_periodic, default_level=4, fleet_ok=True),
+             build_tgv_periodic, default_level=4, fleet_ok=True,
+             initial_vel=tgv_periodic_vel),
     CaseSpec("shear_layer",
              "doubly-periodic double shear layer roll-up (BCG 1989)",
-             build_shear_layer, default_level=4, fleet_ok=True),
+             build_shear_layer, default_level=4, fleet_ok=True,
+             initial_vel=shear_layer_vel),
     CaseSpec("turb2d",
              "seeded decaying 2D turbulence, doubly-periodic",
-             build_turb2d, default_level=4, fleet_ok=True),
+             build_turb2d, default_level=4, fleet_ok=True,
+             initial_vel=turb2d_vel),
 )
 
 REGISTRY = {c.name: c for c in CASES}
@@ -360,3 +375,21 @@ def make_sim(name: str, **kw):
         raise ValueError(
             f"unknown case {name!r}; catalog: {listing}")
     return spec.build(**kw)
+
+
+def initial_states(name: str, grid, n: int):
+    """The first ``n`` member states of a fleet-capable case on
+    ``grid``, stacked [n, ...] — what ``build(members=n)`` installs,
+    without building a second driver (the fleet server admits sessions
+    from these)."""
+    import jax.numpy as jnp
+
+    from .fleet import stack_states
+    vel_fn = REGISTRY[name].initial_vel
+    states = []
+    for m in range(n):
+        st = grid.zero_state()
+        if vel_fn is not None:
+            st = st._replace(vel=jnp.asarray(vel_fn(grid, m), grid.dtype))
+        states.append(st)
+    return stack_states(states)

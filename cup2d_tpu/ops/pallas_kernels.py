@@ -40,7 +40,7 @@ Two generations live here:
    — in lab form — forest-block batches (L=N, per-block h). On a TPU
    the kernels are always Mosaic-compiled; interpret mode exists only
    on a CPU run (JAX_PLATFORMS=cpu, the tests — validation, not
-   performance; see _interpret_default). ISSUE 16 closed the last two refusals:
+   performance; see _on_accel). ISSUE 16 closed the last two refusals:
    non-free-slip BC tables ride the in-VMEM affine ghost synthesis
    (one executable per BC token), and the sharded x-split rides the
    halo-mode kernel (_fused_substage_sharded) behind shard_halo.
@@ -96,23 +96,19 @@ def _rem(k, m):
 
 
 def _on_accel() -> bool:
-    """True when the default device compiles Mosaic kernels. A backend
-    that fails to initialise raises here — it must never read as "no
-    chip", which would silently put every kernel in interpret mode."""
-    return jax.devices()[0].platform == "tpu"
-
-
-def _interpret_default() -> bool:
-    """Whether a kernel wrapper called without ``interpret=`` runs in
-    Pallas interpret mode: never on a TPU, always on a CPU run
-    (``JAX_PLATFORMS=cpu``, the test harness — validation speed, not
-    performance), and on no other platform at all."""
+    """True on a TPU, where the kernels compile through Mosaic; False
+    on a CPU run (``JAX_PLATFORMS=cpu``, the test harness), the only
+    place a wrapper called without ``interpret=`` interprets —
+    validation speed, not performance. Any other platform offers
+    neither and raises, and so does a backend that fails to
+    initialise: it must never read as "no chip", which would silently
+    put every kernel in interpret mode."""
     platform = jax.devices()[0].platform
     if platform not in ("tpu", "cpu"):
         raise RuntimeError(
             f"the Pallas tiers compile for TPU and interpret on CPU; "
             f"platform {platform!r} offers neither")
-    return platform == "cpu"
+    return platform == "tpu"
 
 
 def _core_seq(lab, afac, dfac):
@@ -572,7 +568,7 @@ def fused_advect_heun(vel, h, nu, dt, *, bc=None, bf16: bool = False,
         facs = jnp.stack([-dtv * hh, nu * dtv, dtv], axis=-1)
     ih2 = 1.0 / (hh * hh)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     if bf16:
         vb = v.astype(jnp.bfloat16)
         v1 = _fused_substage(vb, None, facs, 0.5, ih2,
@@ -805,7 +801,7 @@ def fused_lab_rhs(lab, h, nu, dt, *, interpret=None):
     # 16 MiB default scoped limit, 9.4 MiB for 16
     cb = _pick(L, (16, 8, 4, 2, 1))
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     kern = functools.partial(_lab_kernel, g)
     out = pl.pallas_call(
         kern,
@@ -938,7 +934,7 @@ def fused_correction(x, pres_old, vel, mx, mp, pfac, ih2, *,
     by = _BY_F32
     n = ny // by
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     gs = ((1.0, 1.0, 1.0, 1.0) if grad_signs is None
           else tuple(float(s) for s in grad_signs))
     scal = jnp.stack([mx, mp, pfac], axis=-1).astype(jnp.float32)
@@ -1180,7 +1176,7 @@ def fused_jacobi_sweeps(e, r, omega, n, *, edge_signs=None,
     nstr = ny // by
     nsw = int(n)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     gs = ((1.0, 1.0, 1.0, 1.0) if edge_signs is None
           else tuple(float(s) for s in edge_signs))
     ops = [r.reshape((L, ny, nx))]
@@ -1329,7 +1325,7 @@ def fused_jacobi_halo_sweep(e, r, aux, info, omega, *, interpret=None):
     by = _BY_BF16 if store == jnp.bfloat16 else _BY_F32
     nstr = ny // by
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     kern = functools.partial(_jacobi_halo_kernel, by, nstr, ny, nxl,
                              float(omega))
     return pl.pallas_call(
@@ -1389,7 +1385,7 @@ def fused_block_jacobi_update(e, r, lap, p_inv, *, interpret=None):
     m = bs * bs
     cb = _pick(n, (64, 32, 16, 8, 4, 2, 1))
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not _on_accel()
     out = pl.pallas_call(
         _block_jacobi_kernel,
         grid=(n // cb,),

@@ -610,7 +610,7 @@ def _overlap_jacobi_sweeps_strip(e: jnp.ndarray, r: jnp.ndarray,
     from ..ops import pallas_kernels as pk
     D = mesh.devices.size
     if interpret is None:
-        interpret = pk._interpret_default()
+        interpret = not pk._on_accel()
     pad_w = 2 * pk._GX - 2
 
     # check_vma=False: shard_map has no replication rule for
@@ -691,7 +691,7 @@ def fused_advect_heun_sharded(vel, h, nu, dt, mesh: Mesh, *, bc=None,
     facs = jnp.stack([-dtv * hh, nu * dtv, dtv], axis=-1)   # [L, 3] f32
     ih2 = 1.0 / (hh * hh)
     if interpret is None:
-        interpret = pk._interpret_default()
+        interpret = not pk._on_accel()
     D = int(mesh.devices.size)
     nx = v.shape[-1]
     if nx % D:
@@ -898,7 +898,7 @@ def overlap_block_jacobi_sweeps(e: jnp.ndarray, r: jnp.ndarray,
     if tier != "xla":
         from ..ops import pallas_kernels as pk
         use_fused = pk.block_update_supported(e.dtype)
-        interpret = pk._interpret_default()
+        interpret = not pk._on_accel()
 
     @partial(_shard_map, mesh=t.mesh,
              in_specs=(P("x"),) * 10 + (P(),) * 6, out_specs=P("x"),

@@ -39,7 +39,7 @@ def test_env_var_placement_is_left_to_jax(monkeypatch,
     assert jax.config.jax_compilation_cache_dir == "sentinel-untouched"
 
 
-def test_default_is_one_fixed_path_in_the_checkout(monkeypatch,
+def test_default_is_one_fixed_path_in_the_checkout(monkeypatch, tmp_path,
                                                    restore_cache_config):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     want = os.path.join(ROOT, ".jax_cache", "xla")
@@ -53,12 +53,15 @@ def test_default_is_one_fixed_path_in_the_checkout(monkeypatch,
             "cache.enable_compilation_cache(); "
             "print(jax.config.jax_compilation_cache_dir)")
     seen = set()
-    for home in ("/nonexistent-home-a", "/nonexistent-home-b"):
+    for name in ("a", "b"):
+        home, tmp = tmp_path / f"home-{name}", tmp_path / f"tmp-{name}"
+        home.mkdir()
+        tmp.mkdir()
         env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu",
-                   HOME=home, TMPDIR="/tmp")
+                   HOME=str(home), TMPDIR=str(tmp))
         env.pop("JAX_COMPILATION_CACHE_DIR", None)
         out = subprocess.run([sys.executable, "-c", code], env=env,
-                             cwd="/", capture_output=True, text=True,
+                             cwd=tmp_path, capture_output=True, text=True,
                              timeout=120, check=True)
         seen.add(out.stdout.strip().splitlines()[-1])
     assert seen == {want}

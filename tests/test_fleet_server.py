@@ -29,6 +29,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from cup2d_tpu.config import SimConfig
 from cup2d_tpu.faults import FaultPlan
@@ -455,3 +456,49 @@ def test_shaped_fleet_members_match_solo_obstacle_step():
         assert float(np.asarray(diag["umax"])[m]) > 0
     # the disk broke the symmetry: members' solves differ
     assert int(np.asarray(diag["poisson_iters"])[0]) >= 1
+
+
+# -- a -case pool serves the case's own states (PR 21) -----------------
+
+from cup2d_tpu import cases  # noqa: E402
+from cup2d_tpu.__main__ import main  # noqa: E402
+
+FLEET_CASES = [c.name for c in cases.CASES if c.fleet_ok]
+
+
+@pytest.mark.parametrize("name", FLEET_CASES)
+def test_initial_states_are_what_the_builder_installs(name):
+    """cases.initial_states == the state build(members=n) starts from,
+    leaf for leaf — the server's sessions and a plain fleet run of the
+    same case begin identically."""
+    sim = cases.make_sim(name, level=2, members=3)
+    ens = cases.initial_states(name, sim.grid, 3)
+    for got, want in zip(ens, sim.state):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_case_pool_serves_the_cases_own_states(tmp_path, monkeypatch):
+    """``-case turb2d -fleet 2 -serve 3``: every submitted session
+    starts from the case's state for its index (seed + i — three
+    different flows), not from the plain-flag Taylor-Green ladder,
+    which is discontinuous across a periodic wrap."""
+    seen = []
+    submit = FleetServer.submit
+
+    def spy(self, req):
+        seen.append(req)
+        return submit(self, req)
+
+    monkeypatch.setattr(FleetServer, "submit", spy)
+    rc = main(["-case", "turb2d", "-level", "2", "-fleet", "2",
+               "-serve", "3", "-tend", "0.01", "-maxSteps", "3",
+               "-output", str(tmp_path)])
+    assert rc == 0
+    assert [r.client_id for r in seen] == ["s0000", "s0001", "s0002"]
+    grid = cases.make_sim("turb2d", level=2).grid
+    for i, req in enumerate(seen):
+        want = cases.turb2d_vel(grid, i).astype(grid.dtype)
+        np.testing.assert_array_equal(np.asarray(req.state.vel), want)
+    assert not np.array_equal(np.asarray(seen[0].state.vel),
+                              np.asarray(seen[1].state.vel))
