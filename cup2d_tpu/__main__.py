@@ -110,7 +110,13 @@ invariants feed a drift watchdog wired into the recovery ladder
 (catches wrong-but-FINITE corruption the isfinite verdict misses).
 Knobs: ``-noMetrics``, ``-metricsLog PATH``, ``-noWatchdog``. Windowed
 device tracing: ``CUP2D_TRACE=start:stop[:logdir]`` wraps exactly those
-steps in a ``jax.profiler`` TensorBoard trace.
+steps in a ``jax.profiler`` TensorBoard trace; inside the window the
+flight recorder's spans are in the trace too (``cup2d:step``, ...), the
+device operations carry the step's scope names (``tracing.SCOPES``),
+and the profiler's Python tracer is OFF (``profiling.TraceWindow``:
+the spans name the host's part of a step, a frame-by-frame record
+cost 0.7 ms of host a traced step at 8192^2 and took the idle gaps'
+names — PERF.md, PR 24).
 
 THE FLIGHT RECORDER (tracing.py, PR 18) rides the same zero-extra-sync
 discipline: span timeline (``<output>/spans.jsonl``, export with
@@ -171,7 +177,7 @@ def main(argv=None, *, sim_out=None) -> int:
     rotate_mb = p("logRotateMB").asInt() if p.has("logRotateMB") else None
     os.makedirs(outdir, exist_ok=True)
 
-    from . import faults
+    from . import faults, tracing
     from .profiling import HostCounters, MetricsRecorder, TraceWindow
     from .resilience import EventLog, FleetStepGuard, PhysicsWatchdog, \
         PreemptionGuard, ResilienceAbort, StepGuard, set_event_log
@@ -462,9 +468,10 @@ def main(argv=None, *, sim_out=None) -> int:
 
     def record(rec, wall_ms=None):
         if rec is not None and recorder is not None:
-            recorder.record_step(step=rec["step"], t=rec["t"],
-                                 dt=rec["dt"], diag=rec, sim=sim,
-                                 wall_ms=wall_ms)
+            with tracing.span("record", step=int(rec["step"])):
+                recorder.record_step(step=rec["step"], t=rec["t"],
+                                     dt=rec["dt"], diag=rec, sim=sim,
+                                     wall_ms=wall_ms)
 
     def drain():
         # settle every in-flight verdict (before dumps, regrids,
@@ -618,9 +625,12 @@ def main(argv=None, *, sim_out=None) -> int:
                 tracer.maybe_start(sim.step_count)
             t_step = time.perf_counter()
             rec = guard.step()
+            # the record before the window may close: a traced step's
+            # `record` span is in the trace it belongs to, and wall_ms
+            # does not hold the profiler's shut-down
+            record(rec, wall_ms=1e3 * (time.perf_counter() - t_step))
             if tracer is not None:
                 tracer.maybe_stop(sim.step_count)
-            record(rec, wall_ms=1e3 * (time.perf_counter() - t_step))
             if ckpt_every and sim.step_count % ckpt_every == 0:
                 drain()
                 save_checkpoint(ckpt_path, sim)

@@ -48,7 +48,7 @@ from .forest import Forest
 from .halo import _TopoIndex, _bucket, assemble_labs, \
     assemble_labs_ordered, build_face_copy, build_tables, \
     make_fast_tables, pad_tables
-from . import native
+from . import native, tracing
 from .ops.collision import merged_overlap_integrals, \
     pairwise_collision_update
 from .ops.forces import surface_forces_blocks
@@ -298,7 +298,6 @@ class AMRSim(ShapeHostMixin):
         self._comm_stats = None
         # jitted ONCE; tables/order/h are arguments, so regrids that
         # reproduce previously-seen shapes hit the XLA compile cache
-        from . import tracing
         self._step_jit = tracing.named_jit(
             "amr.step", jax.jit(
                 self._step_impl, static_argnames=("exact_poisson",)),
@@ -793,19 +792,20 @@ class AMRSim(ShapeHostMixin):
         ih2 = 1.0 / (h * h)
         vold = vel * maskv               # [N,2,BS,BS]
         v = vold
-        for c in (0.5, 1.0):
-            lab = assemble_labs_ordered(v if c == 1.0 else vel, t3)
-            if self._kernel_tier != "xla":
-                # forest-block-batched fused RHS: one HBM read of the
-                # lab batch per stage, per-block h rides the kernel's
-                # (afac, dfac) scale rows
-                from .ops.pallas_kernels import fused_lab_rhs
-                rhs = fused_lab_rhs(lab, h, cfg.nu, dt)
-            else:
-                rhs = advect_diffuse_rhs(lab, 3, h, cfg.nu, dt)
-            rhs = apply_flux_corr(
-                rhs, diffusive_deposits(lab, 3, cfg.nu * dt), corr)
-            v = heun_substage(vold, c, rhs, ih2) * maskv
+        for k, c in enumerate((0.5, 1.0)):
+            with tracing.scope(f"advect/substage{k}"):
+                lab = assemble_labs_ordered(v if c == 1.0 else vel, t3)
+                if self._kernel_tier != "xla":
+                    # forest-block-batched fused RHS: one HBM read of the
+                    # lab batch per stage, per-block h rides the kernel's
+                    # (afac, dfac) scale rows
+                    from .ops.pallas_kernels import fused_lab_rhs
+                    rhs = fused_lab_rhs(lab, h, cfg.nu, dt)
+                else:
+                    rhs = advect_diffuse_rhs(lab, 3, h, cfg.nu, dt)
+                rhs = apply_flux_corr(
+                    rhs, diffusive_deposits(lab, 3, cfg.nu * dt), corr)
+                v = heun_substage(vold, c, rhs, ih2) * maskv
         return v
 
     def _pressure_project(self, v, pres, dt, h, hsq,
@@ -821,26 +821,27 @@ class AMRSim(ShapeHostMixin):
         (v_new, p_new, res, div_linf)."""
         cfg = self.cfg
         ih2 = 1.0 / (h * h)
-        pord = pres[:, 0] * maskv[:, 0]          # [N,BS,BS]
-        vlab = assemble_labs_ordered(v, t1v)
-        fac = 0.5 * h[:, 0] / dt
-        b = fac * divergence(vlab, 1)
-        ulab = None
-        if udef_b is not None:
-            ulab = assemble_labs_ordered(udef_b, t1v)
-            b = b - fac * chi * divergence(ulab, 1)
-        b = apply_flux_corr(
-            b, divergence_deposits(vlab, ulab, chi, fac[:, 0, 0]), corr)
-        # physics invariant for the telemetry watchdog: max |∇·u| of
-        # the pre-projection velocity, read off the (flux-corrected)
-        # Poisson RHS the step already forms — |b| = fac * |undivided
-        # div| with fac = h/2dt, physical div = undivided/(2h), so the
-        # rescale is dt/h^2 per block. Zero extra lab assemblies (an
-        # honest post-projection divergence would cost one more halo
-        # exchange per step under the sharded mesh). Pad rows carry
-        # stale-but-finite lab data — masked.
-        div_linf = jnp.max(
-            jnp.abs(b) * maskv[:, 0] * (dt / (h[:, 0] * h[:, 0])))
+        with tracing.scope("poisson_rhs"):
+            pord = pres[:, 0] * maskv[:, 0]          # [N,BS,BS]
+            vlab = assemble_labs_ordered(v, t1v)
+            fac = 0.5 * h[:, 0] / dt
+            b = fac * divergence(vlab, 1)
+            ulab = None
+            if udef_b is not None:
+                ulab = assemble_labs_ordered(udef_b, t1v)
+                b = b - fac * chi * divergence(ulab, 1)
+            b = apply_flux_corr(
+                b, divergence_deposits(vlab, ulab, chi, fac[:, 0, 0]), corr)
+            # physics invariant for the telemetry watchdog: max |∇·u| of
+            # the pre-projection velocity, read off the (flux-corrected)
+            # Poisson RHS the step already forms — |b| = fac * |undivided
+            # div| with fac = h/2dt, physical div = undivided/(2h), so the
+            # rescale is dt/h^2 per block. Zero extra lab assemblies (an
+            # honest post-projection divergence would cost one more halo
+            # exchange per step under the sharded mesh). Pad rows carry
+            # stale-but-finite lab data — masked.
+            div_linf = jnp.max(
+                jnp.abs(b) * maskv[:, 0] * (dt / (h[:, 0] * h[:, 0])))
 
         if hasattr(tpois, "nba"):
             # structured per-face operator (flux.poisson_apply_structured)
@@ -854,10 +855,14 @@ class AMRSim(ShapeHostMixin):
         # initial-guess subtraction via A itself (the reference uses the
         # lab Laplacian + flux correction, pressure_rhs1; using A keeps
         # A(dp + p_old) = div-rhs exactly)
-        b = b - A(pord)
+        with tracing.scope("poisson_rhs"):
+            b = b - A(pord)
+
+        smooth = tracing.scoped("mg_smooth", apply_block_precond_blocks)
+        coarse = tracing.scoped("mg_coarse", coarse_neumann_solve_dct)
 
         def M(r):
-            return apply_block_precond_blocks(r, self.p_inv)
+            return smooth(r, self.p_inv)
 
         if tcoarse is not None:
             # two-level preconditioner (VERDICT r2 #6): block-Jacobi
@@ -876,7 +881,9 @@ class AMRSim(ShapeHostMixin):
             ncy, ncx = self._coarse_shape
             cih2 = jnp.where(hsq > 0,
                              1.0 / jnp.where(hsq > 0, hsq, 1.0), 0.0)
-            _deposit, _interp = self._coarse_transfers(tcoarse)
+            _deposit, _interp = (
+                tracing.scoped("mg_transfer", f)
+                for f in self._coarse_transfers(tcoarse))
 
             # form selection: PRODUCTION solves use the ADDITIVE
             # two-level (coarse correction + block-Jacobi on the same
@@ -907,100 +914,97 @@ class AMRSim(ShapeHostMixin):
             if form == "additive":
                 def M(r):
                     rc = _deposit(r * cih2)
-                    ec = coarse_neumann_solve_dct(
-                        rc, dctops, self._coarse_h2)
-                    return _interp(ec, r) + apply_block_precond_blocks(
-                        r, self.p_inv)
+                    ec = coarse(rc, dctops, self._coarse_h2)
+                    return _interp(ec, r) + smooth(r, self.p_inv)
             elif form == "mg2":
                 def M(r):
-                    e = apply_block_precond_blocks(r, self.p_inv)
+                    e = smooth(r, self.p_inv)
                     r1 = r - A(e)
                     rc = _deposit(r1 * cih2)
-                    ec = coarse_neumann_solve_dct(
-                        rc, dctops, self._coarse_h2)
+                    ec = coarse(rc, dctops, self._coarse_h2)
                     e = e + _interp(ec, r)
-                    return e + apply_block_precond_blocks(
-                        r - A(e), self.p_inv)
+                    return e + smooth(r - A(e), self.p_inv)
             else:
                 def M(r):
                     rc = _deposit(r * cih2)
-                    ec = coarse_neumann_solve_dct(
-                        rc, dctops, self._coarse_h2)
+                    ec = coarse(rc, dctops, self._coarse_h2)
                     e = _interp(ec, r)
-                    return e + apply_block_precond_blocks(
-                        r - A(e), self.p_inv)
+                    return e + smooth(r - A(e), self.p_inv)
 
-        if self._pois_mode in ("fas", "fas-f") and not exact_poisson:
-            # forest-native FAS production solve (PR 13): multigrid
-            # over the forest's OWN refinement levels as the FULL
-            # solver — mg_solve's true-residual cycle loop (the same
-            # result/stall contract every driver already reads) around
-            # one ForestFASCycle per cycle. _use_coarse guarantees
-            # tcoarse for these modes; exact/escalation solves fall
-            # through to the Krylov backstop below, mirroring the
-            # uniform path (UniformGrid.pressure_solve).
-            paint_fine, base_solve, extract_all = \
-                self._fas_transfers(tcoarse)
-            mgc = ForestFASCycle(
-                A, self._fas_block_smoother(A, tpois),
-                paint_fine, base_solve, extract_all, cih2,
-                leg_dtype=self._fas_leg_dtype)
-            res = mg_solve(
-                A, b, mgc,
-                tol=cfg.poisson_tol, tol_rel=cfg.poisson_tol_rel,
-                max_cycles=cfg.max_poisson_iterations,
-                fmg=self._pois_mode == "fas-f",
-            )
-        else:
-            # the cold startup solves start from x0 = M(b): one
-            # two-level application removes the global pressure modes
-            # from r0 before the Krylov iteration begins — the
-            # zero-pressure first solve was the 71-iteration outlier of
-            # the round-3 probe precisely because those modes dominated
-            # its RHS (VERDICT r3 #9)
-            x0 = None
-            if exact_poisson and tcoarse is not None:
-                x0 = M(b)
-            # exact mode converges THREE ORDERS past the case's own
-            # production target (max(1e-3*tol, 1e-3*tol_rel*|r0|)) —
-            # deep enough that the startup pressure transient is
-            # converged for any consumer of the production tolerances,
-            # and anchored to the case instead of the r2 builds'
-            # grid-dependent empirical f32 floors (VERDICT r2 #8). The
-            # stall detector remains the backstop when that target sits
-            # below the precision floor. Chasing the literal-0 floor
-            # instead spent up to 71 iterations grinding to 1e-8 on the
-            # first canonical solve (r3 probe) for depth nothing reads;
-            # this exits at <= 40 (measured).
-            res = bicgstab(
-                A, b, M=M, x0=x0,
-                tol=1e-3 * cfg.poisson_tol if exact_poisson
-                else cfg.poisson_tol,
-                tol_rel=1e-3 * cfg.poisson_tol_rel if exact_poisson
-                else cfg.poisson_tol_rel,
-                max_iter=cfg.max_poisson_iterations,
-                max_restarts=100 if exact_poisson
-                else cfg.max_poisson_restarts,
-                sum_dtype=self.sum_dtype,
-                refresh_every=10 if exact_poisson else 50,
-                stall_iters=15 if exact_poisson else 120,
-                stall_rtol=0.99 if exact_poisson else 0.999,
-            )
+        with tracing.scope("poisson_solve"):
+            if self._pois_mode in ("fas", "fas-f") and not exact_poisson:
+                # forest-native FAS production solve (PR 13): multigrid
+                # over the forest's OWN refinement levels as the FULL
+                # solver — mg_solve's true-residual cycle loop (the same
+                # result/stall contract every driver already reads) around
+                # one ForestFASCycle per cycle. _use_coarse guarantees
+                # tcoarse for these modes; exact/escalation solves fall
+                # through to the Krylov backstop below, mirroring the
+                # uniform path (UniformGrid.pressure_solve).
+                paint_fine, base_solve, extract_all = \
+                    self._fas_transfers(tcoarse)
+                mgc = ForestFASCycle(
+                    A, self._fas_block_smoother(A, tpois),
+                    paint_fine, base_solve, extract_all, cih2,
+                    leg_dtype=self._fas_leg_dtype)
+                res = mg_solve(
+                    A, b, mgc,
+                    tol=cfg.poisson_tol, tol_rel=cfg.poisson_tol_rel,
+                    max_cycles=cfg.max_poisson_iterations,
+                    fmg=self._pois_mode == "fas-f",
+                )
+            else:
+                # the cold startup solves start from x0 = M(b): one
+                # two-level application removes the global pressure modes
+                # from r0 before the Krylov iteration begins — the
+                # zero-pressure first solve was the 71-iteration outlier of
+                # the round-3 probe precisely because those modes dominated
+                # its RHS (VERDICT r3 #9)
+                M = tracing.scoped("mg_cycle", M)
+                x0 = None
+                if exact_poisson and tcoarse is not None:
+                    x0 = M(b)
+                # exact mode converges THREE ORDERS past the case's own
+                # production target (max(1e-3*tol, 1e-3*tol_rel*|r0|)) —
+                # deep enough that the startup pressure transient is
+                # converged for any consumer of the production tolerances,
+                # and anchored to the case instead of the r2 builds'
+                # grid-dependent empirical f32 floors (VERDICT r2 #8). The
+                # stall detector remains the backstop when that target sits
+                # below the precision floor. Chasing the literal-0 floor
+                # instead spent up to 71 iterations grinding to 1e-8 on the
+                # first canonical solve (r3 probe) for depth nothing reads;
+                # this exits at <= 40 (measured).
+                res = bicgstab(
+                    A, b, M=M, x0=x0,
+                    tol=1e-3 * cfg.poisson_tol if exact_poisson
+                    else cfg.poisson_tol,
+                    tol_rel=1e-3 * cfg.poisson_tol_rel if exact_poisson
+                    else cfg.poisson_tol_rel,
+                    max_iter=cfg.max_poisson_iterations,
+                    max_restarts=100 if exact_poisson
+                    else cfg.max_poisson_restarts,
+                    sum_dtype=self.sum_dtype,
+                    refresh_every=10 if exact_poisson else 50,
+                    stall_iters=15 if exact_poisson else 120,
+                    stall_rtol=0.99 if exact_poisson else 0.999,
+                )
 
-        # volume-weighted mean removal (main.cpp:7120-7173)
-        wsum = jnp.sum(hsq) * cfg.bs ** 2
-        dp = res.x - jnp.sum(res.x * hsq) / wsum
-        p_new = dp + pord - jnp.sum(pord * hsq) / wsum
+        with tracing.scope("project_correct"):
+            # volume-weighted mean removal (main.cpp:7120-7173)
+            wsum = jnp.sum(hsq) * cfg.bs ** 2
+            dp = res.x - jnp.sum(res.x * hsq) / wsum
+            p_new = dp + pord - jnp.sum(pord * hsq) / wsum
 
-        # projection (shared kernel, per-block h broadcast), gradient
-        # fluxes corrected (pressureCorrectionKernel + fillcases,
-        # main.cpp:7174-7187)
-        plab = assemble_labs_ordered(p_new[:, None], t1s)
-        dv = pressure_gradient_update(plab[:, 0], 1, h, dt)
-        pfac = -0.5 * dt * h[:, 0, 0, 0]
-        dv = apply_flux_corr(
-            dv, gradient_deposits(plab[:, 0], pfac), corr)
-        v = (v + dv * ih2) * maskv
+            # projection (shared kernel, per-block h broadcast), gradient
+            # fluxes corrected (pressureCorrectionKernel + fillcases,
+            # main.cpp:7174-7187)
+            plab = assemble_labs_ordered(p_new[:, None], t1s)
+            dv = pressure_gradient_update(plab[:, 0], 1, h, dt)
+            pfac = -0.5 * dt * h[:, 0, 0, 0]
+            dv = apply_flux_corr(
+                dv, gradient_deposits(plab[:, 0], pfac), corr)
+            v = (v + dv * ih2) * maskv
         return v, p_new[:, None], res, div_linf
 
     def _coarse_transfers(self, tcoarse):
@@ -1303,17 +1307,11 @@ class AMRSim(ShapeHostMixin):
         return jnp.all(jnp.isfinite(v)) & jnp.all(
             jnp.isfinite(jnp.where(maskv > 0, p_new, 0.0)))
 
-    # ------------------------------------------------------------------
-    # device step: obstacle-free (the oracle path)
-    # ------------------------------------------------------------------
-    def _step_impl(self, vel, pres, dt, h, hsq, maskv,
-                   t3, t1v, t1s, tpois, corr, tcoarse,
-                   exact_poisson=False):
-        v = self._advect_rk2(vel, h, dt, t3, corr, maskv)
-        v, p_new, res, div_linf = self._pressure_project(
-            v, pres, dt, h, hsq, t1v, t1s, tpois, corr, tcoarse,
-            exact_poisson, maskv)
-        diag = {
+    @tracing.in_scope("diag")
+    def _diag(self, v, p_new, res, div_linf, hsq, maskv, tcoarse,
+              exact_poisson) -> dict:
+        """The step's device-side scalars (one batched pull)."""
+        return {
             "poisson_iters": res.iters,
             "poisson_residual": res.residual,
             "poisson_stalled": res.stalled,
@@ -1325,6 +1323,19 @@ class AMRSim(ShapeHostMixin):
             "precond_cycles": self._precond_cycles(
                 res, tcoarse, exact_poisson),
         }
+
+    # ------------------------------------------------------------------
+    # device step: obstacle-free (the oracle path)
+    # ------------------------------------------------------------------
+    def _step_impl(self, vel, pres, dt, h, hsq, maskv,
+                   t3, t1v, t1s, tpois, corr, tcoarse,
+                   exact_poisson=False):
+        v = self._advect_rk2(vel, h, dt, t3, corr, maskv)
+        v, p_new, res, div_linf = self._pressure_project(
+            v, pres, dt, h, hsq, t1v, t1s, tpois, corr, tcoarse,
+            exact_poisson, maskv)
+        diag = self._diag(v, p_new, res, div_linf, hsq, maskv, tcoarse,
+                          exact_poisson)
         return v, p_new, diag
 
     # ------------------------------------------------------------------
@@ -1336,69 +1347,60 @@ class AMRSim(ShapeHostMixin):
         cfg = self.cfg
         S = len(self.shapes)
         v = self._advect_rk2(vel, h, dt, t3, corr, maskv)
-        v_cf = v.transpose(1, 0, 2, 3)   # component-first [2,N,BS,BS]
+        with tracing.scope("penalize"):
+            v_cf = v.transpose(1, 0, 2, 3)   # component-first [2,N,BS,BS]
 
-        # rigid momentum solve per shape (main.cpp:6643-6704)
-        uvw = []
-        for k in range(S):
-            if self.shapes[k].free:
+            # rigid momentum solve per shape (main.cpp:6643-6704)
+            uvw = []
+            for k in range(S):
+                if self.shapes[k].free:
+                    xr = xc - obs.com[k, 0]
+                    yr = yc - obs.com[k, 1]
+                    sums = penalization_integrals(
+                        v_cf, obs.chi_s[k], obs.udef_s[k], xr, yr,
+                        cfg.lam * dt, hsq)
+                    uvw.append(solve_rigid_momentum(*sums))
+                else:
+                    uvw.append(prescribed[k])
+            uvw = jnp.stack(uvw)
+
+            # shape-shape collisions (main.cpp:6705-6943): opponent-merged
+            # integrals in one field pass, impulses via lax.fori_loop —
+            # O(S*N) + O(1)-compile in the pair count (many-body ready)
+            if S > 1:
+                colls = merged_overlap_integrals(
+                    obs.chi_s, obs.sdf_s, obs.udef_s, uvw, obs.com, xc, yc)
+                lengths = jnp.asarray(
+                    [s.length for s in self.shapes], v.dtype)
+                uvw = pairwise_collision_update(
+                    colls, uvw, obs.mass, obs.inertia, obs.com, lengths)
+                for k in range(S):
+                    if not self.shapes[k].free:
+                        uvw = uvw.at[k].set(prescribed[k])
+
+            # implicit penalization update, winner shape per cell
+            # (main.cpp:6944-6979)
+            win = jnp.argmax(obs.chi_s, axis=0)
+            us = jnp.zeros_like(v_cf)
+            for k in range(S):
                 xr = xc - obs.com[k, 0]
                 yr = yc - obs.com[k, 1]
-                sums = penalization_integrals(
-                    v_cf, obs.chi_s[k], obs.udef_s[k], xr, yr,
-                    cfg.lam * dt, hsq)
-                uvw.append(solve_rigid_momentum(*sums))
-            else:
-                uvw.append(prescribed[k])
-        uvw = jnp.stack(uvw)
-
-        # shape-shape collisions (main.cpp:6705-6943): opponent-merged
-        # integrals in one field pass, impulses via lax.fori_loop —
-        # O(S*N) + O(1)-compile in the pair count (many-body ready)
-        if S > 1:
-            colls = merged_overlap_integrals(
-                obs.chi_s, obs.sdf_s, obs.udef_s, uvw, obs.com, xc, yc)
-            lengths = jnp.asarray(
-                [s.length for s in self.shapes], v.dtype)
-            uvw = pairwise_collision_update(
-                colls, uvw, obs.mass, obs.inertia, obs.com, lengths)
-            for k in range(S):
-                if not self.shapes[k].free:
-                    uvw = uvw.at[k].set(prescribed[k])
-
-        # implicit penalization update, winner shape per cell
-        # (main.cpp:6944-6979)
-        win = jnp.argmax(obs.chi_s, axis=0)
-        us = jnp.zeros_like(v_cf)
-        for k in range(S):
-            xr = xc - obs.com[k, 0]
-            yr = yc - obs.com[k, 1]
-            usk = jnp.stack([
-                uvw[k, 0] - uvw[k, 2] * yr + obs.udef_s[k, 0],
-                uvw[k, 1] + uvw[k, 2] * xr + obs.udef_s[k, 1],
-            ])
-            us = jnp.where(win == k, usk, us)
-        alpha = jnp.where(obs.chi > 0.5, 1.0 / (1.0 + cfg.lam * dt), 1.0)
-        v_cf = alpha * v_cf + (1.0 - alpha) * us
-        v = v_cf.transpose(1, 0, 2, 3)
+                usk = jnp.stack([
+                    uvw[k, 0] - uvw[k, 2] * yr + obs.udef_s[k, 0],
+                    uvw[k, 1] + uvw[k, 2] * xr + obs.udef_s[k, 1],
+                ])
+                us = jnp.where(win == k, usk, us)
+            alpha = jnp.where(obs.chi > 0.5, 1.0 / (1.0 + cfg.lam * dt), 1.0)
+            v_cf = alpha * v_cf + (1.0 - alpha) * us
+            v = v_cf.transpose(1, 0, 2, 3)
 
         udef = self._combined_udef(obs)  # [2,N,BS,BS]
         v, p_new, res, div_linf = self._pressure_project(
             v, pres, dt, h, hsq, t1v, t1s, tpois, corr, tcoarse,
             exact_poisson, maskv,
             chi=obs.chi, udef_b=udef.transpose(1, 0, 2, 3))
-        diag = {
-            "poisson_iters": res.iters,
-            "poisson_residual": res.residual,
-            "poisson_stalled": res.stalled,
-            "poisson_converged": res.converged,
-            "finite": self._finite_flag(v, p_new, maskv),
-            "umax": jnp.max(jnp.abs(v)),
-            "energy": self._energy(v, hsq),
-            "div_linf": div_linf,
-            "precond_cycles": self._precond_cycles(
-                res, tcoarse, exact_poisson),
-        }
+        diag = self._diag(v, p_new, res, div_linf, hsq, maskv, tcoarse,
+                          exact_poisson)
         return v, p_new, uvw, diag
 
     # ------------------------------------------------------------------
@@ -1850,7 +1852,6 @@ class AMRSim(ShapeHostMixin):
                 isinstance(hmin, jax.core.Tracer):
             return dt_from_umax(umax, hmin, self.cfg.nu, self.cfg.cfl)
         if self._dt_jit is None:
-            from . import tracing
             self._dt_jit = tracing.named_jit(
                 "amr.dt", jax.jit(
                     lambda u, h: dt_from_umax(u, h, self.cfg.nu,
@@ -1873,7 +1874,6 @@ class AMRSim(ShapeHostMixin):
         # would discard the pending poisson-iters scalar and disarm
         # the two-level trigger exactly on such drivers (code-review r4)
         if self._umax_jit is None:
-            from . import tracing
             self._umax_jit = tracing.named_jit(
                 "amr.umax", jax.jit(
                     lambda v, m: jnp.max(jnp.abs(v) * m)))
@@ -2098,7 +2098,6 @@ class AMRSim(ShapeHostMixin):
         # the top-level "tables" bucket, never nested under "adapt" (so
         # profiling.throughput can sum phases without double counting)
         self._refresh()
-        from . import tracing
         with (self.timers or NULL_TIMERS).phase("adapt"), \
                 tracing.span("regrid", step=int(self.step_count)):
             return self._adapt_impl()

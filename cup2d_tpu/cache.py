@@ -6,6 +6,14 @@ across process restarts of the same case. The CLI, bench and driver
 entry points all funnel through here; library users can call it once
 before building a sim. Safe to call repeatedly.
 
+The key includes operation metadata
+(``jax_compilation_cache_include_metadata_in_key``; JAX leaves it out
+by default): the step's scope names (``tracing.SCOPES``) live in that
+metadata, and an executable compiled before a scope was added or
+renamed and served from a shared directory would otherwise trace under
+its old names. Within one checkout the source, and so the key, is the
+same from run to run.
+
 Placement: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
 itself and this module sets no directory. Otherwise the cache lives at
 ONE fixed path inside the checkout (``<repo>/.jax_cache/xla``, listed
@@ -30,3 +38,4 @@ def enable_compilation_cache() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

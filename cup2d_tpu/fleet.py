@@ -381,32 +381,34 @@ class FleetSim:
         # dispatch on the BARE tier latch: the kernel_tier property
         # suffixes the BC token for telemetry and would never compare
         # equal to the bare strings here
-        if g._kernel_tier != "xla":
-            bf16 = g._kernel_tier == "pallas-fused-bf16"
-            bc = None if g.bc.is_free_slip else g.bc
-            if self.placement == "spatial":
-                # spatially sharded pool: the halo-mode kernel behind
-                # the explicit ppermute exchange — one executable for
-                # all shards, still member-batched on the leading axis
-                from .parallel.shard_halo import fused_advect_heun_sharded
-                vel = fused_advect_heun_sharded(
-                    vel, h, g.cfg.nu, dt, self.mesh, bc=bc, bf16=bf16)
+        with tracing.scope("advect"):
+            if g._kernel_tier != "xla":
+                bf16 = g._kernel_tier == "pallas-fused-bf16"
+                bc = None if g.bc.is_free_slip else g.bc
+                if self.placement == "spatial":
+                    # spatially sharded pool: the halo-mode kernel behind
+                    # the explicit ppermute exchange — one executable for
+                    # all shards, still member-batched on the leading axis
+                    from .parallel.shard_halo import fused_advect_heun_sharded
+                    vel = fused_advect_heun_sharded(
+                        vel, h, g.cfg.nu, dt, self.mesh, bc=bc, bf16=bf16)
+                else:
+                    # fused megakernel tier, member-batched: the kernel is
+                    # leading-dim agnostic with a per-member (afac, dfac)
+                    # row, so B members share ONE dispatch per substage
+                    vel = fused_advect_heun(
+                        vel, h, g.cfg.nu, dt, bc=bc, bf16=bf16)
             else:
-                # fused megakernel tier, member-batched: the kernel is
-                # leading-dim agnostic with a per-member (afac, dfac)
-                # row, so B members share ONE dispatch per substage
-                vel = fused_advect_heun(
-                    vel, h, g.cfg.nu, dt, bc=bc, bf16=bf16)
-        else:
-            vold = vel
-            for c in (0.5, 1.0):
-                # grid-level BC dispatch (bc.py): the default table is
-                # the legacy pad_vector verbatim; per-face tables paint
-                # their ghosts member-batched (dt4 broadcasts the
-                # per-member outflow extrapolation speed)
-                lab = g.pad_vector_field(vel, 3, dt4)
-                rhs = advect_diffuse_rhs(lab, 3, h, g.cfg.nu, dt4)
-                vel = heun_substage(vold, c, rhs, ih2)
+                vold = vel
+                for k, c in enumerate((0.5, 1.0)):
+                    # grid-level BC dispatch (bc.py): the default table
+                    # is the legacy pad_vector verbatim; per-face tables
+                    # paint their ghosts member-batched (dt4 broadcasts
+                    # the per-member outflow extrapolation speed)
+                    with tracing.scope(f"substage{k}"):
+                        lab = g.pad_vector_field(vel, 3, dt4)
+                        rhs = advect_diffuse_rhs(lab, 3, h, g.cfg.nu, dt4)
+                        vel = heun_substage(vold, c, rhs, ih2)
 
         # -- deltap pressure projection --
         if self.shaped:
@@ -415,23 +417,29 @@ class FleetSim:
             # so a shaped member matches its solo run to the documented
             # FMA bound): chi/us/udef ride the member axis as frozen
             # per-member obstacle fields
-            alpha = jnp.where(state.chi > 0.5,
-                              1.0 / (1.0 + g.cfg.lam * dt3), 1.0)
-            vel = alpha[:, None] * vel + (1.0 - alpha)[:, None] * state.us
-            b = g.poisson_rhs(vel, state.chi, state.udef, dt3)
-        else:
-            b = g.poisson_rhs(vel, None, None, dt3)
-        div_linf = jnp.max(jnp.abs(b), axis=(-2, -1)) * (dt / (h * h))
-        b = b - g.laplacian(state.pres)
-        if active is not None:
-            # zero the dead rows of the Poisson RHS: their initial
-            # residual is 0 <= max(tol, tol_rel*0), so the
-            # member-batched solvers mark them done AT ITERATION ZERO
-            # with inert diag (iters=0, residual=0, converged) and the
-            # existing converged-member freeze keeps their lanes exact
-            # identity through every sweep the live members need
-            b = jnp.where(active[:, None, None], b, jnp.zeros_like(b))
-        res = self._pressure_solve(b, exact_poisson)
+            with tracing.scope("penalize"):
+                alpha = jnp.where(state.chi > 0.5,
+                                  1.0 / (1.0 + g.cfg.lam * dt3), 1.0)
+                vel = alpha[:, None] * vel \
+                    + (1.0 - alpha)[:, None] * state.us
+        with tracing.scope("poisson_rhs"):
+            if self.shaped:
+                b = g.poisson_rhs(vel, state.chi, state.udef, dt3)
+            else:
+                b = g.poisson_rhs(vel, None, None, dt3)
+            div_linf = jnp.max(jnp.abs(b), axis=(-2, -1)) * (dt / (h * h))
+            b = b - g.laplacian(state.pres)
+            if active is not None:
+                # zero the dead rows of the Poisson RHS: their initial
+                # residual is 0 <= max(tol, tol_rel*0), so the
+                # member-batched solvers mark them done AT ITERATION
+                # ZERO with inert diag (iters=0, residual=0, converged)
+                # and the existing converged-member freeze keeps their
+                # lanes exact identity through every sweep the live
+                # members need
+                b = jnp.where(active[:, None, None], b, jnp.zeros_like(b))
+        with tracing.scope("poisson_solve"):
+            res = self._pressure_solve(b, exact_poisson)
         # bare latch again; under spatial placement the correction
         # kernel's strip DMA cannot be GSPMD-partitioned, so the
         # sharded pool keeps the XLA epilogue (pinned sharded==single)
@@ -447,12 +455,22 @@ class FleetSim:
             # freeze dead slots: state, diag and clock all read the
             # UNSTEPPED values (bit-exact slot preservation under
             # arbitrary co-member churn)
-            vel = jnp.where(active[:, None, None, None], vel, state.vel)
-            pres = jnp.where(active[:, None, None], pres, state.pres)
-            div_linf = jnp.where(active, div_linf,
-                                 jnp.zeros_like(div_linf))
+            with tracing.scope("project_correct"):
+                vel = jnp.where(active[:, None, None, None], vel,
+                                state.vel)
+                pres = jnp.where(active[:, None, None], pres, state.pres)
+                div_linf = jnp.where(active, div_linf,
+                                     jnp.zeros_like(div_linf))
+        diag = self._diag(vel, pres, res, div_linf, exact_poisson,
+                          active, dt_req)
+        return state._replace(vel=vel, pres=pres), diag
 
-        # -- per-member diag (the one batched pull's payload) --
+    @tracing.in_scope("diag")
+    def _diag(self, vel, pres, res, div_linf, exact_poisson, active,
+              dt_req) -> dict:
+        """Per-member diag (the one batched pull's payload)."""
+        g = self.grid
+        h = g.h
         umax = jnp.max(jnp.abs(vel), axis=(-3, -2, -1))
         vv = vel.astype(g.sum_dtype) if g.sum_dtype is not None else vel
         energy = 0.5 * h * h * jnp.sum(vv * vv, axis=(-3, -2, -1))
@@ -480,7 +498,7 @@ class FleetSim:
             # reaches the times accumulator
             diag["dt"] = jnp.where(active, dt_req,
                                    jnp.zeros_like(dt_req))
-        return state._replace(vel=vel, pres=pres), diag
+        return diag
 
     # -- driver contract (StepGuard-compatible) -----------------------
     def step_once(self, dt=None):

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
+
 
 def block_precond_matrix(bs: int, dtype=np.float64) -> np.ndarray:
     """P_inv = -inv(A_local), the negated inverse of the bs^2 x bs^2
@@ -250,6 +252,12 @@ class MultigridPreconditioner:
         return 1.0 / (ey[:, None] + ex[None, :] - 4.0)
 
     def _smooth(self, e, r, lvl, n, from_zero=False):
+        # the coarsest level's sweeps ARE this hierarchy's coarse solve
+        name = "mg_coarse" if lvl == len(self.shapes) - 1 else "mg_smooth"
+        with tracing.scope(name):
+            return self._sweeps(e, r, lvl, n, from_zero)
+
+    def _sweeps(self, e, r, lvl, n, from_zero):
         sharded = n > 0 and lvl < self.overlap_levels and r.ndim == 2
         if self.smoother == "strip" and n > 0 and not sharded:
             # strip tier (ISSUE 19): the whole sweep chain as ONE
@@ -286,9 +294,11 @@ class MultigridPreconditioner:
             e,
         )
 
+    @tracing.in_scope("mg_cycle")
     def __call__(self, r):
         return self._cycle(r.astype(self.dtype), 0).astype(self.out_dtype)
 
+    @tracing.in_scope("mg_cycle")
     def fcycle(self, r):
         """One F(ull)MG cycle: recurse to the coarsest level FIRST,
         prolongate each coarse solution as the next-finer level's
@@ -303,10 +313,12 @@ class MultigridPreconditioner:
                                 from_zero=True)
         # same full-weighting restriction (+x4 undivided scale) as the
         # V-cycle below
-        rows = r[..., 0::2, :] + r[..., 1::2, :]
-        rc = rows[..., :, 0::2] + rows[..., :, 1::2]
+        with tracing.scope("mg_transfer"):
+            rows = r[..., 0::2, :] + r[..., 1::2, :]
+            rc = rows[..., :, 0::2] + rows[..., :, 1::2]
         ec = self._fcycle(rc, lvl + 1)
-        e0 = jnp.repeat(jnp.repeat(ec, 2, axis=-2), 2, axis=-1)
+        with tracing.scope("mg_transfer"):
+            e0 = jnp.repeat(jnp.repeat(ec, 2, axis=-2), 2, axis=-1)
         return self._cycle(r, lvl, e0=e0)
 
     def _cycle(self, r, lvl, e0=None):
@@ -322,6 +334,15 @@ class MultigridPreconditioner:
         else:
             e = self._smooth(jnp.zeros_like(r), r, lvl, self.nu1,
                              from_zero=True)
+        with tracing.scope("mg_transfer"):
+            rc = self._restrict(r, e)
+        ec = self._cycle(rc, lvl + 1)
+        with tracing.scope("mg_transfer"):
+            # nearest prolongation (2x2 replicate)
+            e = e + jnp.repeat(jnp.repeat(ec, 2, axis=-2), 2, axis=-1)
+        return self._smooth(e, r, lvl, self.nu2)
+
+    def _restrict(self, r, e):
         res = r - self._lap(e)
         # full-weighting restriction (2x2 mean), x4 for the undivided
         # coarse operator scale, decomposed as row-pair sum then
@@ -333,11 +354,7 @@ class MultigridPreconditioner:
         # the cycle is leading-dim agnostic so the fleet path can run
         # one V-cycle over a whole [B, Ny, Nx] member batch.
         rows = res[..., 0::2, :] + res[..., 1::2, :]
-        rc = rows[..., :, 0::2] + rows[..., :, 1::2]
-        ec = self._cycle(rc, lvl + 1)
-        # nearest prolongation (2x2 replicate)
-        e = e + jnp.repeat(jnp.repeat(ec, 2, axis=-2), 2, axis=-1)
-        return self._smooth(e, r, lvl, self.nu2)
+        return rows[..., :, 0::2] + rows[..., :, 1::2]
 
 
 def dct_neumann_operators(ncy: int, ncx: int, dtype=np.float32):
@@ -504,7 +521,6 @@ def bicgstab(
     # trace-time only: tags the enclosing named executable's compile-
     # ledger entry with this solver component (tracing.py); a no-op
     # inside an already-compiled launch
-    from . import tracing
     tracing.note_component("poisson.bicgstab")
     if M is None:
         M = lambda v: v
@@ -562,6 +578,7 @@ def bicgstab(
         # freezes independently in the body below)
         return jnp.any(~s.done) & (s.it < max_iter)
 
+    @tracing.in_scope("krylov")
     def body(s: _State):
         frozen = s.done   # members already converged at loop entry
         rho_probe = dot(s.rhat, s.r)
@@ -794,7 +811,6 @@ def mg_solve(
     iters/residual/converged/stalled come back per-member [B].
     """
     # trace-time only — see the bicgstab note
-    from . import tracing
     tracing.note_component("poisson.mg_solve")
     dt_ = b.dtype
     if member_axis:
@@ -1023,6 +1039,7 @@ class FFTDiagPlan:
         return x.astype(b.dtype)
 
 
+@tracing.in_scope("fft_diag")
 def fft_diag_solve(
     A: Callable[[jnp.ndarray], jnp.ndarray],
     b: jnp.ndarray,
@@ -1048,7 +1065,6 @@ def fft_diag_solve(
     of the iterative solvers is trivially inert — there are no extra
     sweeps a frozen member could diverge under (tests/test_fleet.py).
     """
-    from . import tracing
     tracing.note_component("poisson.fft_diag_solve")
     dt_ = b.dtype
     if member_axis:
@@ -1157,10 +1173,10 @@ class ForestFASCycle:
                  omega: float = 0.8, nu_pre: int = 1, nu_post: int = 1,
                  leg_dtype=None):
         self.A = A
-        self.smooth_blocks = smooth_blocks
-        self.paint_fine = paint_fine
-        self.base_solve = base_solve
-        self.extract_all = extract_all
+        self.smooth_blocks = tracing.scoped("mg_smooth", smooth_blocks)
+        self.paint_fine = tracing.scoped("mg_transfer", paint_fine)
+        self.base_solve = tracing.scoped("mg_coarse", base_solve)
+        self.extract_all = tracing.scoped("mg_transfer", extract_all)
         self.cih2 = cih2
         self.nu_img = nu_img
         self.omega = omega
@@ -1177,6 +1193,7 @@ class ForestFASCycle:
         # solver-precision legs, bit-identical to the pre-tier cycle.
         self.leg_dtype = leg_dtype
 
+    @tracing.in_scope("mg_smooth")
     def _img_smooth(self, e, r, n: int, from_zero: bool = False):
         # damped Jacobi on the Neumann-ghost window image; interior
         # diag of the undivided 5-point operator is -4
@@ -1235,9 +1252,11 @@ class ForestFASCycle:
         e = corr if e is None else e + corr
         return self.smooth_blocks(e, r, self.nu_post)
 
+    @tracing.in_scope("mg_cycle")
     def __call__(self, r):
         return self._cycle(r, pre=True)
 
+    @tracing.in_scope("mg_cycle")
     def fcycle(self, r):
         # coarse-first opening for cold RHSes (fas-f): the base modes
         # dominate a cold deltap RHS (VERDICT r3 #9), so spend the
@@ -1249,6 +1268,7 @@ class ForestFASCycle:
 # Shared projection-correction epilogue (PR 9)
 # ---------------------------------------------------------------------------
 
+@tracing.in_scope("project_correct")
 def project_correct(x, pres_old, vel, h, dt, *, spmd_safe=False,
                     mean_axes=None, tier="xla", remove_mean=True,
                     grad_signs=None, periodic=None):

@@ -36,9 +36,6 @@ metrics-on run is bit-identical to metrics-off with equal
   gives totals, means, and counts per phase.
 - ``throughput(sim)``: the north-star cells*steps/s metric from a sim's
   counters (works for uniform and forest sims).
-- ``trace(logdir)``: context manager around `jax.profiler` for a full
-  TensorBoard-readable device trace (whole-block form; production runs
-  want :class:`TraceWindow`).
 """
 
 from __future__ import annotations
@@ -53,6 +50,8 @@ from typing import Optional
 import numpy as np
 
 import jax
+
+from . import tracing
 
 
 class PhaseTimers:
@@ -142,16 +141,6 @@ class _NullTimers:
 NULL_TIMERS = _NullTimers()
 
 
-@contextmanager
-def trace(logdir: str):
-    """TensorBoard device trace of the enclosed block."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
 # ---------------------------------------------------------------------------
 # windowed device tracing (CUP2D_TRACE=start:stop[:logdir])
 # ---------------------------------------------------------------------------
@@ -168,6 +157,14 @@ class TraceWindow:
     step range already passed. :meth:`close` stops a still-open trace
     at loop exit (a window past ``tend`` must not leave the profiler
     running)."""
+
+    # the profiler's Python tracer is OFF: the flight recorder's spans
+    # are in the trace themselves (tracing.set_profiling) and name the
+    # host's part of a step. Measured on the chip at 8192^2 (PERF.md,
+    # PR 24): with it on a traced step costs 4.0 ms of host against
+    # 3.3, starting the trace 96 ms against 48, and every idle gap is
+    # named by a Python frame instead of a span or a runtime event
+    PYTHON_TRACER_LEVEL = 0
 
     def __init__(self, start: int, stop: int, logdir: str = "trace"):
         if not (0 <= int(start) < int(stop)):
@@ -207,8 +204,11 @@ class TraceWindow:
         if self.active or self.done or step_count < self.start \
                 or step_count >= self.stop:
             return
-        jax.profiler.start_trace(self.logdir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = self.PYTHON_TRACER_LEVEL
+        jax.profiler.start_trace(self.logdir, profiler_options=opts)
         self.active = True
+        tracing.set_profiling(True)   # spans enter the trace from here
         from .resilience import record_event
         record_event(event="trace_start", step=step_count,
                      logdir=self.logdir)
@@ -224,6 +224,7 @@ class TraceWindow:
             self._stop(None)
 
     def _stop(self, step_count) -> None:
+        tracing.set_profiling(False)
         jax.profiler.stop_trace()
         self.active = False
         self.done = True
@@ -249,7 +250,6 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 def _on_compile(event, duration, **kw):
     if event == _COMPILE_EVENT:
-        from . import tracing
         if tracing.compiles_suppressed():
             # a flight-recorder memory-ledger re-lower is compiling:
             # ledger-internal, invisible to HostCounters AND to the

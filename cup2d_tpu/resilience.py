@@ -280,6 +280,21 @@ _PULL_KEYS = _HEALTH_KEYS + _INVARIANT_KEYS + (
     "poisson_iters", "precond_cycles", "dt_next", "dt")
 
 
+def _waited_for(sp, vals: dict, pend) -> None:
+    """What a ``verdict`` span waited for, from the host values its
+    pull has just read (no further pull): the solver's counts of the
+    step it fenced, so one line of spans.jsonl explains a long wait.
+    Fleet pulls are [B] vectors: the slowest member's counts."""
+    if sp is None:              # recorder off: the shared nullcontext
+        return
+    for attr, key in (("iters", "poisson_iters"),
+                      ("cycles", "precond_cycles")):
+        v = vals.get(key)
+        if v is not None:
+            sp.attrs[attr] = int(np.max(v))
+    sp.attrs["exact"] = bool(pend.exact)
+
+
 def _host_scalars(diag: dict, keys) -> dict:
     """The named diag entries as host scalars. On the CLI driver paths
     every value is already host-side (batched into the step's one
@@ -822,11 +837,12 @@ class StepGuard:
 
     def _resolve_oldest(self) -> dict:
         pend = self._pendings.pop(0)
-        with tracing.span("verdict", step=int(pend.step0)):
+        with tracing.span("verdict", step=int(pend.step0)) as sp:
             # the ONE batched pull (host-side already on the eager
             # paths) — where the diag is on device this span fences,
             # so its interval is fence-accurate by construction
             vals = _host_scalars(pend.diag, _PULL_KEYS)
+            _waited_for(sp, vals, pend)
             v = self._verdict_from(vals, pend.step0)
         if v.ok:
             return self._commit(pend, vals)
@@ -1364,8 +1380,9 @@ class FleetStepGuard(StepGuard):
     # -- vectorized verdict -------------------------------------------
     def _resolve_oldest(self) -> dict:
         pend = self._pendings.pop(0)
-        with tracing.span("verdict", step=int(pend.step0)):
+        with tracing.span("verdict", step=int(pend.step0)) as sp:
             vals = _host_scalars(pend.diag, _PULL_KEYS)   # [B] vectors
+            _waited_for(sp, vals, pend)
             verdicts = self._member_verdicts(vals, pend.step0)
             bad = [m for m, v in enumerate(verdicts) if not v.ok]
         if not bad:

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .config import SimConfig
 from .models import DiskShape, FishShape
 from .ops.collision import merged_overlap_integrals, \
@@ -101,7 +102,6 @@ class Simulation(ShapeHostMixin):
         for s in self.shapes:
             w = int(np.ceil(1.25 * s.length / g.h)) + 12
             self._wins.append((min(w, g.nx), min(w, g.ny)))
-        from . import tracing
         self._rasterize = tracing.named_jit(
             "sim.rasterize", jax.jit(self._rasterize_impl))
         # donate the state (arg 0) so pass-through fields aren't copied
@@ -257,18 +257,11 @@ class Simulation(ShapeHostMixin):
     # ------------------------------------------------------------------
     # device: one flow step (main.cpp:6607-7187)
     # ------------------------------------------------------------------
-    def _flow_step_impl(self, state: FlowState, obs: ObstacleFields,
-                        prescribed_uvw, dt, exact_poisson=False):
+    def _penalize(self, obs, prescribed_uvw, vel, dt, x, y):
         g = self.grid
         cfg = self.cfg
         h = g.h
         S = len(self.shapes)
-        x, y = g.cell_centers()
-        x = jnp.asarray(x, dtype=g.dtype)
-        y = jnp.asarray(y, dtype=g.dtype)
-
-        vel = g.advect_heun(state.vel, dt)
-
         # rigid momentum solve per shape (main.cpp:6643-6704)
         uvw = []
         for k in range(S):
@@ -320,6 +313,22 @@ class Simulation(ShapeHostMixin):
         else:
             us = jnp.zeros_like(vel)
             udef = jnp.zeros_like(vel)
+        return vel, us, udef, uvw
+
+    def _flow_step_impl(self, state: FlowState, obs: ObstacleFields,
+                        prescribed_uvw, dt, exact_poisson=False):
+        g = self.grid
+        x, y = g.cell_centers()
+        x = jnp.asarray(x, dtype=g.dtype)
+        y = jnp.asarray(y, dtype=g.dtype)
+
+        vel = g.advect_heun(state.vel, dt)
+
+        # the shapes' share of the step: rigid momentum, collisions,
+        # the Brinkman update
+        with tracing.scope("penalize"):
+            vel, us, udef, uvw = self._penalize(obs, prescribed_uvw, vel,
+                                                dt, x, y)
 
         vel, pres, res, div_linf = g.project(
             vel, state.pres, obs.chi, udef, dt, exact_poisson)

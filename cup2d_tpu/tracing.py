@@ -12,7 +12,13 @@ FleetServer churn):
    lock-free per process into a bounded ring, flushed through the
    EventLog writer (cold path: shutdown or ring-full), exported to a
    Chrome/Perfetto ``trace.json`` by ``python -m cup2d_tpu.post
-   --trace``. Spans are host-clock intervals between points the run
+   --trace``. Inside a ``CUP2D_TRACE`` window (and only there:
+   ``profiling.TraceWindow`` raises :func:`set_profiling`) every span
+   additionally opens a ``jax.profiler.TraceAnnotation`` named
+   ``cup2d:<name>`` (``step`` a ``StepTraceAnnotation`` with its
+   ``step_num``), so the spans sit in the profiler's trace on the
+   device's clock and an idle gap of the chip names the span it fell
+   in. Spans are host-clock intervals between points the run
    already passes through: where a phase already fences (the verdict's
    batched pull, the snapshot's host gather) the span is
    fence-accurate; a ``dispatch`` span times enqueue cost only — the
@@ -47,13 +53,24 @@ FleetServer churn):
    client and pool-wide; ``FleetServer`` drives it from its existing
    submit/admit/step boundaries (host clocks only).
 
+5. **Device scopes** — :data:`SCOPES` is the one vocabulary of the
+   step program's parts and :func:`scope` the ``jax.named_scope`` that
+   writes a name into the ``op_name`` of every operation traced under
+   it (uniform/poisson/sim/fleet/amr apply it where the work is
+   traced). Metadata only: the lowered program is byte-identical with
+   and without it (tests/test_scopes.py), the names reach the TPU
+   trace as each operation's ``tf_op``, and ``benchmark/xplane_meta.py``
+   sums device time by them.
+
 Import discipline: this module imports nothing from the package at
 module level (resilience/fleet/profiling all import it), and jax only
-inside the cold-path memory capture.
+inside the cold-path memory capture, :func:`scope` (trace time) and an
+open profiler window's annotations.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import time
@@ -69,6 +86,48 @@ _RECORDER: Optional["FlightRecorder"] = None
 _LABEL_STACK: list = []     # innermost active named_jit label
 _SUPPRESS = [0]             # >0: backend compiles are ledger-internal
 _NULL = nullcontext()       # shared, reentrant — the recorder-off span
+_PROFILING = False          # a CUP2D_TRACE window is open (TraceWindow)
+
+# the step program's parts, as they read in a device trace: top-level
+# scopes in step order (the Heun substages nest in advect), then the
+# ones that nest inside poisson_solve
+SCOPES = ("advect", "substage0", "substage1", "penalize", "poisson_rhs",
+          "poisson_solve", "project_correct", "diag",
+          "krylov", "mg_cycle", "mg_smooth", "mg_transfer", "mg_coarse",
+          "fft_diag")
+
+
+def scope(name: str):
+    """``jax.named_scope`` of a :data:`SCOPES` name (``advect`` also as
+    ``advect/substage<k>``): operation metadata only, no operation."""
+    import jax
+    return jax.named_scope(name)
+
+
+def scoped(name: str, fn):
+    """``fn``, traced under :func:`scope` ``name`` wherever it is
+    called (for closures that are handed on as operators)."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with scope(name):
+            return fn(*args, **kwargs)
+    return run
+
+
+def in_scope(name: str):
+    """:func:`scoped` as a decorator: ``@tracing.in_scope("diag")``."""
+    return functools.partial(scoped, name)
+
+
+def set_profiling(on: bool) -> None:
+    """``TraceWindow`` tells the spans that the profiler is running:
+    while up, each span also opens a ``cup2d:<name>`` annotation."""
+    global _PROFILING
+    _PROFILING = bool(on)
+
+
+def profiling() -> bool:
+    return _PROFILING
 
 
 def recorder() -> Optional["FlightRecorder"]:
@@ -139,7 +198,7 @@ class _SpanCtx:
     list ops; the record lands in the recorder's ring at exit (LIFO —
     spans close in nesting order, enforced by ``with`` scoping)."""
 
-    __slots__ = ("_r", "name", "attrs", "_wall", "_t0")
+    __slots__ = ("_r", "name", "attrs", "_wall", "_t0", "_ann")
 
     def __init__(self, r: "FlightRecorder", name: str, attrs: dict):
         self._r = r
@@ -148,12 +207,27 @@ class _SpanCtx:
 
     def __enter__(self):
         self._r._stack.append(self)
+        self._ann = None
+        if _PROFILING:
+            # the span on the profiler's clock, for the window's steps
+            # only (the attrs known at entry; later ones reach
+            # spans.jsonl alone)
+            import jax
+            if self.name == "step":
+                self._ann = jax.profiler.StepTraceAnnotation(
+                    "cup2d:step", step_num=self.attrs.get("step", 0))
+            else:
+                self._ann = jax.profiler.TraceAnnotation(
+                    "cup2d:" + self.name, **self.attrs)
+            self._ann.__enter__()
         self._wall = time.time()          # cross-process alignment
         self._t0 = time.perf_counter()    # duration
         return self
 
     def __exit__(self, etype, _exc, _tb):
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         r = self._r
         r._stack.pop()
         attrs = self.attrs

@@ -32,6 +32,7 @@ from .bc import (
     periodic_axes,
     pressure_signs,
 )
+from . import tracing
 from .config import SimConfig
 from .ops.stencil import (
     advect_diffuse_rhs,
@@ -532,20 +533,23 @@ class UniformGrid:
         if self._kernel_tier != "xla":
             bf16 = self._kernel_tier == "pallas-fused-bf16"
             bc = None if self.bc.is_free_slip else self.bc
-            if self._mesh is not None:
-                from .parallel.shard_halo import fused_advect_heun_sharded
-                return fused_advect_heun_sharded(
-                    vel, self.h, self.cfg.nu, dt, self._mesh,
-                    bc=bc, bf16=bf16)
-            from .ops.pallas_kernels import fused_advect_heun
-            return fused_advect_heun(
-                vel, self.h, self.cfg.nu, dt, bc=bc, bf16=bf16)
+            with tracing.scope("advect"):
+                if self._mesh is not None:
+                    from .parallel.shard_halo import \
+                        fused_advect_heun_sharded
+                    return fused_advect_heun_sharded(
+                        vel, self.h, self.cfg.nu, dt, self._mesh,
+                        bc=bc, bf16=bf16)
+                from .ops.pallas_kernels import fused_advect_heun
+                return fused_advect_heun(
+                    vel, self.h, self.cfg.nu, dt, bc=bc, bf16=bf16)
         ih2 = 1.0 / (self.h * self.h)
         vold = vel
-        for c in (0.5, 1.0):
-            lab = self.pad_vector_field(vel, 3, dt)
-            rhs = advect_diffuse_rhs(lab, 3, self.h, self.cfg.nu, dt)
-            vel = heun_substage(vold, c, rhs, ih2)
+        for k, c in enumerate((0.5, 1.0)):
+            with tracing.scope(f"advect/substage{k}"):
+                lab = self.pad_vector_field(vel, 3, dt)
+                rhs = advect_diffuse_rhs(lab, 3, self.h, self.cfg.nu, dt)
+                vel = heun_substage(vold, c, rhs, ih2)
         return vel
 
     def project(self, vel, pres_old, chi, udef, dt, exact_poisson=False):
@@ -560,12 +564,14 @@ class UniformGrid:
         (zero extra field passes; the telemetry watchdog's second
         invariant, resilience.PhysicsWatchdog)."""
         h = self.h
-        ih2 = 1.0 / (h * h)
-        b = self.poisson_rhs(vel, chi, udef, dt)
-        # |b| = (h/2dt) * |undivided div|; physical div = undivided/(2h)
-        div_linf = jnp.max(jnp.abs(b)) * (dt / (h * h))
-        b = b - self.laplacian(pres_old)
-        res = self.pressure_solve(b, exact=exact_poisson)
+        with tracing.scope("poisson_rhs"):
+            b = self.poisson_rhs(vel, chi, udef, dt)
+            # |b| = (h/2dt) * |undivided div|; physical div =
+            # undivided/(2h)
+            div_linf = jnp.max(jnp.abs(b)) * (dt / (h * h))
+            b = b - self.laplacian(pres_old)
+        with tracing.scope("poisson_solve"):
+            res = self.pressure_solve(b, exact=exact_poisson)
         # any-Dirichlet tables (outflow face) pin the pressure level:
         # the operator is non-singular and the legacy mean removal
         # would shift the anchored solution — skip it (bc.py docs)
@@ -599,6 +605,7 @@ class UniformGrid:
             return 2 * res.iters
         return jnp.zeros_like(res.iters)
 
+    @tracing.in_scope("diag")
     def step_diag(self, vel, pres, res, div_linf=None,
                   exact=False) -> dict:
         umax = jnp.max(jnp.abs(vel))
@@ -649,8 +656,10 @@ class UniformGrid:
         if obstacle_terms:
             # Brinkman penalization implicit update (main.cpp:6961-6977):
             # alpha = chi>0.5 ? 1/(1+lambda dt) : 1; u <- alpha u + (1-alpha) u_s
-            alpha = jnp.where(state.chi > 0.5, 1.0 / (1.0 + cfg.lam * dt), 1.0)
-            vel = alpha * vel + (1.0 - alpha) * state.us
+            with tracing.scope("penalize"):
+                alpha = jnp.where(state.chi > 0.5,
+                                  1.0 / (1.0 + cfg.lam * dt), 1.0)
+                vel = alpha * vel + (1.0 - alpha) * state.us
 
         vel, pres, res, div_linf = self.project(
             vel, state.pres,
@@ -691,7 +700,6 @@ class UniformSim:
         # return value; the donated input buffers are invalidated.
         # UniformSim is the obstacle-free driver, so the obstacle terms
         # are statically dropped.
-        from . import tracing
         self._step = tracing.named_jit(
             "uniform.step", jax.jit(
                 self.grid.step, donate_argnums=(0,),

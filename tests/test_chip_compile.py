@@ -162,3 +162,53 @@ def test_jacobi_halo_sweep_compiles_on_4_chip_mesh(mesh4):
         fn, jax.ShapeDtypeStruct((N, N), F32, sharding=split),
         jax.ShapeDtypeStruct((N, N), F32, sharding=split))
     assert "collective-permute" in compiled.as_text()
+
+
+def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
+    """The whole cavity step (the benchmark's cell: 8192^2 f32, XLA
+    tier, bicgstab + multigrid), compiled for the described v5e: the
+    TPU compiler's fusions keep the step's scope names in ``op_name``
+    — what the chip's trace then shows as each operation's ``tf_op``
+    and benchmark/xplane_meta.py sums device time by. The no-chip
+    evidence that the names survive fusion (PR 24)."""
+    import re
+
+    from cup2d_tpu import tracing
+    from cup2d_tpu.config import SimConfig
+    from cup2d_tpu.uniform import FlowState, UniformGrid
+
+    with jax.enable_x64(False):
+        cfg = SimConfig(bpdx=1, bpdy=1, level_max=1, level_start=0,
+                        extent=1.0, dtype="float32", nu=1e-4, cfl=0.4,
+                        poisson_tol=1e-4, poisson_tol_rel=1e-3)
+        grid = UniformGrid(cfg, 10, bc=cavity_table(1.0))
+        assert (grid.ny, grid.nx) == (N, N)
+
+        def field(*lead):
+            return jax.ShapeDtypeStruct(lead + (N, N), F32,
+                                        sharding=one_chip)
+
+        state = FlowState(vel=field(2), pres=field(), chi=field(),
+                          us=field(2), udef=field(2))
+
+        def step(state, dt):
+            return grid.step(state, dt, exact_poisson=False,
+                             obstacle_terms=False)
+
+        text = jax.jit(step, donate_argnums=(0,)).lower(
+            state, jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+        ).compile().as_text()
+    fusions = re.findall(r' fusion\(.*op_name="([^"]*)"', text)
+    assert len(fusions) > 50
+
+    def scope_of(name):
+        return [p for p in name.split("/") if p in tracing.SCOPES]
+
+    named = [n for n in fusions if scope_of(n)]
+    assert len(named) >= 0.95 * len(fusions), sorted(
+        set(fusions) - set(named))[:10]
+    seen = {s for n in named for s in scope_of(n)}
+    assert {"advect", "substage0", "substage1", "poisson_rhs",
+            "poisson_solve", "krylov", "mg_cycle", "mg_smooth",
+            "mg_transfer", "mg_coarse", "project_correct",
+            "diag"} <= seen, seen

@@ -309,12 +309,24 @@ def test_uniform_sim_compiles_fully_attributed():
 # bit-identical with equal device_gets AND equal jit_compiles
 # ---------------------------------------------------------------------------
 
-def test_tracing_zero_overhead_uniform(tmp_path, monkeypatch):
+@pytest.mark.parametrize("window", [False, True],
+                         ids=["recorder", "recorder+trace-window"])
+def test_tracing_zero_overhead_uniform(tmp_path, monkeypatch, window):
+    """``window``: the traced twin additionally runs inside an open
+    ``CUP2D_TRACE`` window (PR 24), so every span also opens its
+    ``cup2d:*`` profiler annotation — still bit-identical, still equal
+    pulls and compiles."""
+    from cup2d_tpu.profiling import TraceWindow
+
     def run(traced, tag):
-        flight = None
+        flight = tw = None
         if traced:
             sink = EventLog(str(tmp_path / f"spans_{tag}.jsonl"))
             flight = FlightRecorder(sink=sink).install()
+            if window:
+                tw = TraceWindow(0, 99, str(tmp_path / f"trace_{tag}"))
+                tw.maybe_start(0)
+                assert tracing.profiling()
         counters = HostCounters().install()
         pulls = {"n": 0}
         real_get = jax.device_get
@@ -333,6 +345,9 @@ def test_tracing_zero_overhead_uniform(tmp_path, monkeypatch):
                 guard.drain()
         finally:
             counters.uninstall()
+            if tw is not None:
+                tw.close()
+                assert not tracing.profiling()
             if flight is not None:
                 flight.close()
         return (np.asarray(sim.state.vel), np.asarray(sim.state.pres),
