@@ -244,6 +244,11 @@ class Run:
             "poisson_mode": last.get("poisson_mode"),
             "kernel_tier": last.get("kernel_tier"),
             "smoother_tier": last.get("smoother_tier"),
+            # how many levels of a uniform hierarchy run the two fused
+            # legs (the strip tier's "does it engage"; None: a forest)
+            "fused_levels": getattr(getattr(getattr(
+                self.sim, "grid", None), "mg", None),
+                "fused_levels", None),
             "bc_table": last.get("bc_table"),
             "poisson_iters": [r["poisson_iters"] for r in self.records],
             "hbm_peak_bytes": last.get("hbm_peak_bytes"),
@@ -356,6 +361,16 @@ def phase1(sz: dict) -> None:
 CAVITY_TABLE = "ns,ns,ns,ns(1,0)"
 
 
+def _picked(on_chip: str, on_cpu: str = "xla") -> str:
+    """The smoother tier a wall-bounded single-device uniform hierarchy
+    picks for itself: the fused strip legs on the chip (``strip+bf16``
+    under Krylov, whose preconditioner cycle stores bf16), XLA in a
+    CPU rehearsal. Periodic tables, mesh runs and the forest stay
+    ``xla`` everywhere."""
+    import jax
+    return on_chip if jax.devices()[0].platform == "tpu" else on_cpu
+
+
 def _cavity_argv(sz: dict) -> list:
     return ["-case", "cavity", "-level", str(sz["uniform_level"]),
             "-maxSteps", str(sz["uniform_steps"])]
@@ -364,7 +379,9 @@ def _cavity_argv(sz: dict) -> list:
 def phase2(sz: dict):
     """Uniform, walls. Returns the XLA-tier final velocity for phase 5."""
     run = Run("2-cavity", _cavity_argv(sz), {})
-    checks = run.checks(sz["uniform_steps"], bc_table=CAVITY_TABLE)
+    checks = run.checks(sz["uniform_steps"],
+                        smoother_tier=_picked("strip+bf16"),
+                        bc_table=CAVITY_TABLE)
     vel = run.final_vel()
     checks["lid_drives_flow"] = float(np.abs(vel).max()) > 1e-3
     run.finish(checks, grid=run.grid())
@@ -479,13 +496,15 @@ def phase5(sz: dict, refs: dict) -> None:
     pallas, bf16 = {"CUP2D_PALLAS": "1"}, {"CUP2D_PREC": "bf16"}
     fas = {"CUP2D_POIS": "fas"}
     tiers = (
-        ("5a-cavity-pallas", pallas, "pallas-fused", "xla", F32_BAND),
+        ("5a-cavity-pallas", pallas, "pallas-fused",
+         _picked("strip+bf16"), F32_BAND),
         ("5b-cavity-pallas-bf16", {**pallas, **bf16},
-         "pallas-fused-bf16", "xla", BF16_BAND),
+         "pallas-fused-bf16", _picked("strip+bf16"), BF16_BAND),
         ("5c-cavity-pallas-fas", {**pallas, **fas},
-         "pallas-fused", "strip", F32_BAND),
+         "pallas-fused", _picked("strip"), F32_BAND),
         ("5d-cavity-pallas-fas-bf16", {**pallas, **fas, **bf16},
-         "pallas-fused-bf16", "strip+bf16", BF16_BAND),
+         "pallas-fused-bf16", _picked("strip+bf16", "xla+bf16"),
+         BF16_BAND),
     )
     for name, env, ktier, stier, band in tiers:
         if "cavity" not in refs:
@@ -581,7 +600,9 @@ def mesh_uniform(sz: dict, chips: int) -> None:
     """ShardedUniformSim (XLA tier, then the halo-mode kernel) against
     UniformSim on cavity."""
     solo = Run("m0-cavity-1dev", _cavity_argv(sz), {})
-    checks = solo.checks(sz["uniform_steps"], bc_table=CAVITY_TABLE)
+    checks = solo.checks(sz["uniform_steps"],
+                         smoother_tier=_picked("strip+bf16"),
+                         bc_table=CAVITY_TABLE)
     ref = solo.final_vel()
     solo.finish(checks, grid=solo.grid(),
                 devices=_spans(solo.sim.state.vel))

@@ -173,12 +173,14 @@ LEADING_DIM_SCOPES = {
     # fused_jacobi_sweeps / fused_block_jacobi_update (ISSUE 19): the
     # strip-smoother wrappers flatten any leading shape to the same
     # [L, ...] layout before dispatch (uniform [ny,nx], fleet
-    # [B,ny,nx], forest-lab [B,bs,bs] callers share one executable)
+    # [B,ny,nx], forest-lab [B,bs,bs] callers share one executable);
+    # fused_mg_down / fused_mg_up (ISSUE 26) ride the same pipeline
     "ops/pallas_kernels.py": ("fused_advect_heun", "fused_lab_rhs",
                               "fused_correction", "_per_member",
                               "advect_diffuse_rhs_pallas",
                               "_fused_substage_sharded",
-                              "fused_jacobi_sweeps",
+                              "fused_jacobi_sweeps", "fused_mg_down",
+                              "fused_mg_up", "_strip_pipeline",
                               "fused_block_jacobi_update"),
     # the sharded megakernel wrapper (ISSUE 16): flattens any leading
     # shape before entering shard_map, so fleet spatial pools (L=B) and

@@ -167,8 +167,12 @@ class FleetSim:
         # bc: the pool-wide per-face BCTable (bc.py) — every member of
         # a fleet shares ONE table (the slot-pool executable bakes the
         # edge treatment in; FleetServer._admit refuses mismatches)
+        # a mesh placement hands the step to the partitioner, which
+        # cannot split a strip pipeline: those hierarchies stay XLA
         self.grid = UniformGrid(cfg, level,
-                                spmd_safe=(placement == "spatial"), bc=bc)
+                                spmd_safe=(placement == "spatial"), bc=bc,
+                                mg_smoother=(None if mesh is None
+                                             else "xla"))
         g = self.grid
         self.state = stack_states([g.zero_state()
                                    for _ in range(self.members)])

@@ -59,6 +59,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1000,39 +1001,133 @@ def jacobi_strip_supported(ny: int, nx: int, dtype, n: int) -> bool:
     return 1 <= n <= _JACOBI_MAX_SWEEPS
 
 
-def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
-                          store_dtype, r_ref, *rest):
-    """Time-skewed n-sweep Jacobi chain over row strips of one member.
+# fused multigrid legs (ISSUE 26): strip height of the down/up-leg
+# pipelines. The restricted strip is by/2 rows and must itself be a
+# whole sublane tile of the storage dtype (bf16: 16), so one height
+# serves both precisions.
+_BY_LEG = 32
+# lane width of one column-pair chunk: 256 fine lanes <-> 128 coarse
+_LEG_CHUNK = 256
 
-    Grid (L, nstr + nsw - 1): at step i, sweep level k (k = 1..nsw)
-    computes strip j = i - (k - 1) — level k-1's strip j+1 is produced
-    earlier in the SAME program (the Python level loop emits them in
-    order), so every neighbor a sweep needs is resident by the time it
-    runs. Input rings follow the megakernel's exactly-once DMA
-    discipline; intermediate sweeps live in plain 4-slot VMEM rings
-    (compute writes, no DMA); the final sweep writes the out block,
-    whose index map revisits block j = max(i - nsw + 1, 0) so Pallas
-    flushes it exactly once, after the level-nsw write. All arithmetic
-    is f32 (accumulate tier); strips store at ``store_dtype`` — for
-    bf16 legs that is the one rounding per sweep the XLA bf16 chain
-    also pays, for f32 the chain is term-for-term the XLA expression
-    (the ~1-ulp parity contract, tests/test_strip_smoother.py)."""
-    if from_zero:
-        e_ref = None
-        out_ref = rest[0]
-        sc = rest[1:]
-    else:
-        e_ref, out_ref = rest[0], rest[1]
-        sc = rest[2:]
-        ering, esems = sc[0], sc[1]
-        sc = sc[2:]
-    rslots = nsw + 2
-    rring, rsems = sc[0], sc[1]
-    lvls = sc[2:]                     # nsw-1 intermediate sweep rings
+
+def mg_leg_supported(ny: int, nx: int, dtype, n: int) -> bool:
+    """Gate for ``fused_mg_down`` / ``fused_mg_up`` at one MG level:
+    the sweep-chain gate plus whole 32-row strips and whole 256-lane
+    column-pair chunks (the coarse strip is then a full (16, 128)
+    tile in either storage dtype). Like ``jacobi_strip_supported`` a
+    False is a silent fall-back to the XLA legs."""
+    return (jacobi_strip_supported(ny, nx, dtype, n)
+            and ny % _BY_LEG == 0 and nx % _LEG_CHUNK == 0)
+
+
+def _pair_matrix(dtype=jnp.bfloat16):
+    """The constant 0/1 [256, 128] tile S with S[i, i // 2] = 1:
+    ``x @ S`` sums adjacent lane pairs (full-weighting restriction
+    along x), ``y @ S.T`` replicates every lane twice (nearest
+    prolongation along x). Exact on the MXU: every product is x * 1
+    and the accumulator is f32."""
+    i = np.arange(_LEG_CHUNK)[:, None]
+    k = np.arange(_LEG_CHUNK // 2)[None, :]
+    # a numpy constant: baked into the program, not recomputed a call
+    return jnp.asarray((i // 2 == k).astype(np.float32), dtype)
+
+
+def _dot_split(x, sel, npass):
+    """``x @ sel`` for a 0/1 ``sel`` with the f32 operand split into
+    ``npass`` bf16 terms (hi, mid, lo): each pass is exact, so three
+    passes carry all 24 mantissa bits of an f32 and one carries a
+    bf16 operand whole. The MXU has no other work in this program."""
+    f32 = jnp.float32
+    acc = None
+    rem = x
+    for p in range(npass):
+        part = rem.astype(jnp.bfloat16)
+        d = jnp.dot(part, sel, preferred_element_type=f32)
+        acc = d if acc is None else acc + d
+        if p + 1 < npass:
+            rem = rem - part.astype(f32)
+    return acc
+
+
+def _lane_chunks_matmul(x, sel, npass):
+    """Apply ``sel`` ([cin, cout]) to every ``cin``-lane chunk of
+    ``x`` [m, n]: the chunks are stacked along sublanes (whole vregs
+    relabelled, nothing moves), multiplied in ONE MXU call with M =
+    m * n / cin, and unstacked along lanes the same way."""
+    m, n = x.shape
+    cin, cout = sel.shape
+    nch = n // cin
+    if nch == 1:
+        return _dot_split(x, sel, npass)
+    xs = jnp.concatenate(
+        [x[:, c * cin:(c + 1) * cin] for c in range(nch)], axis=0)
+    ys = _dot_split(xs, sel, npass)
+    return jnp.concatenate(
+        [ys[c * m:(c + 1) * m, :] for c in range(nch)], axis=1)
+
+
+def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
+                          prolong, restrict, store_dtype, r_ref, *rest):
+    """Time-skewed n-sweep Jacobi chain over row strips of one member,
+    optionally with a prolong-add head and a residual-restrict tail
+    (the two fused legs of a V-cycle level, ISSUE 26).
+
+    Grid (L, nstr + nsw - 1 + head + tail): at step i, sweep level k
+    (k = 1..nsw) computes strip j = i - (k - 1) - head — level k-1's
+    strip j+1 is produced earlier in the SAME program (the Python
+    level loop emits them in order), so every neighbor a sweep needs
+    is resident by the time it runs. Input rings follow the
+    megakernel's exactly-once DMA discipline; intermediate sweeps live
+    in plain 4-slot VMEM rings (compute writes, no DMA); the final
+    sweep writes the out block, whose index map revisits block
+    j = max(i - nsw + 1 - head, 0) so Pallas flushes it exactly once,
+    after the level-nsw write. All arithmetic is f32 (accumulate
+    tier); strips store at ``store_dtype`` — for bf16 legs that is the
+    one rounding per sweep the XLA bf16 chain also pays, for f32 the
+    chain is term-for-term the XLA expression (the ~1-ulp parity
+    contract, tests/test_strip_smoother.py).
+
+    ``prolong`` (the up-leg's head, level 0 at strip j = i): the
+    coarse correction's strip [by/2, nx/2] is replicated 2x2 (lanes on
+    the MXU against the constant pair matrix, rows by strided stores)
+    and added to e before the first sweep reads it. ``restrict`` (the
+    down-leg's tail, one more level after sweep nsw at strip
+    j = i - nsw - head): the residual r - lap(e) of the smoothed e,
+    summed over 2x2 cells (rows by strided loads, lanes on the MXU) —
+    the x4 of the undivided coarse operator is that plain sum."""
+    rest = list(rest)
+    # e comes in as pipelined blocks under the prolong head (no halo
+    # is read before the head has written its ring), through the
+    # manual HBM ring otherwise, and not at all from zero
+    ring_e = not prolong and not from_zero
+    if prolong:
+        e_ref, ec_ref = rest.pop(0), rest.pop(0)
+    elif ring_e:
+        e_ref = rest.pop(0)
+    if restrict:
+        sel_ref = rest.pop(0)
+    if prolong:
+        selt_ref = rest.pop(0)
+    out_ref = rest.pop(0)
+    if restrict:
+        rc_ref = rest.pop(0)
+    if ring_e:
+        ering, esems = rest.pop(0), rest.pop(0)
+    head = 1 if prolong else 0
+    tail = 1 if restrict else 0
+    rslots = nsw + 2 + head + tail
+    rring, rsems = rest.pop(0), rest.pop(0)
+    nrings = nsw - 1 + head + tail
+    lvls = [rest.pop(0) for _ in range(nrings)]
+    # ring of level k's output (k = 0 the head, nsw the last sweep)
+    ring_of = {k: lvls[k - 1 + head] for k in range(1 - head,
+                                                   nsw + tail)}
+    buf = rest.pop(0) if (prolong or restrict) else None
 
     l = pl.program_id(0)
     i = pl.program_id(1)
     f32 = jnp.float32
+    hb = by // 2
 
     def rdma(k):
         slot = _rem(k, rslots)
@@ -1040,9 +1135,10 @@ def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
             r_ref.at[l, pl.ds(k * by, by), :],
             rring.at[slot], rsems.at[slot])
 
-    # r strip j is first consumed by sweep 1 at step j and last by
-    # sweep nsw at step j + nsw - 1; nsw+2 slots keep the window plus
-    # a one-step prefetch live
+    # r strip j arrives at step j, is first consumed by sweep 1 at
+    # step j + head and last by sweep nsw (or the tail) at step
+    # j + nsw - 1 + head + tail; the slots keep that window plus a
+    # one-step prefetch live
     @pl.when(i == 0)
     def _():
         rdma(0).start()
@@ -1055,7 +1151,7 @@ def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
     def _():
         rdma(i).wait()
 
-    if not from_zero:
+    if ring_e:
         def edma(k):
             slot = _rem(k, 4)
             return pltpu.make_async_copy(
@@ -1084,36 +1180,77 @@ def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
 
     sx_lo, sx_hi, sy_lo, sy_hi = gs
     zero = jnp.zeros((), f32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, nx), 1)
+    rowl = jax.lax.broadcasted_iota(jnp.int32, (by, 1), 0)
+    first_col, last_col = col == 0, col == nx - 1
 
     def corr_inv(j):
         """The level's signed wall-diagonal row, from GLOBAL indices —
         the exact values (and groupings) of stencil._edge_ones /
         MultigridPreconditioner._inv_diag (2-D iota: Mosaic has no
-        1-D iota)."""
-        col = jax.lax.broadcasted_iota(jnp.int32, (by, nx), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (by, nx), 0) + j * by
-        ex = jnp.where(col == 0, jnp.asarray(sx_lo, f32),
-                       jnp.where(col == nx - 1, jnp.asarray(sx_hi, f32),
-                                 zero))
+        1-D iota). The reciprocal is taken on the three [by, 1]
+        columns the diagonal can hold (interior, low wall, high wall)
+        and selected per lane: the same quotients as 1 / corr without
+        a divide per cell."""
+        row = rowl + j * by
         ey = jnp.where(row == 0, jnp.asarray(sy_lo, f32),
                        jnp.where(row == ny - 1, jnp.asarray(sy_hi, f32),
                                  zero))
-        corr = (ey + ex) - 4.0
-        return corr, 1.0 / corr
+        c_mid = (ey + zero) - 4.0
+        c_lo = (ey + jnp.asarray(sx_lo, f32)) - 4.0
+        c_hi = (ey + jnp.asarray(sx_hi, f32)) - 4.0
 
-    def sweep(cur, top, bot, rv, corr, inv_d):
-        """One damped-Jacobi update of one strip: zero-ghost 5-point
-        Laplacian (term order of stencil.laplacian5_neumann/_bc) then
-        the _smooth fori-body grouping e + omega*(r - lap)*inv_d."""
-        ecol = jnp.concatenate([top, cur, bot], axis=0)   # [by+2, nx]
-        z = jnp.zeros((by + 2, 1), f32)
-        ew = jnp.concatenate([z, ecol, z], axis=1)        # [by+2, nx+2]
-        lap = (ew[1:-1, 2:] + ew[1:-1, :-2] + ew[2:, 1:-1]
-               + ew[:-2, 1:-1]) + cur * corr
-        return cur + omega * (rv - lap) * inv_d
+        def pick(lo, hi, mid):
+            return jnp.where(first_col, lo, jnp.where(last_col, hi, mid))
+
+        return (pick(c_lo, c_hi, c_mid),
+                pick(1.0 / c_lo, 1.0 / c_hi, 1.0 / c_mid))
+
+    def lap_of(cur, top, bot, corr):
+        """Zero-ghost 5-point Laplacian of one strip (term order of
+        stencil.laplacian5_neumann/_bc): neighbours by rotation, the
+        wrapped lane/row replaced by the ghost (0 in x, the resident
+        neighbour strip's edge row in y)."""
+        xp = jnp.where(last_col, zero, pltpu.roll(cur, nx - 1, 1))
+        xm = jnp.where(first_col, zero, pltpu.roll(cur, 1, 1))
+        yp = jnp.where(rowl == by - 1, bot, pltpu.roll(cur, by - 1, 0))
+        ym = jnp.where(rowl == 0, top, pltpu.roll(cur, 1, 0))
+        return (xp + xm + yp + ym) + cur * corr
+
+    def strips(ring, j):
+        """Strip j of a 4-slot ring with its two halo rows (zero at the
+        walls). Untaken wall branches may read an uninitialized ring
+        slot — jnp.where only selects, never computes on the discarded
+        operand."""
+        def src(m, rows):
+            return ring[_rem(m, 4)][rows, :].astype(f32)
+
+        cur = src(j, slice(None))
+        top = jnp.where(j > 0, src(j + 3, slice(by - 1, by)), zero)
+        bot = jnp.where(j + 1 < nstr, src(j + 1, slice(0, 1)), zero)
+        return cur, top, bot
+
+    def put(ring, j, new):
+        ring[_rem(j, 4)] = new.astype(store_dtype)
+
+    if prolong:
+        @pl.when(i < nstr)
+        def _():
+            # nearest prolongation (2x2 replicate) of the coarse strip,
+            # added to e: lanes doubled on the MXU, rows by two strided
+            # stores into the f32 scratch
+            npass = 1 if store_dtype == jnp.bfloat16 else 3
+            wide = _lane_chunks_matmul(ec_ref[0], selt_ref[...], npass)
+            for c in range(nx // 128):
+                piece = wide[:, c * 128:(c + 1) * 128]
+                buf[c, pl.ds(0, hb, stride=2), :] = piece
+                buf[c, pl.ds(1, hb, stride=2), :] = piece
+            up = jnp.concatenate([buf[c] for c in range(nx // 128)],
+                                 axis=1)
+            put(ring_of[0], i, e_ref[0].astype(f32) + up)
 
     for k in range(1, nsw + 1):
-        j = i - (k - 1)
+        j = i - (k - 1) - head
 
         @pl.when((j >= 0) & (j < nstr))
         def _(k=k, j=j):
@@ -1124,34 +1261,132 @@ def _jacobi_strips_kernel(by, nstr, ny, nx, nsw, omega, gs, from_zero,
                 # from_zero shortcut, same grouping)
                 new = omega * rv * inv_d
             else:
-                if k == 1:
-                    ring = ering
-                else:
-                    ring = lvls[k - 2]
-
-                def src(m, rows):
-                    # untaken wall branches may read an uninitialized
-                    # ring slot — jnp.where only selects, never
-                    # computes on the discarded operand
-                    return ring[_rem(m, 4)][rows, :].astype(f32)
-
-                cur = src(j, slice(None))
-                top = jnp.where(j > 0, src(j + 3, slice(by - 1, by)),
-                                zero)
-                bot = jnp.where(j + 1 < nstr, src(j + 1, slice(0, 1)),
-                                zero)
-                new = sweep(cur, top, bot, rv, corr, inv_d)
+                ring = ering if (k == 1 and not prolong) \
+                    else ring_of[k - 1]
+                cur, top, bot = strips(ring, j)
+                # the _smooth fori-body grouping
+                new = cur + omega * (rv - lap_of(cur, top, bot, corr)) \
+                    * inv_d
             if k == nsw:
                 out_ref[0] = new.astype(store_dtype)
-            else:
-                dst = lvls[k - 1]
-                slot = _rem(j, 4)
-                for s in range(4):
-                    # static-index stores (dynamic leading-index READS
-                    # are established idiom above; writes stay static)
-                    @pl.when(slot == s)
-                    def _(s=s):
-                        dst[s] = new.astype(store_dtype)
+            if k < nsw or restrict:
+                put(ring_of[k], j, new)
+
+    if restrict:
+        j = i - nsw - head
+
+        @pl.when((j >= 0) & (j < nstr))
+        def _(j=j):
+            rv = rring[_rem(j, rslots)].astype(f32)
+            corr, _ = corr_inv(j)
+            cur, top, bot = strips(ring_of[nsw], j)
+            res = rv - lap_of(cur, top, bot, corr)
+            for c in range(nx // 128):
+                buf[c] = res[:, c * 128:(c + 1) * 128]
+            rows = jnp.concatenate(
+                [buf[c, pl.ds(0, hb, stride=2), :]
+                 + buf[c, pl.ds(1, hb, stride=2), :]
+                 for c in range(nx // 128)], axis=1)
+            npass = 2 if store_dtype == jnp.bfloat16 else 3
+            rc_ref[0] = _lane_chunks_matmul(
+                rows, sel_ref[...], npass).astype(store_dtype)
+
+
+def _strip_pipeline(r, e, ec, omega, n, edge_signs, from_zero,
+                    restrict, by, interpret):
+    """The one ``pallas_call`` behind ``fused_jacobi_sweeps`` (plain
+    chain), ``fused_mg_down`` (restrict tail) and ``fused_mg_up``
+    (prolong head, ``ec`` given). Jitted on its configuration, so a
+    kernel body is traced once a process however many cycles, Krylov
+    applications and step executables call it (on the chip's host a
+    trace and lowering of one body costs about a second)."""
+    if interpret is None:
+        interpret = not _on_accel()
+    gs = ((1.0, 1.0, 1.0, 1.0) if edge_signs is None
+          else tuple(float(s) for s in edge_signs))
+    return _strip_pipeline_jit(
+        r, None if from_zero else e, ec, omega=float(omega), nsw=int(n),
+        gs=gs,
+        from_zero=bool(from_zero), restrict=bool(restrict), by=int(by),
+        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "omega", "nsw", "gs", "from_zero", "restrict", "by", "interpret"))
+def _strip_pipeline_jit(r, e, ec, *, omega, nsw, gs, from_zero,
+                        restrict, by, interpret):
+    lead = r.shape[:-2]
+    L = _flatten_lead(lead)
+    ny, nx = r.shape[-2:]
+    store = jnp.dtype(r.dtype)
+    nstr = ny // by
+    prolong = ec is not None
+    head, tail = int(prolong), int(restrict)
+    hb, hx = by // 2, nx // 2
+    ops = [r.reshape((L, ny, nx))]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    scratch = []
+    if prolong:
+        ops += [e.astype(store).reshape((L, ny, nx)),
+                ec.astype(store).reshape((L, ny // 2, hx))]
+        in_specs += [
+            pl.BlockSpec((1, by, nx),
+                         lambda l, i: (l, jnp.minimum(i, nstr - 1), 0)),
+            pl.BlockSpec((1, hb, hx),
+                         lambda l, i: (l, jnp.minimum(i, nstr - 1), 0))]
+    elif not from_zero:
+        ops.append(e.astype(store).reshape((L, ny, nx)))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch += [pltpu.VMEM((4, by, nx), store),
+                    pltpu.SemaphoreType.DMA((4,))]
+    if restrict:
+        ops.append(_pair_matrix())
+        in_specs.append(pl.BlockSpec((_LEG_CHUNK, _LEG_CHUNK // 2),
+                                     lambda l, i: (0, 0)))
+    if prolong:
+        ops.append(_pair_matrix().T)
+        in_specs.append(pl.BlockSpec((_LEG_CHUNK // 2, _LEG_CHUNK),
+                                     lambda l, i: (0, 0)))
+    rslots = nsw + 2 + head + tail
+    scratch += [pltpu.VMEM((rslots, by, nx), store),
+                pltpu.SemaphoreType.DMA((rslots,))]
+    for _ in range(nsw - 1 + head + tail):
+        scratch.append(pltpu.VMEM((4, by, nx), store))
+    if prolong or restrict:
+        # the row-pair scratch, one [by, 128] tile a lane chunk: a
+        # sublane-strided access needs a 128-lane base
+        scratch.append(pltpu.VMEM((nx // 128, by, 128), jnp.float32))
+
+    def clip(j):
+        return jnp.minimum(jnp.maximum(j, 0), nstr - 1)
+
+    # block j revisited (unwritten) by the skew's fill steps, then
+    # written by sweep nsw at step j + nsw - 1 + head and flushed on
+    # the next index change — exactly once per strip
+    out_specs = [pl.BlockSpec(
+        (1, by, nx), lambda l, i: (l, clip(i - (nsw - 1) - head), 0))]
+    out_shape = [jax.ShapeDtypeStruct((L, ny, nx), store)]
+    if restrict:
+        out_specs.append(pl.BlockSpec(
+            (1, hb, hx), lambda l, i: (l, clip(i - nsw - head), 0)))
+        out_shape.append(jax.ShapeDtypeStruct((L, ny // 2, hx), store))
+    kern = functools.partial(_jacobi_strips_kernel, by, nstr, ny, nx,
+                             nsw, omega, gs, from_zero, prolong,
+                             restrict, store)
+    outs = pl.pallas_call(
+        kern,
+        grid=(L, nstr + nsw - 1 + head + tail),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_STRIP_PARAMS,
+        interpret=interpret,
+    )(*ops)
+    e_out = outs[0].reshape(r.shape)
+    if restrict:
+        return e_out, outs[1].reshape(lead + (ny // 2, hx))
+    return e_out
 
 
 def fused_jacobi_sweeps(e, r, omega, n, *, edge_signs=None,
@@ -1168,47 +1403,30 @@ def fused_jacobi_sweeps(e, r, omega, n, *, edge_signs=None,
     all-Neumann. ``from_zero``: first sweep is the e = omega r / d
     shortcut and ``e`` is ignored (may be None). Storage dtype follows
     ``r`` (f32 or bf16); accumulation is always f32."""
-    lead = r.shape[:-2]
-    L = _flatten_lead(lead)
-    ny, nx = r.shape[-2:]
-    store = jnp.dtype(r.dtype)
-    by = _BY_BF16 if store == jnp.bfloat16 else _BY_F32
-    nstr = ny // by
-    nsw = int(n)
-    if interpret is None:
-        interpret = not _on_accel()
-    gs = ((1.0, 1.0, 1.0, 1.0) if edge_signs is None
-          else tuple(float(s) for s in edge_signs))
-    ops = [r.reshape((L, ny, nx))]
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
-    scratch = []
-    if not from_zero:
-        ops.append(e.astype(store).reshape((L, ny, nx)))
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        scratch += [pltpu.VMEM((4, by, nx), store),
-                    pltpu.SemaphoreType.DMA((4,))]
-    rslots = nsw + 2
-    scratch += [pltpu.VMEM((rslots, by, nx), store),
-                pltpu.SemaphoreType.DMA((rslots,))]
-    for _ in range(nsw - 1):
-        scratch.append(pltpu.VMEM((4, by, nx), store))
-    kern = functools.partial(_jacobi_strips_kernel, by, nstr, ny, nx,
-                             nsw, float(omega), gs, from_zero, store)
-    out = pl.pallas_call(
-        kern,
-        grid=(L, nstr + nsw - 1),
-        in_specs=in_specs,
-        # block j revisited (unwritten) by the skew's fill steps, then
-        # written by sweep nsw at step j + nsw - 1 and flushed on the
-        # next index change — exactly once per strip
-        out_specs=pl.BlockSpec(
-            (1, by, nx),
-            lambda l, i: (l, jnp.maximum(i - (nsw - 1), 0), 0)),
-        out_shape=jax.ShapeDtypeStruct((L, ny, nx), store),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*ops)
-    return out.reshape(r.shape)
+    by = _BY_BF16 if jnp.dtype(r.dtype) == jnp.bfloat16 else _BY_F32
+    return _strip_pipeline(r, e, None, omega, n, edge_signs, from_zero,
+                           False, by, interpret)
+
+
+def fused_mg_down(e, r, omega, n, *, edge_signs=None, from_zero=False,
+                  interpret=None):
+    """The down-leg of one V-cycle level in one strip pipeline: ``n``
+    pre-smoothing sweeps (as ``fused_jacobi_sweeps``), then the
+    residual r - lap(e) and its 2x2 full-weighting restriction (plain
+    sum: the x4 of the undivided coarse operator). Reads r (and e
+    unless ``from_zero``) once, writes e and the restricted residual
+    [..., Ny/2, Nx/2] once. Returns ``(e, rc)``."""
+    return _strip_pipeline(r, e, None, omega, n, edge_signs, from_zero,
+                           True, _BY_LEG, interpret)
+
+
+def fused_mg_up(e, r, ec, omega, n, *, edge_signs=None, interpret=None):
+    """The up-leg of one V-cycle level in one strip pipeline: the
+    coarse correction ``ec`` [..., Ny/2, Nx/2] prolonged (nearest, 2x2
+    replicate) and added to e, then ``n`` post-smoothing sweeps. Reads
+    e, r, ec once, writes e once."""
+    return _strip_pipeline(r, e, ec, omega, n, edge_signs, False,
+                           False, _BY_LEG, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -82,6 +82,15 @@ def apply_block_precond_blocks(r: jnp.ndarray, p_inv: jnp.ndarray) -> jnp.ndarra
 # VPU/HBM streaming work that XLA fuses well.
 # ---------------------------------------------------------------------------
 
+# How deep a single-device hierarchy takes its strip kernels: the
+# finest three levels hold 63/64 of the cells, and every further level
+# would add two more kernel bodies to trace and lower (about a second
+# of host time each, per step executable, on the chip's host — set-up
+# time) for under 2 % of a cycle's work. Coarser levels keep the XLA
+# legs, as the shape gate's misses do.
+_STRIP_DEPTH = 3
+
+
 class MultigridPreconditioner:
     """V(nu1, nu2)-cycle for lap(e) = r on a [Ny, Nx] uniform grid.
 
@@ -100,7 +109,7 @@ class MultigridPreconditioner:
                  cycle_dtype=None, spmd_safe: bool = False,
                  mesh=None, overlap_levels: int = 1,
                  edge_signs=None, leg_dtype=None,
-                 smoother: str = "xla",
+                 smoother: str | None = None,
                  periodic=(False, False)):
         self.shapes = []
         self.nu1 = nu1
@@ -126,20 +135,6 @@ class MultigridPreconditioner:
                     "periodic entries are 0); the legacy all-Neumann "
                     "default would paint wall corrections over wrap "
                     "rows")
-            if smoother == "strip":
-                # PR-16 refusal pattern: name the face/kind/token. The
-                # fused strip pipeline synthesizes ghosts from edge
-                # lines in-VMEM and has NO wrap form — silently running
-                # its Neumann edge corrections over periodic rows would
-                # smooth a different operator than the cycle corrects.
-                faces = [n for n, p in zip(("x_lo/x_hi", "y_lo/y_hi"),
-                                           self.periodic) if p]
-                raise ValueError(
-                    f"strip smoother does not support periodic faces "
-                    f"({' and '.join(faces)}: kind='periodic', token "
-                    f"'pd'): the fused sweep pipeline has no wrap-"
-                    "ghost variant — use smoother='xla' (or drop "
-                    "CUP2D_PALLAS) for periodic tables")
         # edge_signs: the BC table's per-face pressure-ghost signs
         # (sx_lo, sx_hi, sy_lo, sy_hi) from bc.pressure_signs — the
         # cycle's operator and Jacobi diagonal carry the same per-face
@@ -191,29 +186,62 @@ class MultigridPreconditioner:
             ny //= 2
             nx //= 2
         self.shapes.append((ny, nx))
-        # smoother (ISSUE 19): "strip" fuses each sweep chain into one
-        # ring-buffered VMEM strip pipeline (pallas_kernels.
-        # fused_jacobi_sweeps — n sweeps = one HBM read + one write).
-        # Demoted to "xla" HERE when the finest level fails the shape
-        # gate, so the reported smoother_tier is always truthful;
-        # coarse levels and the 24-sweep coarsest chain fall back
-        # per-level inside _smooth (identical results either way).
-        if smoother == "strip":
-            from .ops.pallas_kernels import jacobi_strip_supported
-            if not jacobi_strip_supported(ny0, nx0, self.dtype,
-                                          max(nu1, nu2, 1)):
-                smoother = "xla"
+        # smoother: "strip" runs each level as fused strip pipelines
+        # (ops/pallas_kernels): where ``mg_leg_supported`` admits the
+        # level, the whole down-leg (sweeps + residual + restriction)
+        # and up-leg (prolongation + sweeps) are one kernel each
+        # (ISSUE 26: reads r, writes e and rc; reads e, r, ec, writes
+        # e); where only ``jacobi_strip_supported`` does, the sweep
+        # chains alone are fused (ISSUE 19) and the transfers stay
+        # XLA; the rest (coarse shapes, the 24-sweep coarsest chain)
+        # keeps the identical-result XLA form. None (the default)
+        # lets the hierarchy pick by what it can see: "strip" on an
+        # accelerator, "xla" on a CPU run (where a kernel would be
+        # interpreted — tests pass smoother="strip" to get that).
+        # Either way the strip forms are taken only where they exist:
+        # no mesh and no GSPMD-partitioned operands (a strip DMA cannot
+        # be partitioned; a mesh-attached hierarchy keeps the tier it
+        # was handed, whose sweeps ride the halo kernel), no periodic
+        # axis (the pipeline synthesizes wall ghosts and has no wrap
+        # form), f32 or bf16 storage, and a finest level that passes
+        # the shape gate — so the reported smoother_tier is always
+        # what runs.
+        from .ops import pallas_kernels as pk
+        if smoother is None:
+            smoother = ("strip" if mesh is None and not self.spmd_safe
+                        and pk._on_accel() else "xla")
+        nmax = max(nu1, nu2, 1)
+        if smoother == "strip" and (
+                any(self.periodic)
+                or (mesh is None and self.spmd_safe)
+                or not pk.jacobi_strip_supported(ny0, nx0, self.dtype,
+                                                 nmax)):
+            smoother = "xla"
         self.smoother = smoother
+        # levels whose two legs run fused: the finest _STRIP_DEPTH that
+        # the gate admits (never the coarsest, which has no legs); a
+        # mesh-attached hierarchy fuses none
+        self._leg_fused = [
+            smoother == "strip" and mesh is None
+            and lvl < _STRIP_DEPTH and min(nu1, nu2) >= 1
+            and pk.mg_leg_supported(ny_, nx_, self.dtype, nmax)
+            for lvl, (ny_, nx_) in enumerate(self.shapes[:-1])]
+
+    @property
+    def fused_levels(self) -> int:
+        """How many levels run the two fused legs — the "does it
+        engage" reading of the strip tier (0 under "xla")."""
+        return sum(self._leg_fused)
 
     @property
     def smoother_tier(self) -> str:
-        """Telemetry label of the sweep-chain implementation: "xla" or
+        """Telemetry label of the legs' implementation: "xla" or
         "strip", with "+bf16" suffixed when the cycle legs store bf16
         (so a shape-gate demotion of the strip pipeline cannot hide an
-        armed bf16 leg tier — e.g. "xla+bf16"). The default bf16
-        PRECONDITIONER cycles (cycle_dtype=None under Krylov) keep the
-        bare label: that tier predates this latch and is carried by
-        poisson_mode."""
+        armed bf16 leg tier — e.g. "xla+bf16"). The XLA form of the
+        default bf16 PRECONDITIONER cycle (cycle_dtype=None under
+        Krylov) keeps the bare "xla": that tier predates the label
+        and is carried by poisson_mode."""
         base = "strip" if self.smoother == "strip" else "xla"
         if jnp.dtype(self.dtype) == jnp.bfloat16 and (
                 self.leg_dtype is not None or base == "strip"):
@@ -259,7 +287,8 @@ class MultigridPreconditioner:
 
     def _sweeps(self, e, r, lvl, n, from_zero):
         sharded = n > 0 and lvl < self.overlap_levels and r.ndim == 2
-        if self.smoother == "strip" and n > 0 and not sharded:
+        if self.smoother == "strip" and n > 0 and not sharded and (
+                lvl < _STRIP_DEPTH or self.mesh is not None):
             # strip tier (ISSUE 19): the whole sweep chain as ONE
             # time-skewed strip pipeline — n sweeps cost one HBM read
             # of (e, r) and one write instead of ~2n+1 field passes.
@@ -294,8 +323,16 @@ class MultigridPreconditioner:
             e,
         )
 
+    def _note(self):
+        # trace-time only: the tier and its fused-level count on the
+        # compiling executable's ledger row
+        tracing.note_component(
+            f"poisson.mg[{self.smoother_tier},"
+            f"fused_levels={self.fused_levels}/{len(self.shapes)}]")
+
     @tracing.in_scope("mg_cycle")
     def __call__(self, r):
+        self._note()
         return self._cycle(r.astype(self.dtype), 0).astype(self.out_dtype)
 
     @tracing.in_scope("mg_cycle")
@@ -305,6 +342,7 @@ class MultigridPreconditioner:
         initial guess, and run one V-cycle relaxation there. ~2x a
         V-cycle's cost for a much better cold-start correction — the
         opening move of the FAS solver's ``fmg`` mode (mg_solve)."""
+        self._note()
         return self._fcycle(r.astype(self.dtype), 0).astype(self.out_dtype)
 
     def _fcycle(self, r, lvl):
@@ -329,6 +367,8 @@ class MultigridPreconditioner:
                 return self._smooth(e0, r, lvl, 24)
             return self._smooth(jnp.zeros_like(r), r, lvl, 24,
                                 from_zero=True)
+        if self._leg_fused[lvl]:
+            return self._fused_level(r, lvl, e0)
         if e0 is not None:
             e = self._smooth(e0, r, lvl, self.nu1)
         else:
@@ -341,6 +381,21 @@ class MultigridPreconditioner:
             # nearest prolongation (2x2 replicate)
             e = e + jnp.repeat(jnp.repeat(ec, 2, axis=-2), 2, axis=-1)
         return self._smooth(e, r, lvl, self.nu2)
+
+    def _fused_level(self, r, lvl, e0):
+        """One level as two strip pipelines (ISSUE 26): sweeps +
+        residual + restriction going down, prolongation + sweeps
+        coming up. Traced as ``mg_smooth``, the part that dominates
+        each leg; ``mg_transfer`` then holds the unfused levels only."""
+        from .ops.pallas_kernels import fused_mg_down, fused_mg_up
+        with tracing.scope("mg_smooth"):
+            e, rc = fused_mg_down(e0, r, self.omega, self.nu1,
+                                  edge_signs=self.edge_signs,
+                                  from_zero=e0 is None)
+        ec = self._cycle(rc, lvl + 1)
+        with tracing.scope("mg_smooth"):
+            return fused_mg_up(e, r, ec, self.omega, self.nu2,
+                               edge_signs=self.edge_signs)
 
     def _restrict(self, r, e):
         res = r - self._lap(e)

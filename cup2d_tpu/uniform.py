@@ -145,7 +145,8 @@ class UniformGrid:
     def __init__(self, cfg: SimConfig, level: Optional[int] = None,
                  use_pallas: Optional[bool] = None,
                  spmd_safe: bool = False,
-                 bc: Optional[BCTable] = None):
+                 bc: Optional[BCTable] = None,
+                 mg_smoother: Optional[str] = None):
         # spmd_safe: the fused-BC stencil forms have a fast pad+slice
         # variant the SPMD partitioner miscompiles on sharded axes
         # (see ops/stencil._zshift); sharded sims set True
@@ -278,32 +279,32 @@ class UniformGrid:
         # solve spends 2-4 cycles total vs Krylov's 2 M-applies x 8-11
         # iterations, so the byte TOTAL still drops.
         #
-        # Memory-tiered FAS (ISSUE 19): the CUP2D_PREC/CUP2D_PALLAS
-        # composition extends to the SOLVER side of the fas latch —
-        # bf16 lives on the cycle's smoother/transfer LEGS only
-        # (leg_dtype), while mg_solve's outer loop keeps the f32 true
-        # residual (iterative refinement: the legs cannot floor the
-        # solve the way the fully-bf16 solver above does), and the
-        # Pallas latch arms the fused strip smoother (one HBM pass per
-        # sweep chain). Both are demoted truthfully by the
-        # MultigridPreconditioner shape gate; prec=bf16 without the
-        # Pallas tier already refused above.
+        # Memory-tiered FAS (ISSUE 19): the CUP2D_PREC composition
+        # extends to the SOLVER side of the fas latch — bf16 lives on
+        # the cycle's smoother/transfer LEGS only (leg_dtype), while
+        # mg_solve's outer loop keeps the f32 true residual (iterative
+        # refinement: the legs cannot floor the solve the way the
+        # fully-bf16 solver above does); prec=bf16 without the Pallas
+        # tier already refused above.
+        #
+        # The hierarchy picks its own smoother tier by what it can see
+        # (ISSUE 26): the fused strip legs on an accelerator, under
+        # Krylov and fas alike, XLA on a CPU run, under a mesh, on a
+        # periodic table, in f64 or past the shape gate. ``mg_smoother``
+        # lets an owner that shards the operands itself (FleetSim's
+        # mesh placements) hold it to "xla", and a CPU test ask for the
+        # interpreted kernels.
         self._fas_leg_dtype = (
             jnp.bfloat16
             if (prec == "bf16" and self.solver_mode == "fas")
             else None)
-        self._mg_smoother = (
-            "strip"
-            if (self._kernel_tier != "xla"
-                and self.solver_mode == "fas")
-            else "xla")
         self.mg = MultigridPreconditioner(
             self.ny, self.nx, self.dtype, spmd_safe=spmd_safe,
             cycle_dtype=(self.dtype if self.solver_mode == "fas"
                          else None),
             edge_signs=self._psigns,
             leg_dtype=self._fas_leg_dtype,
-            smoother=self._mg_smoother,
+            smoother=mg_smoother,
             periodic=self._paxes)
         # f64 dot-product accumulation when fields are f32 AND x64 is
         # available (the Krylov scalars are precision-critical, SURVEY.md §7
@@ -474,7 +475,11 @@ class UniformGrid:
                 cycle_dtype=self.dtype,
                 edge_signs=self._psigns,
                 leg_dtype=self._fas_leg_dtype,
-                smoother=self._mg_smoother)
+                # what a mesh-attached fas hierarchy has always run:
+                # the Pallas latch arms the halo strip sweep of its
+                # overlapped levels
+                smoother=("strip" if self._kernel_tier != "xla"
+                          else "xla"))
 
     def pressure_solve(self, rhs: jnp.ndarray, exact: bool = False):
         """Solve lap(dp) = rhs (undivided). ``exact`` reproduces the

@@ -93,6 +93,20 @@ def _jacobi(dtype):
     return fn, [((N, N), dtype), ((N, N), dtype)]
 
 
+def _mg_down(dtype):
+    def fn(r):
+        return pk.fused_mg_down(None, r, 0.8, 2, from_zero=True,
+                                interpret=False)
+    return fn, [((N, N), dtype)]
+
+
+def _mg_up(dtype):
+    def fn(e, r, ec):
+        return pk.fused_mg_up(e, r, ec, 0.8, 2, interpret=False)
+    return fn, [((N, N), dtype), ((N, N), dtype),
+                ((N // 2, N // 2), dtype)]
+
+
 def _lab_rhs():
     def fn(lab, h, dt):
         return pk.fused_lab_rhs(lab, h, 4e-5, dt, interpret=False)
@@ -122,6 +136,10 @@ ONE_CHIP_CASES = {
     "fused_correction": _correction,
     "fused_jacobi_sweeps-f32": lambda: _jacobi(F32),
     "fused_jacobi_sweeps-bf16": lambda: _jacobi(BF16),
+    "fused_mg_down-f32": lambda: _mg_down(F32),
+    "fused_mg_down-bf16": lambda: _mg_down(BF16),
+    "fused_mg_up-f32": lambda: _mg_up(F32),
+    "fused_mg_up-bf16": lambda: _mg_up(BF16),
     "fused_lab_rhs": _lab_rhs,
     "fused_block_jacobi_update": _block_update,
     "advect_diffuse_rhs_pallas": _round4_rhs,
@@ -164,16 +182,9 @@ def test_jacobi_halo_sweep_compiles_on_4_chip_mesh(mesh4):
     assert "collective-permute" in compiled.as_text()
 
 
-def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
-    """The whole cavity step (the benchmark's cell: 8192^2 f32, XLA
-    tier, bicgstab + multigrid), compiled for the described v5e: the
-    TPU compiler's fusions keep the step's scope names in ``op_name``
-    — what the chip's trace then shows as each operation's ``tf_op``
-    and benchmark/xplane_meta.py sums device time by. The no-chip
-    evidence that the names survive fusion (PR 24)."""
-    import re
-
-    from cup2d_tpu import tracing
+def _cavity_step_text(one_chip, **grid_kw):
+    """The benchmark cell's step (8192^2 f32 cavity, bicgstab +
+    multigrid), compiled for the described v5e: the executable's text."""
     from cup2d_tpu.config import SimConfig
     from cup2d_tpu.uniform import FlowState, UniformGrid
 
@@ -181,7 +192,7 @@ def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
         cfg = SimConfig(bpdx=1, bpdy=1, level_max=1, level_start=0,
                         extent=1.0, dtype="float32", nu=1e-4, cfl=0.4,
                         poisson_tol=1e-4, poisson_tol_rel=1e-3)
-        grid = UniformGrid(cfg, 10, bc=cavity_table(1.0))
+        grid = UniformGrid(cfg, 10, bc=cavity_table(1.0), **grid_kw)
         assert (grid.ny, grid.nx) == (N, N)
 
         def field(*lead):
@@ -198,6 +209,44 @@ def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
         text = jax.jit(step, donate_argnums=(0,)).lower(
             state, jax.ShapeDtypeStruct((), F32, sharding=one_chip)
         ).compile().as_text()
+    return grid, text
+
+
+def test_cavity_step_with_fused_legs_compiles_on_v5e(one_chip,
+                                                     monkeypatch):
+    """The same step as the chip builds it (PR 26): the hierarchy's
+    strip tier with ``_on_accel`` held true, so every level's kernel
+    is Mosaic-compiled at its own width — the finest 3 of the 11
+    levels run the two fused legs (8192, 4096, 2048 wide), in both
+    cycles of the Krylov body, and they sit in the ``mg_smooth``
+    scope."""
+    import re
+
+    monkeypatch.setattr(pk, "_on_accel", lambda: True)
+    grid, text = _cavity_step_text(one_chip)     # the hierarchy's pick
+    assert grid.smoother_tier == "strip+bf16"
+    assert (grid.mg.fused_levels, len(grid.mg.shapes)) == (3, 11)
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call".*'
+        r'op_name="([^"]*)"', text)
+    # two cycles a Krylov iteration, each 3 fused levels x 2 legs
+    assert len(calls) == 2 * 3 * 2, len(calls)
+    assert all("mg_cycle/mg_smooth" in n for n in calls), calls[:3]
+
+
+def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
+    """The whole cavity step (the benchmark's cell: 8192^2 f32, XLA
+    tier, bicgstab + multigrid), compiled for the described v5e: the
+    TPU compiler's fusions keep the step's scope names in ``op_name``
+    — what the chip's trace then shows as each operation's ``tf_op``
+    and benchmark/xplane_meta.py sums device time by. The no-chip
+    evidence that the names survive fusion (PR 24)."""
+    import re
+
+    from cup2d_tpu import tracing
+
+    grid, text = _cavity_step_text(one_chip)
+    assert grid.smoother_tier == "xla"       # a CPU process picks XLA
     fusions = re.findall(r' fusion\(.*op_name="([^"]*)"', text)
     assert len(fusions) > 50
 

@@ -254,12 +254,15 @@ def test_pallas_megakernel_refuses_periodic(monkeypatch):
         UniformGrid(cfg, level=4, bc=periodic_channel_table())
 
 
-def test_strip_smoother_refuses_periodic():
+def test_strip_smoother_falls_back_on_periodic():
+    """The strip pipeline has no wrap-ghost form: a periodic axis is a
+    silent, reported fall-back to the XLA legs (ISSUE 26; a
+    construction-time refusal before)."""
     from cup2d_tpu.poisson import MultigridPreconditioner
-    with pytest.raises(ValueError, match="strip smoother"):
-        MultigridPreconditioner(
-            64, 64, jnp.float32, edge_signs=(0.0, 0.0, 1.0, 1.0),
-            smoother="strip", periodic=(True, False))
+    mg = MultigridPreconditioner(
+        64, 64, jnp.float32, edge_signs=(0.0, 0.0, 1.0, 1.0),
+        smoother="strip", periodic=(True, False))
+    assert (mg.smoother_tier, mg.fused_levels) == ("xla", 0)
 
 
 def test_mg_periodic_needs_edge_signs():

@@ -20,11 +20,13 @@ from cup2d_tpu.config import SimConfig
 from cup2d_tpu.ops.pallas_kernels import (block_update_supported,
                                           fused_block_jacobi_update,
                                           fused_jacobi_sweeps,
-                                          jacobi_strip_supported)
+                                          fused_mg_down, fused_mg_up,
+                                          jacobi_strip_supported,
+                                          mg_leg_supported)
 from cup2d_tpu.ops.stencil import (_edge_ones, laplacian5_bc,
                                    laplacian5_neumann)
 from cup2d_tpu.poisson import (MultigridPreconditioner,
-                               apply_block_precond_blocks,
+                               apply_block_precond_blocks, bicgstab,
                                block_precond_matrix, mg_solve)
 
 SIGNED = (1.0, -1.0, 1.0, 1.0)
@@ -115,37 +117,237 @@ def test_strip_bf16_storage_f32_accumulate():
 # hierarchy integration: cycle parity, truthful tier label, demotion
 # ---------------------------------------------------------------------------
 
-def test_mg_cycle_strip_matches_xla():
-    """One full V-cycle with the strip smoother vs the XLA chain, and
-    the truthful smoother_tier labels (including the shape-gate
-    demotion and the leg-suffix composition)."""
+F32, BF16 = jnp.float32, jnp.bfloat16
+# one bf16 storage rounding (half an ulp of an 8-bit mantissa, on the
+# field's largest value) — what a fused leg may differ by from the same
+# arithmetic rounded at the same storage points
+BF16_ROUNDING = 2.0 ** -8
+
+
+def _legs_reference(r, e0, ec, signs, from_zero, n=2, omega=0.8):
+    """The XLA legs of ``MultigridPreconditioner._cycle`` with f32
+    arithmetic and ONE rounding wherever a strip is stored (each
+    sweep's result, the prolonged sum, the restricted residual): for
+    f32 operands these
+    are the XLA legs themselves, for bf16 operands the legs as the
+    fused kernels define them."""
+    store = r.dtype
+    f = lambda a: a.astype(F32)
+    rf = f(r)
+
+    def sweeps(e, k, fz):
+        for m in range(k):
+            e = f(_xla_chain(e, rf, omega, 1, signs,
+                             fz and m == 0).astype(store))
+        return e
+
+    lap = (laplacian5_neumann if signs is None
+           else (lambda p: laplacian5_bc(p, *signs)))
+    down = sweeps(None if from_zero else f(e0), n, from_zero)
+    res = rf - lap(down)
+    rows = res[..., 0::2, :] + res[..., 1::2, :]
+    rc = (rows[..., :, 0::2] + rows[..., :, 1::2]).astype(store)
+    start = f(e0) + jnp.repeat(jnp.repeat(f(ec), 2, axis=-2), 2,
+                               axis=-1)
+    up = sweeps(f(start.astype(store)), n, False)
+    return down.astype(store), rc, up.astype(store)
+
+
+def _close(got, ref, dtype):
+    assert got.dtype == ref.dtype and got.shape == ref.shape
+    tol = 2e-6 if dtype == F32 else BF16_ROUNDING
+    g, x = got.astype(F32), ref.astype(F32)
+    return float(jnp.max(jnp.abs(g - x))) <= tol * float(
+        jnp.max(jnp.abs(x)))
+
+
+# leading dims 1 and 3; the batch of 3 on an ODD strip count (96 / 32)
+LEG_SHAPES = {"lead1": (1, 64, 256), "lead3-3strips": (3, 96, 256)}
+
+
+@pytest.mark.parametrize("shape", sorted(LEG_SHAPES))
+@pytest.mark.parametrize("signs", [None, SIGNED],
+                         ids=["neumann", "one-dirichlet-face"])
+@pytest.mark.parametrize("from_zero", [True, False])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_fused_down_leg_matches_xla_legs(dtype, from_zero, signs, shape):
+    """sweeps + residual + restriction in one pipeline against the XLA
+    legs it replaces: f32 in the file's ~1-ulp band, bf16 within one
+    storage rounding."""
+    shp = LEG_SHAPES[shape]
+    r, e0 = _rand(shp, 31, dtype), _rand(shp, 32, dtype)
+    ec = jnp.zeros(shp[:-2] + (shp[-2] // 2, shp[-1] // 2), dtype)
+    e_ref, rc_ref, _ = _legs_reference(r, e0, ec, signs, from_zero)
+    e, rc = fused_mg_down(None if from_zero else e0, r, 0.8, 2,
+                          edge_signs=signs, from_zero=from_zero)
+    assert _close(e, e_ref, dtype)
+    assert _close(rc, rc_ref, dtype)
+
+
+@pytest.mark.parametrize("shape", sorted(LEG_SHAPES))
+@pytest.mark.parametrize("signs", [None, SIGNED],
+                         ids=["neumann", "one-dirichlet-face"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_fused_up_leg_matches_xla_legs(dtype, signs, shape):
+    """prolongation + sweeps in one pipeline against the XLA legs."""
+    shp = LEG_SHAPES[shape]
+    r, e0 = _rand(shp, 41, dtype), _rand(shp, 42, dtype)
+    ec = _rand(shp[:-2] + (shp[-2] // 2, shp[-1] // 2), 43, dtype)
+    _, _, up_ref = _legs_reference(r, e0, ec, signs, False)
+    up = fused_mg_up(e0, r, ec, 0.8, 2, edge_signs=signs)
+    assert _close(up, up_ref, dtype)
+
+
+def test_leg_gate():
+    """Whole 32-row strips and whole 256-lane pair chunks on top of
+    the sweep-chain gate; a False is the silent XLA fall-back."""
+    assert mg_leg_supported(64, 256, F32, 2)
+    assert mg_leg_supported(32, 512, BF16, 2)
+    assert not mg_leg_supported(48, 256, F32, 2)     # ny % 32
+    assert not mg_leg_supported(64, 128, F32, 2)     # nx % 256
+    assert not mg_leg_supported(64, 256, jnp.float64, 2)
+    assert not mg_leg_supported(64, 256, F32, 7)     # depth cap
+
+
+# ---------------------------------------------------------------------------
+# hierarchy integration: cycle parity, truthful tier label, selection
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_mg_cycle_strip_matches_xla(dtype):
+    """One full V-cycle through the strip tier (fused legs at the
+    finest level, fused sweep chains below it, XLA at the coarse end)
+    against the XLA hierarchy, and the truthful labels."""
     b = _rand((128, 256), 11)
-    mgx = MultigridPreconditioner(128, 256, jnp.float32,
-                                  cycle_dtype=jnp.float32)
-    mgs = MultigridPreconditioner(128, 256, jnp.float32,
-                                  cycle_dtype=jnp.float32,
+    mgx = MultigridPreconditioner(128, 256, F32, cycle_dtype=dtype,
+                                  smoother="xla")
+    mgs = MultigridPreconditioner(128, 256, F32, cycle_dtype=dtype,
                                   smoother="strip")
-    assert (mgx.smoother_tier, mgs.smoother_tier) == ("xla", "strip")
+    suffix = "+bf16" if dtype == BF16 else ""
+    assert (mgx.smoother_tier, mgs.smoother_tier) == (
+        "xla", "strip" + suffix)
+    assert (mgx.fused_levels, mgs.fused_levels) == (0, 1)
     cx, cs = mgx(b), mgs(b)
-    tol = 2e-6 * float(jnp.max(jnp.abs(cx)))
+    assert cs.dtype == cx.dtype == F32         # out_dtype restored
+    # bf16: a cycle's worth of storage roundings taken at other points
+    tol = (2e-6 if dtype == F32 else 3e-2) * float(jnp.max(jnp.abs(cx)))
     assert float(jnp.max(jnp.abs(cs - cx))) <= tol
+
+
+def test_fcycle_strip_matches_xla():
+    """The F-cycle hands the down-leg a prolonged initial guess (the
+    leg's e-given form) — same parity."""
+    b = _rand((64, 256), 12)
+    mgx = MultigridPreconditioner(64, 256, F32, cycle_dtype=F32,
+                                  smoother="xla")
+    mgs = MultigridPreconditioner(64, 256, F32, cycle_dtype=F32,
+                                  smoother="strip")
+    assert mgs.fused_levels == 1
+    cx, cs = mgx.fcycle(b), mgs.fcycle(b)
+    assert float(jnp.max(jnp.abs(cs - cx))) <= 2e-6 * float(
+        jnp.max(jnp.abs(cx)))
+
+
+def test_strip_tier_labels():
+    """The shape-gate demotion and the leg-suffix composition."""
     # unsupported finest shape: truthful demotion, identical results
-    mgd = MultigridPreconditioner(36, 36, jnp.float32,
-                                  cycle_dtype=jnp.float32,
+    mgd = MultigridPreconditioner(36, 36, F32, cycle_dtype=F32,
                                   smoother="strip")
-    assert mgd.smoother_tier == "xla"
+    assert (mgd.smoother_tier, mgd.fused_levels) == ("xla", 0)
     # bf16 legs survive a demotion in the label (no hidden tier)
-    mgdb = MultigridPreconditioner(36, 36, jnp.float32,
-                                   cycle_dtype=jnp.float32,
-                                   leg_dtype=jnp.bfloat16,
-                                   smoother="strip")
+    mgdb = MultigridPreconditioner(36, 36, F32, cycle_dtype=F32,
+                                   leg_dtype=BF16, smoother="strip")
     assert mgdb.smoother_tier == "xla+bf16"
-    mgb = MultigridPreconditioner(128, 256, jnp.float32,
-                                  cycle_dtype=jnp.float32,
-                                  leg_dtype=jnp.bfloat16,
-                                  smoother="strip")
+    mgb = MultigridPreconditioner(128, 256, F32, cycle_dtype=F32,
+                                  leg_dtype=BF16, smoother="strip")
     assert mgb.smoother_tier == "strip+bf16"
-    assert mgb(b).dtype == jnp.float32      # out_dtype restored
+    # sweep chains fused, legs not (128 lanes hold no pair chunk)
+    mgw = MultigridPreconditioner(64, 128, F32, cycle_dtype=F32,
+                                  smoother="strip")
+    assert (mgw.smoother_tier, mgw.fused_levels) == ("strip", 0)
+
+
+def _mesh8():
+    from jax.sharding import Mesh
+    if jax.device_count() < 8:
+        pytest.skip("needs the 8 forced host devices")
+    return Mesh(np.array(jax.devices()[:8]), ("x",))
+
+
+SELECTION = {
+    # what the hierarchy picks for itself (smoother=None) ...
+    "default-cpu": (dict(), "xla"),
+    # ... and what a forced strip tier falls back to, silently
+    "periodic-axis": (dict(smoother="strip", periodic=(True, False),
+                           edge_signs=(0.0, 0.0, 1.0, 1.0)), "xla"),
+    "f64": (dict(smoother="strip", dtype=jnp.float64), "xla"),
+    "ny-not-strips": (dict(smoother="strip", ny=36), "xla"),
+    "partitioned-operands": (dict(smoother="strip", spmd_safe=True),
+                             "xla"),
+    "forced": (dict(smoother="strip"), "strip"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECTION))
+def test_smoother_selection(case):
+    """Selection by what the code can see: no knob. Every fall-back is
+    silent and reported."""
+    kw, tier = SELECTION[case]
+    kw = dict(kw)
+    ny, dtype = kw.pop("ny", 128), kw.pop("dtype", F32)
+    mg = MultigridPreconditioner(ny, 256, dtype, cycle_dtype=dtype, **kw)
+    assert mg.smoother_tier == tier
+    assert (mg.fused_levels > 0) == (tier == "strip")
+
+
+def test_smoother_selection_mesh_attached():
+    """A mesh-attached hierarchy fuses no legs: picking for itself it
+    stays XLA, and handed the strip tier it runs what it always has
+    (the halo strip sweep of its overlapped levels)."""
+    mesh = _mesh8()
+    auto = MultigridPreconditioner(128, 1024, F32, cycle_dtype=F32,
+                                   mesh=mesh)
+    assert (auto.smoother_tier, auto.fused_levels) == ("xla", 0)
+    armed = MultigridPreconditioner(128, 1024, F32, cycle_dtype=F32,
+                                    mesh=mesh, smoother="strip")
+    assert (armed.smoother_tier, armed.fused_levels) == ("strip", 0)
+
+
+@pytest.mark.parametrize("ny,nx,dtype", [(256, 256, BF16),
+                                         (512, 1024, F32),
+                                         (1024, 2048, BF16),
+                                         (96, 768, F32)])
+def test_fused_level_counter_equals_gate(ny, nx, dtype):
+    mg = MultigridPreconditioner(ny, nx, F32, cycle_dtype=dtype,
+                                 smoother="strip")
+    # the finest three levels (63/64 of the cells), where the gate
+    # admits them
+    admitted = [lvl < 3 and mg_leg_supported(y, x, dtype, 2)
+                for lvl, (y, x) in enumerate(mg.shapes[:-1])]
+    assert mg.fused_levels == sum(admitted) >= 1
+    assert mg._leg_fused == admitted
+
+
+def test_bicgstab_same_iterations_either_hierarchy():
+    """BiCGSTAB preconditioned by the default bf16 cycle reaches the
+    production tolerance in the same iteration count with the strip
+    hierarchy as with the XLA one (256^2, all-Neumann)."""
+    n = 256
+    x = (np.arange(n) + 0.5) / n
+    X, Y = np.meshgrid(x, x)
+    b = jnp.asarray(np.cos(2 * np.pi * X) * np.cos(3 * np.pi * Y)
+                    + 0.3 * np.cos(9 * np.pi * X) * np.cos(np.pi * Y),
+                    F32)
+    iters = {}
+    for tier in ("xla", "strip"):
+        mg = MultigridPreconditioner(n, n, F32, smoother=tier)
+        assert mg.dtype == BF16
+        res = jax.jit(lambda rhs, mg=mg: bicgstab(
+            laplacian5_neumann, rhs, M=mg, tol=1e-4, tol_rel=1e-3,
+            max_iter=50))(b)
+        assert bool(res.converged), tier
+        iters[tier] = int(res.iters)
+    assert iters["strip"] == iters["xla"] > 0, iters
 
 
 def test_bf16_leg_mg_solve_same_criterion():
@@ -209,12 +411,9 @@ def test_sharded_strip_matches_gspmd_overlap():
     ppermutes FIRST, then the per-sweep halo strip kernel) against the
     pinned GSPMD overlap body — the in-kernel device-masked wall
     diagonal reproduces it exactly."""
-    from jax.sharding import Mesh
     from cup2d_tpu.parallel.shard_halo import overlap_jacobi_sweeps
 
-    if jax.device_count() < 8:
-        pytest.skip("needs the 8 forced host devices")
-    mesh = Mesh(np.array(jax.devices()[:8]), ("x",))
+    mesh = _mesh8()
     ny, nx = 32, 1024
     e, r = _rand((ny, nx), 21), _rand((ny, nx), 22)
     ey, ex = _edge_ones(ny, r.dtype), _edge_ones(nx, r.dtype)
@@ -233,6 +432,10 @@ def test_sharded_strip_matches_gspmd_overlap():
 # ---------------------------------------------------------------------------
 
 def test_uniform_latch_composition(monkeypatch):
+    """The hierarchy's smoother no longer follows CUP2D_PALLAS + fas
+    (ISSUE 26): a CPU run picks XLA whatever the latches say, the fas
+    bf16-leg tier still rides CUP2D_PREC, and an owner that hands the
+    strip tier down gets it under Krylov and fas alike."""
     from cup2d_tpu.uniform import UniformGrid
 
     cfg = SimConfig(bpdx=1, bpdy=1, level_max=1, level_start=0,
@@ -243,16 +446,22 @@ def test_uniform_latch_composition(monkeypatch):
     assert UniformGrid(cfg, level=4).smoother_tier == "xla"
     monkeypatch.setenv("CUP2D_PALLAS", "1")
     g = UniformGrid(cfg, level=4)
-    assert g.smoother_tier == "strip" and g.mg.leg_dtype is None
+    assert g.smoother_tier == "xla" and g.mg.leg_dtype is None
+    assert UniformGrid(cfg, level=4,
+                       mg_smoother="strip").smoother_tier == "strip"
     monkeypatch.setenv("CUP2D_PREC", "bf16")
     g = UniformGrid(cfg, level=4)
-    assert g.smoother_tier == "strip+bf16"
+    assert g.smoother_tier == "xla+bf16"
     assert g.mg.leg_dtype == jnp.bfloat16
-    # non-fas: the strip/leg tier stays off (preconditioner cycles
-    # keep their pinned bf16-storage default under Krylov)
+    g = UniformGrid(cfg, level=4, mg_smoother="strip")
+    assert g.smoother_tier == "strip+bf16"
+    # Krylov: the preconditioner cycles keep their bf16-storage
+    # default, and take the strip tier the same way
     monkeypatch.setenv("CUP2D_POIS", "")
     monkeypatch.delenv("CUP2D_PREC", raising=False)
     assert UniformGrid(cfg, level=4).smoother_tier == "xla"
+    g = UniformGrid(cfg, level=5, mg_smoother="strip")
+    assert (g.smoother_tier, g.mg.fused_levels) == ("strip+bf16", 1)
 
 
 def test_forest_latch_composition_and_refusals(monkeypatch):
@@ -357,7 +566,8 @@ def test_bf16_leg_cavity_watchdog(tmp_path, monkeypatch):
                     max_poisson_iterations=60)
     sim = UniformSim(cfg, level=2, bc=cavity_table(1.0))
     assert sim.prec_mode == "bf16"
-    assert sim.smoother_tier == "strip+bf16"
+    # a CPU run keeps the XLA sweeps (ISSUE 26); the bf16 legs show
+    assert sim.smoother_tier == "xla+bf16"
 
     wd = PhysicsWatchdog.for_prec(sim.prec_mode, window=4)
     assert (wd.div_factor, wd.div_settle) == (100.0, 8.0)
@@ -372,7 +582,7 @@ def test_bf16_leg_cavity_watchdog(tmp_path, monkeypatch):
     rec = MetricsRecorder()
     rec.prime(sim)
     r = rec.record(sim, sim.step_once(dt))
-    assert r["smoother_tier"] == "strip+bf16"
+    assert r["smoother_tier"] == "xla+bf16"
     assert wd._armed(wd.umax, wd.umax_settle) is not None
     with open(tmp_path / "events.jsonl") as f:
         evs = [json.loads(ln) for ln in f if ln.strip()]
