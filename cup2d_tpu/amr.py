@@ -368,7 +368,8 @@ class AMRSim(ShapeHostMixin):
         f = self.forest
         if self._tables_version == f.version:
             return
-        with (self.timers or NULL_TIMERS).phase("tables"):
+        with (self.timers or NULL_TIMERS).phase("tables"), \
+                tracing.span("tables", step=int(self.step_count)):
             self._refresh_impl()
 
     def _refresh_impl(self):
@@ -1441,6 +1442,7 @@ class AMRSim(ShapeHostMixin):
     # ------------------------------------------------------------------
     # device: rasterization + chi + integrals (ongrid, main.cpp:4208-4630)
     # ------------------------------------------------------------------
+    @tracing.in_scope("rasterize")
     def _rasterize_impl(self, inputs, xc, yc, h3, hsq, t1s):
         cfg = self.cfg
         bs = cfg.bs
@@ -1605,6 +1607,7 @@ class AMRSim(ShapeHostMixin):
     # ------------------------------------------------------------------
     # device: surface force diagnostics (main.cpp:7188-7284)
     # ------------------------------------------------------------------
+    @tracing.in_scope("forces")
     def _forces_impl(self, vel, pres, obs, uvw, t4v, t4s,
                      hflat, xc, yc):
         velp = assemble_labs_ordered(vel, t4v)                 # [N,2,L,L]
@@ -2035,11 +2038,13 @@ class AMRSim(ShapeHostMixin):
 
         # ongrid host part (main.cpp:3992-4207)
         cfg = self.cfg
-        with tm.phase("kinematics"):
+        step = int(self.step_count)
+        with tm.phase("kinematics"), tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
                 s.midline(self.time)
-        with tm.phase("rasterize"):
+        with tm.phase("rasterize"), \
+                tracing.span("shape_inputs", step=step):
             inputs = self._shape_inputs()
 
         prescribed = jnp.asarray(
@@ -2074,6 +2079,7 @@ class AMRSim(ShapeHostMixin):
         for k, s in enumerate(self.shapes):
             if s.free:
                 s.u, s.v, s.omega = uvw_np[k]
+        diag["bodies"] = self._bodies_record()
         self._next_dt = float(dt_next)
         self._next_dt_version = f.version
         self._next_umax = float(diag["umax"])

@@ -352,7 +352,7 @@ class HostCounters:
 # always present; fields that do not apply to a path (AMR shape on a
 # uniform run, comm volume on a single device, counters when disabled)
 # are null — consumers key on names, never on presence.
-METRICS_SCHEMA_VERSION = 12
+METRICS_SCHEMA_VERSION = 13
 METRICS_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     # solver health + timestep state (the step's existing diag pull).
@@ -409,8 +409,15 @@ METRICS_KEYS = (
     "bc_table", "case",
     # fused on-device physics invariants (watchdog inputs)
     "energy", "div_linf",
-    # AMR shape
-    "n_blocks", "blocks_per_level", "refines", "coarsens",
+    # AMR shape; pad_blocks (schema v13) is the bucket the step's
+    # block arrays are padded to, so 1 - n_blocks/pad_blocks is the
+    # share of the forest step spent on masked rows. Null off the forest
+    "n_blocks", "pad_blocks", "blocks_per_level", "refines", "coarsens",
+    # the bodies (schema v13): one entry a shape — com [x, y], angle, u,
+    # v, omega, mass, inertia — host floats from the step's one existing
+    # pull (shapes_host._bodies_record), so a run's body trajectory is
+    # in metrics.jsonl and a reference can hold it. Null without shapes
+    "bodies",
     # comm volume (shard surface-exchange plan, per one vec3 exchange)
     "halo_real_bytes", "halo_padded_bytes",
     # host-side counters (per-step deltas; hbm peak is absolute);
@@ -604,6 +611,7 @@ class MetricsRecorder:
                 kv = getattr(sim, key, None)
             rec[key] = str(kv) if kv is not None else None
         rec.update(self._amr_fields(sim))
+        rec["bodies"] = diag.get("bodies")
         rec.update(self._comm_fields(sim))
         rec.update(self._counter_fields())
         rec.update(self._guard_fields())
@@ -655,7 +663,8 @@ class MetricsRecorder:
     def _amr_fields(self, sim) -> dict:
         f = getattr(sim, "forest", None)
         if f is None:
-            return {"n_blocks": None, "blocks_per_level": None,
+            return {"n_blocks": None, "pad_blocks": None,
+                    "blocks_per_level": None,
                     "refines": None, "coarsens": None}
         if self._lvl_cache[0] != f.version:
             order = getattr(sim, "_order", None)
@@ -669,7 +678,9 @@ class MetricsRecorder:
         ref_d = nr - self._last_regrid[0]
         coa_d = nc - self._last_regrid[1]
         self._last_regrid = (nr, nc)
+        mask = getattr(sim, "_mask", None)
         return {"n_blocks": self._lvl_cache[2],
+                "pad_blocks": int(len(mask)) if mask is not None else None,
                 "blocks_per_level": self._lvl_cache[1],
                 "refines": ref_d, "coarsens": coa_d}
 

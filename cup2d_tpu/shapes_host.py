@@ -6,6 +6,8 @@ and must stay identical between the two paths."""
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import numpy as np
 
@@ -35,6 +37,22 @@ class ShapeHostMixin:
             cth, sth = np.cos(s.orientation), np.sin(s.orientation)
             s.d_gm[0] = dc[0] * cth + dc[1] * sth
             s.d_gm[1] = -dc[0] * sth + dc[1] * cth
+
+    def _bodies_record(self) -> Optional[list]:
+        """The bodies as the step's record carries them (telemetry
+        schema v13): one entry a shape, host floats the step already
+        holds after its one pull — the rigid state the momentum solve
+        returned and the chi-corrected mass, centre and inertia the
+        rasterisation returned; None where the run has no shape. No
+        device value is touched."""
+        if not self.shapes:
+            return None
+        return [{"com": [float(s.com[0]), float(s.com[1])],
+                 "angle": float(s.orientation),
+                 "u": float(s.u), "v": float(s.v),
+                 "omega": float(s.omega),
+                 "mass": float(s.M), "inertia": float(s.J)}
+                for s in self.shapes]
 
     def _kinematic_dt_cap(self) -> float:
         """Deforming bodies need dt well under their gait period: the

@@ -170,6 +170,7 @@ class Simulation(ShapeHostMixin):
     # ------------------------------------------------------------------
     # device: rasterization + chi + integrals (ongrid, main.cpp:4208-4630)
     # ------------------------------------------------------------------
+    @tracing.in_scope("rasterize")
     def _rasterize_impl(self, inputs):
         g = self.grid
         h = g.h
@@ -341,6 +342,7 @@ class Simulation(ShapeHostMixin):
     # ------------------------------------------------------------------
     # device: surface force diagnostics (main.cpp:7188-7284)
     # ------------------------------------------------------------------
+    @tracing.in_scope("forces")
     def _forces_impl(self, state: FlowState, obs: ObstacleFields, uvw):
         g = self.grid
         out = []
@@ -464,13 +466,16 @@ class Simulation(ShapeHostMixin):
                              self._kinematic_dt_cap())
 
         # ongrid host part (main.cpp:3992-4207)
-        with tm.phase("kinematics"):
+        step = int(self.step_count)
+        with tm.phase("kinematics"), tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
                 s.midline(self.time)
 
         with tm.phase("rasterize"):
-            obs = self._rasterize(self._shape_inputs())
+            with tracing.span("shape_inputs", step=step):
+                inputs = self._shape_inputs()
+            obs = self._rasterize(inputs)
             self._sync_shape_scalars(obs)
             # fence the field outputs too (the scalar pull above only
             # proves the scalars landed): device raster time must land
@@ -499,6 +504,7 @@ class Simulation(ShapeHostMixin):
         for k, s in enumerate(self.shapes):
             if s.free:
                 s.u, s.v, s.omega = uvw_np[k]
+        diag["bodies"] = self._bodies_record()
 
         if self.shapes and self.compute_forces_every and \
                 self.step_count % self.compute_forces_every == 0:

@@ -90,11 +90,12 @@ _PROFILING = False          # a CUP2D_TRACE window is open (TraceWindow)
 
 # the step program's parts, as they read in a device trace: top-level
 # scopes in step order (the Heun substages nest in advect), then the
-# ones that nest inside poisson_solve
+# ones that nest inside poisson_solve, then the bodies' own two: the
+# rasterisation before the flow step and the surface forces after it
 SCOPES = ("advect", "substage0", "substage1", "penalize", "poisson_rhs",
           "poisson_solve", "project_correct", "diag",
           "krylov", "mg_cycle", "mg_smooth", "mg_transfer", "mg_coarse",
-          "fft_diag")
+          "fft_diag", "rasterize", "forces")
 
 
 def scope(name: str):

@@ -86,10 +86,40 @@ def _forest_step(exact=False):
     return step, seen["args"]
 
 
+def _forest_fish_step():
+    """One AMRSim step WITH a body — rasterisation, penalisation, the
+    flow step and the surface forces in the one megastep the forest's
+    cell drives; the arguments are the driver's own."""
+    from cup2d_tpu.amr import AMRSim
+    from cup2d_tpu.models import FishShape
+    cfg = SimConfig(bpdx=2, bpdy=1, level_max=4, level_start=2,
+                    extent=2.0, nu=4e-5, lam=1e7, cfl=0.5, rtol=2.0,
+                    ctol=1.0, dtype="float32")
+    sim = AMRSim(cfg, shapes=[FishShape(0.4, 1.0, 0.5, 0.0, cfg.min_h)])
+    sim.initialize()
+    sim.step_count = 20                         # production solve
+    seen = {}
+    real = sim._mega_jit
+
+    def capture(*args, **kwargs):
+        seen["args"], seen["kw"] = _abstract(args), kwargs
+        return real(*args, **kwargs)
+
+    sim._mega_jit = capture
+    sim.step_once()
+    assert seen["kw"] == {"exact_poisson": False, "with_forces": True}
+
+    def step(*args):
+        return sim._megastep_impl(*args, **seen["kw"])
+    return step, seen["args"]
+
+
 CORES = {"uniform": lambda: _uniform_step(False),
          "uniform-exact": lambda: _uniform_step(True),
          "fleet": _fleet_step,
-         "forest": _forest_step}
+         "forest": _forest_step,
+         "forest-fish": _forest_fish_step}
+BODY_SCOPES = ("rasterize", "penalize", "forces")
 
 
 def _op_names(step, args):
@@ -113,10 +143,12 @@ def test_scopes_cover_the_step(core):
         {n for n in names if not _scoped(n)})[:20])
     joined = "\n".join(names)
     for scope in STEP_SCOPES:
-        if core == "forest" and scope == "mg_coarse":
-            continue    # no coarse correction on a 16-block forest
+        if core.startswith("forest") and scope == "mg_coarse":
+            continue    # no coarse correction on a forest this small
         assert f"/{scope}/" in joined, scope
     assert "/poisson_solve/" in joined
+    for scope in BODY_SCOPES:
+        assert (f"/{scope}/" in joined) is (core == "forest-fish"), scope
 
 
 # (b) a scope is metadata only

@@ -84,8 +84,11 @@ def _amr_sim():
 # tokens "fftd" / "fftd+tridiag" (FFT-diagonalized per-mode solves,
 # poisson_iters == 1 by contract, precond_cycles == 0) and bc_table
 # gains the "pd" periodic face token ("pd,pd,pd,pd" turbulence box,
-# "pd,pd,ns,ns" periodic channel).
-_SCHEMA_V12_KEYS = (
+# "pd,pd,ns,ns" periodic channel); v13 the bodies and the pad bucket
+# (ISSUE 28): bodies — one entry a shape of com / angle / u / v / omega
+# / mass / inertia from the step's one existing pull, null without
+# shapes — and pad_blocks beside n_blocks, null off the forest.
+_SCHEMA_V13_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     "umax", "dt_next",
     "poisson_iters", "poisson_residual",
@@ -95,7 +98,8 @@ _SCHEMA_V12_KEYS = (
     "smoother_tier",
     "bc_table", "case",
     "energy", "div_linf",
-    "n_blocks", "blocks_per_level", "refines", "coarsens",
+    "n_blocks", "pad_blocks", "blocks_per_level", "refines", "coarsens",
+    "bodies",
     "halo_real_bytes", "halo_padded_bytes",
     "jit_compiles", "device_gets", "state_gathers", "hbm_peak_bytes",
     "snap_ring_bytes", "replayed_steps",
@@ -109,15 +113,15 @@ _SCHEMA_V12_KEYS = (
 )
 
 
-def test_metrics_schema_v12_key_set_pinned():
+def test_metrics_schema_v13_key_set_pinned():
     from cup2d_tpu.profiling import METRICS_SCHEMA_VERSION
-    assert METRICS_SCHEMA_VERSION == 12
-    assert METRICS_KEYS == _SCHEMA_V12_KEYS
+    assert METRICS_SCHEMA_VERSION == 13
+    assert METRICS_KEYS == _SCHEMA_V13_KEYS
 
 
 @pytest.mark.slow   # ~17 s; duplicative tier-1 coverage: the frozen key
 #                     SET is pinned as a literal tuple in
-#                     test_metrics_schema_v12_key_set_pinned and the
+#                     test_metrics_schema_v13_key_set_pinned and the
 #                     uniform producer stream (every record, key-exact)
 #                     in test_cli_metrics_stream_and_post_report; the
 #                     AMR/bench records drilled here ride the identical
