@@ -46,8 +46,9 @@ from .flux import apply_flux_corr, build_flux_corr, \
     divergence_deposits, gradient_deposits, poisson_apply_structured
 from .forest import Forest
 from .halo import _TopoIndex, _bucket, assemble_labs, \
-    assemble_labs_ordered, build_face_copy, build_tables, \
-    make_fast_tables, pad_tables
+    FastHalo, assemble_labs_ordered, assemble_labs_rows, block_rows, \
+    build_face_copy, build_tables, make_fast_tables, pad_tables, \
+    rows_of_blocks
 from . import native, tracing
 from .ops.collision import merged_overlap_integrals, \
     pairwise_collision_update
@@ -119,6 +120,13 @@ def _raster_neg(cfg, dtype):
     must not close over tracers), so both paths construct it from the
     config through this function and provably agree."""
     return jnp.asarray(-float(cfg.extent), dtype)
+
+
+def _padded_list(a, cap: int) -> np.ndarray:
+    """An index list, -1 padded to its capacity."""
+    out = np.full(cap, -1, np.int32)
+    out[:len(a)] = a
+    return out
 
 
 def _window_sdf_udef(inp, bs: int, dtype):
@@ -283,6 +291,14 @@ class AMRSim(ShapeHostMixin):
         self._ord_key = None
         self._ord_dirty = False
         self._wcap = [16] * len(self.shapes)
+        # the force pass's block lists (_shape_inputs): sticky
+        # capacities, rows selected at the last build, growths since
+        # construction
+        self._fcap = [16] * len(self.shapes)
+        self._frcap = [512] * len(self.shapes)   # their g=4 table rows
+        self._frow_index = None                  # (_finalize_tables)
+        self._force_blocks = None
+        self._fcap_growths = 0
         # sticky block-axis padding (see _refresh_impl)
         self._npad_hwm = 128
         self._npad_floor = 128    # reserve_blocks raises this
@@ -665,7 +681,27 @@ class AMRSim(ShapeHostMixin):
                                           corners=self._FAST_SETS[k])
             else:
                 out[k] = pad_tables(t, n_pad)
+        self._frow_index = self._force_row_index(out)
         return jax.device_put(out)
+
+    def _force_row_index(self, tables: dict):
+        """Host index of the g=4 sets' table rows by block (simple rows,
+        interpolation rows), from which _shape_inputs lists the rows of
+        a body's blocks so that the force pass assembles their labs
+        alone. One index serves the vector and the scalar set — the two
+        are built alike and differ in signs and weights only; None
+        (the pass then assembles all N labs and takes its rows) if they
+        ever do not, or without the single-device fast form."""
+        tv, ts = tables.get("vec4t"), tables.get("sca4t")
+        if not (isinstance(tv, FastHalo) and isinstance(ts, FastHalo)):
+            return None
+        names = ("dest_s", "src_ord", "dest", "idx_ord")
+        if not all(np.array_equal(getattr(tv.t, a), getattr(ts.t, a))
+                   for a in names):
+            return None
+        L2 = tv.t.L * tv.t.L
+        return (block_rows(tv.t.dest_s, L2, self._n_real),
+                block_rows(tv.t.dest, L2, self._n_real))
 
     def _build_pois(self, topo, n_pad: int):
         """Poisson operator build hook: the structured per-face form
@@ -1427,7 +1463,7 @@ class AMRSim(ShapeHostMixin):
         if with_forces:
             forces = self._forces_impl(
                 vel, pres, obs, uvw, t4v, t4s,
-                h[:, 0, 0, 0], xc, yc)
+                h[:, 0, 0, 0], xc, yc, lists=inputs)
         scalars = (uvw, obs.com, obs.mass, obs.inertia, dt_next, diag)
         return vel, pres, obs.chi[:, None], scalars, forces
 
@@ -1609,17 +1645,42 @@ class AMRSim(ShapeHostMixin):
     # ------------------------------------------------------------------
     @tracing.in_scope("forces")
     def _forces_impl(self, vel, pres, obs, uvw, t4v, t4s,
-                     hflat, xc, yc):
-        velp = assemble_labs_ordered(vel, t4v)                 # [N,2,L,L]
-        chip = assemble_labs_ordered(obs.chi[:, None], t4s)[:, 0]
-        sdfp = assemble_labs_ordered(obs.sdf[:, None], t4s)[:, 0]
-        pord = pres[:, 0]
+                     hflat, xc, yc, lists=None):
+        """The 19 sums per shape. ``lists[k]`` (_shape_inputs) names
+        the only block rows that can hold a surface cell of shape k —
+        ``fpos`` [C], -1 padded — and the reduction runs over those C
+        rows, the reference's per-obstacle-block loop (main.cpp:5573);
+        with ``fsrow`` / ``fgrow``, the table rows of those blocks,
+        their g=4 labs are assembled alone too. Without lists: all N."""
+        tight = lists is not None and "fgrow" in lists[0]
+        if tight:
+            # both scalars through ONE pass over the scalar set's rows
+            chisdf = jnp.stack([obs.chi, obs.sdf], axis=1)     # [N,2,..]
+        else:
+            labs = (assemble_labs_ordered(vel, t4v),           # [N,2,L,L]
+                    assemble_labs_ordered(obs.chi[:, None], t4s)[:, 0],
+                    assemble_labs_ordered(obs.sdf[:, None], t4s)[:, 0])
+        neg = _raster_neg(self.cfg, self.forest.dtype)
         out = []
         for k in range(len(self.shapes)):
+            rows = [*(() if tight else labs), pres[:, 0],
+                    obs.udef_s[k].transpose(1, 0, 2, 3), obs.sdf_s[k],
+                    xc, yc, hflat]
+            if lists is not None:
+                fpos = lists[k]["fpos"]
+                ok = fpos >= 0
+                rows = [a[jnp.where(ok, fpos, 0)] for a in rows]
+                # a pad entry reads row 0 as "far outside": no surface
+                rows[-4] = jnp.where(ok[:, None, None], rows[-4], neg)
+            if tight:
+                trows = (fpos, lists[k]["fsrow"], lists[k]["fgrow"])
+                cs = assemble_labs_rows(chisdf, t4s, *trows)
+                rows = [assemble_labs_rows(vel, t4v, *trows),
+                        cs[:, 0], cs[:, 1], *rows]
+            velp, chip, sdfp, pord, udef, own, xc_k, yc_k, h_k = rows
             out.append(surface_forces_blocks(
-                velp, pord, chip, sdfp,
-                obs.udef_s[k].transpose(1, 0, 2, 3), obs.sdf_s[k],
-                xc, yc, obs.com[k], uvw[k], self.cfg.nu, hflat, G=4))
+                velp, pord, chip, sdfp, udef, own, xc_k, yc_k,
+                obs.com[k], uvw[k], self.cfg.nu, h_k, G=4))
         return out
 
     # ------------------------------------------------------------------
@@ -1630,7 +1691,15 @@ class AMRSim(ShapeHostMixin):
         build the device rasterization inputs (the reference's
         AreaSegment-AABB block intersection, main.cpp:4208-4269). Window
         capacities are padded powers of two, so a moving body only
-        recompiles when it grows past the current capacity."""
+        recompiles when it grows past the current capacity.
+
+        ``fpos`` is the second, tight list: the blocks the force pass
+        reduces over (_forces_impl). A surface cell of body k has
+        own_sdf > -4h, so it lies within 4 cells of the body, and the
+        body lies inside its segments' boxes: every block whose box,
+        grown by 5 of its own cells, meets one of those boxes is
+        listed — a superset of the blocks where the pass's mask can be
+        true, whose size does not depend on the heading."""
         cfg = self.cfg
         f = self.forest
         order = self._order
@@ -1640,8 +1709,10 @@ class AMRSim(ShapeHostMixin):
         y0 = f.bj[order] * bs * h
         x1 = x0 + bs * h
         y1 = y0 + bs * h
-        dt_ = f.dtype
+        reach = 5.0 * h
+        dt_ = np.dtype(jnp.dtype(f.dtype).name)
         out = []
+        self._force_blocks = []
         for k, s in enumerate(self.shapes):
             r = self._raster_radius(s)
             cx, cy = s.com
@@ -1651,8 +1722,7 @@ class AMRSim(ShapeHostMixin):
             if len(idx) > self._wcap[k]:
                 self._wcap[k] = max(
                     16, 1 << int(np.ceil(np.log2(len(idx) * 1.3))))
-            pos = np.full(self._wcap[k], -1, np.int32)
-            pos[:len(idx)] = idx
+            pos = _padded_list(idx, self._wcap[k])
             # window-block origins/spacings ride along so the raster
             # kernel computes its cell coordinates instead of gathering
             # them from the (possibly sharded) per-block arrays
@@ -1662,24 +1732,77 @@ class AMRSim(ShapeHostMixin):
             wx0[:len(idx)] = x0[idx]
             wy0[:len(idx)] = y0[idx]
             wh[:len(idx)] = h[idx]
+            poly = s.surface_polygon()
+            lo, hi = self._segment_boxes(s, poly)
+            near = ((x1 + reach)[:, None] > lo[:, 0]) \
+                & ((x0 - reach)[:, None] < hi[:, 0]) \
+                & ((y1 + reach)[:, None] > lo[:, 1]) \
+                & ((y0 - reach)[:, None] < hi[:, 1])
+            fidx = np.nonzero(near.any(axis=1))[0].astype(np.int32)
+            # ... and the g=4 table rows that fill those blocks' ghosts
+            trows = [rows_of_blocks(ix, fidx)
+                     for ix in self._frow_index or ()]
+            need = max(map(len, trows), default=0)
+            if len(fidx) > self._fcap[k] or need > self._frcap[k]:
+                # grown BEFORE the dispatch: no step reduces over a
+                # truncated list (it costs a recompile, so it is counted)
+                if len(fidx) > self._fcap[k]:
+                    self._fcap[k] = _bucket(int(1.3 * len(fidx)), lo=16)
+                if need > self._frcap[k]:
+                    self._frcap[k] = _bucket(int(1.3 * need), lo=512)
+                self._fcap_growths += 1
+                from .resilience import record_event
+                record_event(event="force_cap_grow",
+                             step=int(self.step_count), shape=k,
+                             blocks=len(fidx), cap=self._fcap[k],
+                             rows=need, row_cap=self._frcap[k],
+                             growths=self._fcap_growths)
+            flist = {"fpos": _padded_list(fidx, self._fcap[k])}
+            if trows:
+                flist["fsrow"], flist["fgrow"] = (
+                    _padded_list(a, self._frcap[k]) for a in trows)
+            self._force_blocks.append(len(fidx))
             mid_r, mid_v, mid_nor, mid_vnor = s.midline_comp_frame()
             com = np.asarray(s.com, np.float64)
             # packed body-frame tables (see _window_sdf_udef): com is
             # subtracted host-side in f64 so the device sees two large
             # operands instead of ~13 tiny derived arrays
-            seg = pack_polygon_segments(s.surface_polygon() - com)
+            seg = pack_polygon_segments(poly - com)
             mid = pack_midline(mid_r - com, mid_v, mid_nor, mid_vnor,
                                s.width)
             out.append({
-                "pos": jnp.asarray(pos),
-                "wx0": jnp.asarray(wx0, dtype=dt_),
-                "wy0": jnp.asarray(wy0, dtype=dt_),
-                "wh": jnp.asarray(wh, dtype=dt_),
-                "seg": jnp.asarray(seg, dtype=dt_),
-                "mid": jnp.asarray(mid, dtype=dt_),
-                "com": jnp.asarray(s.com, dtype=dt_),
+                **flist,
+                "pos": pos,
+                "wx0": wx0.astype(dt_), "wy0": wy0.astype(dt_),
+                "wh": wh.astype(dt_),
+                "seg": seg.astype(dt_), "mid": mid.astype(dt_),
+                "com": com.astype(dt_),
             })
-        return out
+        # ONE transfer for every leaf of every body, cast on the host:
+        # a jnp.asarray per leaf is a dispatch each (and a convert on
+        # the device where it casts), 20 a step between two dispatches
+        # of a loop in which the host sets the pace
+        return jax.device_put(out)
+
+    def _segment_boxes(self, s, poly):
+        """Axis-aligned boxes ([n, 2] low and high corners) that together
+        hold the body: its surface polygon cut across into segments
+        about one finest block long (the reference's AreaSegments,
+        main.cpp:4208-4236). Vertex i and vertex P-1-i face each other
+        across the body — the two skins of one midline node on a fish,
+        the ends of a chord on a disk — so a segment is both sides'
+        vertices between two such cuts, and its box holds their hull."""
+        half = (len(poly) + 1) // 2
+        sides = np.stack([poly[:half], poly[::-1][:half]])  # [2, half, 2]
+        plo = sides.min(axis=0)
+        phi = sides.max(axis=0)
+        n = int(np.ceil(s.length / (self.cfg.bs * self.cfg.min_h)))
+        cuts = np.linspace(0, half - 1, min(max(n, 1), half - 1) + 1
+                           ).astype(int)
+        # each segment runs up to AND including the next one's first cut
+        lo = np.minimum(np.minimum.reduceat(plo, cuts[:-1]), plo[cuts[1:]])
+        hi = np.maximum(np.maximum.reduceat(phi, cuts[:-1]), phi[cuts[1:]])
+        return lo, hi
 
     def _rasterize(self) -> ObstacleForestFields:
         self._refresh()
@@ -1725,6 +1848,19 @@ class AMRSim(ShapeHostMixin):
         lb = int(np.ceil((float(hi[0] - lo[0]) + pad) / bh)) + 1
         wb = int(np.ceil((float(hi[1] - lo[1]) + pad) / bh)) + 1
         return lb * wb
+
+    def _force_blocks_estimate(self, s) -> int:
+        """Finest-level blocks the force pass's list can hold for shape
+        ``s`` at ANY heading (sizes the static capacity ``_fcap``): a
+        box of the body's length by its thickness, grown by the list's
+        reach of 5 cells, covers at most this many blocks however it
+        is turned; coarser blocks during the climb are fewer."""
+        cfg = self.cfg
+        h_fin = cfg.h_at(cfg.level_max - 1)
+        bh = cfg.bs * h_fin
+        grow = 10.0 * h_fin + np.sqrt(2.0) * bh
+        thick = 2.0 * float(np.max(s.width))
+        return int(np.ceil((s.length + grow) * (thick + grow) / bh ** 2))
 
     def _estimate_blocks(self, coarse_start: bool) -> int:
         """Upper-ish estimate of the peak active block count of the init
@@ -1810,6 +1946,12 @@ class AMRSim(ShapeHostMixin):
         for k, s in enumerate(self.shapes):
             want = int(2.6 * self._window_blocks_estimate(s)) + 16
             self._wcap[k] = max(self._wcap[k], _bucket(want, lo=16))
+            want = int(1.5 * self._force_blocks_estimate(s)) + 8
+            self._fcap[k] = max(self._fcap[k], _bucket(want, lo=16))
+            # a rim block has up to 192 ghost rows, a block among its
+            # like none: 32 a block holds the canonical climb's 49 a
+            # LISTED block (54 of them) with half again to spare
+            self._frcap[k] = max(self._frcap[k], 32 * self._fcap[k])
         if coarse:
             for key in list(f.blocks):
                 f.release(*key)

@@ -770,6 +770,81 @@ def assemble_labs_ordered(x: jnp.ndarray, tables):
     return _place(x, simple, general, t, bs, fh=fh)
 
 
+def block_rows(dest, L2: int, n_real: int):
+    """Host index of a table's rows by the block whose lab they write:
+    ``perm[start[b]:start[b + 1]]`` are the rows of ``dest`` that land
+    in block b < n_real (pad rows point past the real blocks and sort
+    behind them)."""
+    blk = np.asarray(dest) // L2
+    perm = np.argsort(blk, kind="stable").astype(np.int32)
+    start = np.searchsorted(blk[perm], np.arange(n_real + 1))
+    return perm, start
+
+
+def rows_of_blocks(index, blocks) -> np.ndarray:
+    """The table rows that write into the listed ``blocks``' labs (a
+    ``block_rows`` index), block after block."""
+    perm, start = index
+    lo = start[blocks]
+    cnt = start[blocks + 1] - lo
+    return perm[np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+                + np.arange(int(cnt.sum()))]
+
+
+def assemble_labs_rows(x: jnp.ndarray, fh: FastHalo, rows, srows, grows):
+    """``assemble_labs_ordered(x, fh)[rows]`` without the other rows:
+    the labs [C, dim, L, L] of the block rows ``rows`` [C] alone, from
+    the same paint and the same table rows — ``srows`` / ``grows``, the
+    simple and the interpolation rows that write into those blocks
+    (``rows_of_blocks``; -1 = padding). A padding entry of ``rows`` (-1)
+    yields block 0's interior with empty ghosts, for the caller to
+    mask. ``x`` may carry more components than a one-component table
+    set was built for (chi and sdf through one gather): sign and
+    weights broadcast."""
+    t = fh.t
+    n, dim, bs, _ = x.shape
+    L2 = t.L * t.L
+    C = rows.shape[0]
+    r = jnp.maximum(rows, 0)
+    # the paint of _paint_regions as ONE block-row gather and one
+    # concatenation of the nine tiles of a lab (eight masked neighbour
+    # strips around the interior): the same values, without a pass
+    # over the labs per strip
+    regions = _fc_regions(t.g, bs, fh.corners)
+    nbx = x[fh.nb[:len(regions), r]] * fh.mask[:len(regions), r][
+        :, :, None, None, None].astype(x.dtype)
+    west, east, south, north, *diag = [
+        nbx[o][:, :, ssy, ssx] for o, (_, _, ssy, ssx) in enumerate(regions)]
+    sw, se, nw, ne = diag or [
+        jnp.zeros((C, dim, t.g, t.g), x.dtype)] * 4
+    labs = jnp.concatenate([
+        jnp.concatenate([sw, south, se], axis=3),
+        jnp.concatenate([west, x[r], east], axis=3),
+        jnp.concatenate([nw, north, ne], axis=3)], axis=2)
+    # block row -> its place in the list (padding entries park at n)
+    slot = jnp.full((n + 1,), C, jnp.int32).at[
+        jnp.where(rows >= 0, rows, n)].set(jnp.arange(C, dtype=jnp.int32))
+
+    def local(sel, dest):
+        d = dest[jnp.maximum(sel, 0)]
+        return jnp.where(sel >= 0, slot[d // L2] * L2 + d % L2, C * L2)
+
+    flat = x.transpose(1, 0, 2, 3).reshape(dim, n * bs * bs)
+    si = jnp.maximum(srows, 0)
+    gi = jnp.maximum(grows, 0)
+    simple = flat[:, t.src_ord[si]].T * t.sign[si]
+    w = t.w[gi]
+    w = jnp.broadcast_to(w, (*w.shape[:-1], dim))
+    general = jnp.einsum("dgk,gkd->gd", flat[:, t.idx_ord[gi]], w,
+                         precision=jax.lax.Precision.HIGHEST)
+    labs_flat = labs.transpose(1, 0, 2, 3).reshape(dim, C * L2)
+    labs_flat = labs_flat.at[:, local(srows, t.dest_s)].set(
+        simple.T.astype(labs.dtype), mode="drop")
+    labs_flat = labs_flat.at[:, local(grows, t.dest)].set(
+        general.T.astype(labs.dtype), mode="drop")
+    return labs_flat.reshape(dim, C, t.L, t.L).transpose(1, 0, 2, 3)
+
+
 def _place(interior, simple, general, t: HaloTables, bs: int,
            fh: "FastHalo | None" = None):
     dim = interior.shape[1]

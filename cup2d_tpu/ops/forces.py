@@ -12,15 +12,24 @@ lift, and output/deformation power — the reference's 19-component
 per-shape reduction (main.cpp:7188-7284).
 
 `surface_forces_block` is the single-tile core over ghost-padded labs,
-with the reference's probe/stencil lab-edge gates; the AMR path vmaps it
+with the reference's probe/stencil lab-edge gates. The AMR path vmaps it
 over forest blocks with G=4 (the reference's own lab extent, including
 its stencil-order degradation at lab edges — see `surface_forces_blocks`)
-and the uniform wrapper `surface_forces` calls it as one big tile with
-G=10 ghosts, so derivative order degrades only near the *domain*
-boundary — a documented improvement over the reference's per-8-cell-block
-artifacts. Surface membership for overlapping bodies is cell-granular
-(own-sdf band) instead of the reference's block-granular choice — the
-second documented deviation.
+and, like the reference's loop over the blocks that hold a piece of that
+obstacle (main.cpp:5573, the AreaSegment-AABB block lists of 4208-4269),
+over the body's block list only: `AMRSim._shape_inputs` lists per body
+the blocks within the pass's reach of its segments' boxes and
+`AMRSim._forces_impl` hands `surface_forces_blocks` those rows (<= 128 at
+the two-fish case) instead of all N padded rows, nine tenths of which
+are pad rows, background or the other body (PR 29). The per-cell cost is
+the ~30 data-dependent lab gathers of the probe walk and the one-sided
+stencils; the surface detection's fixed +-1 neighbours are static
+slices. The uniform wrapper `surface_forces` calls the core as one big
+tile with G=10 ghosts, so derivative order degrades only near the
+*domain* boundary — a documented improvement over the reference's
+per-8-cell-block artifacts. Surface membership for overlapping bodies is
+cell-granular (own-sdf band) instead of the reference's block-granular
+choice — the second documented deviation.
 """
 
 from __future__ import annotations
@@ -62,12 +71,17 @@ def surface_forces_block(velp, pres, chip, sdfp, udef, own_sdf, xc, yc,
     def at_v(yy, xx):
         return velp[:, yy + G, xx + G]
 
+    def nb(lab, dy, dx):
+        """The interior shifted by a FIXED (dy, dx): a static slice of
+        the lab, where at_s/at_v would gather through index arrays."""
+        return lab[..., G + dy:G + dy + ny, G + dx:G + dx + nx]
+
     # --- surface detection (ComputeSurfaceNormals, main.cpp:3786-3810) ---
-    grad_hx = at_s(chip, iy, ix + 1) - at_s(chip, iy, ix - 1)
-    grad_hy = at_s(chip, iy + 1, ix) - at_s(chip, iy - 1, ix)
+    grad_hx = nb(chip, 0, 1) - nb(chip, 0, -1)
+    grad_hy = nb(chip, 1, 0) - nb(chip, -1, 0)
     i2h = 0.5 / h
-    grad_ux = i2h * (at_s(sdfp, iy, ix + 1) - at_s(sdfp, iy, ix - 1))
-    grad_uy = i2h * (at_s(sdfp, iy + 1, ix) - at_s(sdfp, iy - 1, ix))
+    grad_ux = i2h * (nb(sdfp, 0, 1) - nb(sdfp, 0, -1))
+    grad_uy = i2h * (nb(sdfp, 1, 0) - nb(sdfp, -1, 0))
     grad_usq = grad_ux * grad_ux + grad_uy * grad_uy + _EPS
     d_w = (0.5 * h) * (grad_hx * grad_ux + grad_hy * grad_uy) / grad_usq
     norm_x = -d_w * grad_ux
@@ -144,8 +158,7 @@ def surface_forces_block(velp, pres, chip, sdfp, udef, own_sdf, xc, yc,
     fxt = fxv + fxp
     fyt = fyv + fyp
 
-    u_here = at_v(iy, ix)[0]
-    v_here = at_v(iy, ix)[1]
+    u_here, v_here = nb(velp, 0, 0)
     vel_norm = jnp.sqrt(uvw[0] ** 2 + uvw[1] ** 2)
     unit_x = jnp.where(vel_norm > 0, uvw[0] / (vel_norm + _EPS), 0.0)
     unit_y = jnp.where(vel_norm > 0, uvw[1] / (vel_norm + _EPS), 0.0)
@@ -192,7 +205,8 @@ def _finish(sums, uvw):
 def surface_forces_blocks(velp, pres, chip, sdfp, udef, own_sdf, xc, yc,
                           com, uvw, nu, h, G=4):
     """AMR path: vmap the core over [N] forest blocks (velp [N, 2, L, L],
-    labs [N, L, L], interiors [N, ...], h [N]) and sum the partials."""
+    labs [N, L, L], interiors [N, ...], h [N]) and sum the partials. The
+    caller chooses the rows: all of the forest's, or a body's list."""
     core = functools.partial(surface_forces_block, G=G)
     per_block = jax.vmap(
         core, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None, None, None, 0),

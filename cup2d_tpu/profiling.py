@@ -352,7 +352,7 @@ class HostCounters:
 # always present; fields that do not apply to a path (AMR shape on a
 # uniform run, comm volume on a single device, counters when disabled)
 # are null — consumers key on names, never on presence.
-METRICS_SCHEMA_VERSION = 13
+METRICS_SCHEMA_VERSION = 14
 METRICS_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     # solver health + timestep state (the step's existing diag pull).
@@ -418,6 +418,12 @@ METRICS_KEYS = (
     # pull (shapes_host._bodies_record), so a run's body trajectory is
     # in metrics.jsonl and a reference can hold it. Null without shapes
     "bodies",
+    # the force pass's block lists (schema v14): per shape, the block
+    # rows the surface-force reduction ran over at this step's list
+    # build and the sticky power-of-two capacity they are padded to
+    # (amr.AMRSim._shape_inputs; a growth is a "force_cap_grow" event).
+    # Null without shapes and off the forest
+    "force_blocks", "force_cap",
     # comm volume (shard surface-exchange plan, per one vec3 exchange)
     "halo_real_bytes", "halo_padded_bytes",
     # host-side counters (per-step deltas; hbm peak is absolute);
@@ -612,6 +618,9 @@ class MetricsRecorder:
             rec[key] = str(kv) if kv is not None else None
         rec.update(self._amr_fields(sim))
         rec["bodies"] = diag.get("bodies")
+        fb = getattr(sim, "_force_blocks", None)
+        rec["force_blocks"] = list(fb) if fb else None
+        rec["force_cap"] = list(sim._fcap) if fb else None
         rec.update(self._comm_fields(sim))
         rec.update(self._counter_fields())
         rec.update(self._guard_fields())

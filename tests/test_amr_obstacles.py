@@ -242,3 +242,203 @@ def test_dump_forest_mixed_level(tmp_path):
     vel = np.asarray(f.fields["vel"][order], np.float32)
     assert np.allclose(attr[:, 0], vel[:, 0].ravel(), atol=1e-6)
     assert np.allclose(attr[:, 1], vel[:, 1].ravel(), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the surface-force pass over the body's block list (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+_FCFG = dict(bpdx=2, bpdy=1, level_max=4, level_start=2, extent=2.0,
+             dtype="float32", nu=4e-5, lam=1e6, rtol=2.0, ctol=1.0)
+
+
+def _two_fish(angle, gap=0.4):
+    """Two fish nose to nose as in twofish-amr-l8, the pair turned by
+    ``angle`` degrees about its middle."""
+    from cup2d_tpu.models import FishShape
+    cfg = SimConfig(**_FCFG)
+    a = np.deg2rad(angle)
+    d = 0.5 * gap * np.array([np.cos(a), np.sin(a)])
+    c = np.array([1.0, 0.5])
+    return AMRSim(cfg, shapes=[
+        FishShape(0.4, *(c + d), angle, cfg.min_h),
+        FishShape(0.4, *(c - d), angle + 180.0, cfg.min_h)])
+
+
+def _half_climbed_fish():
+    """One fish whose climb stopped half way: refined to the finest
+    level over its head half only, so its surface band crosses
+    coarse-fine edges (two levels and their 2:1 rings)."""
+    from cup2d_tpu.models import FishShape
+    cfg = SimConfig(**_FCFG)
+    sim = AMRSim(cfg, shapes=[FishShape(0.5, 1.0, 0.5, 20.0, cfg.min_h)])
+    for s in sim.shapes:
+        s.advect(0.0, cfg.extents)
+        s.midline(0.0)
+    lo, hi = sim._shape_bbox(sim.shapes[0])
+    sim._shape_bbox = lambda s: (lo, np.array([1.0, hi[1]]))
+    while sim._refine_toward_shapes():
+        pass
+    sim._initialized = True
+    return sim
+
+
+def _force_operands(sim):
+    """What _forces_impl reads, on the sim's forest: the rasterised
+    bodies, a smooth flow and pressure in the ordered layout."""
+    sim._refresh()
+    inputs = sim._shape_inputs()
+    obs = sim._raster_jit(inputs, sim._xc, sim._yc, sim._h3,
+                          sim._hsq_flat, sim._tables["sca1"])
+    xc, yc = sim._xc, sim._yc
+    vel = jnp.stack([jnp.sin(np.pi * xc) * jnp.cos(np.pi * yc) + 0.3 * yc,
+                     -jnp.cos(np.pi * xc) * jnp.sin(np.pi * yc)], axis=1)
+    pres = (jnp.cos(2.0 * xc) * jnp.sin(3.0 * yc))[:, None]
+    uvw = jnp.asarray([[0.05, -0.02, 0.3]] * len(sim.shapes), vel.dtype)
+    return inputs, (vel, pres, obs, uvw, sim._tables["vec4t"],
+                    sim._tables["sca4t"], sim._hflat, xc, yc)
+
+
+def _mask_blocks(sim, obs, k):
+    """Ordered block rows where the pass's surface mask (forces.py:
+    chi gradient, |D| > eps, own_sdf > -4h) is true in some cell."""
+    from cup2d_tpu.halo import assemble_labs_ordered
+    t4s = sim._tables["sca4t"]
+    chip = np.asarray(assemble_labs_ordered(obs.chi[:, None], t4s)[:, 0])
+    sdfp = np.asarray(assemble_labs_ordered(obs.sdf[:, None], t4s)[:, 0])
+    h = np.asarray(sim._hflat)[:, None, None]
+    c, m, p = slice(4, -4), slice(3, -5), slice(5, -3)
+    ghx = chip[:, c, p] - chip[:, c, m]
+    ghy = chip[:, p, c] - chip[:, m, c]
+    gux = (0.5 / h) * (sdfp[:, c, p] - sdfp[:, c, m])
+    guy = (0.5 / h) * (sdfp[:, p, c] - sdfp[:, m, c])
+    d_w = (0.5 * h) * (ghx * gux + ghy * guy) \
+        / (gux * gux + guy * guy + 2.220446049250313e-16)
+    mask = (ghx * ghx + ghy * ghy >= 1e-12) \
+        & (np.abs(d_w) > 2.220446049250313e-16) \
+        & (np.asarray(obs.sdf_s[k]) > -4.0 * h)
+    return set(np.nonzero(mask.any(axis=(1, 2)))[0].tolist())
+
+
+def _assert_lists_hold(sim):
+    """The compacted pass against the pass over all N rows, all 19 keys
+    to f32 summation round-off, and every masked block on its list."""
+    import jax
+    from cup2d_tpu.ops.forces import FORCE_KEYS
+    inputs, ops = _force_operands(sim)
+    full = jax.jit(sim._forces_impl)(*ops)
+    # the lists as the step hands them over: the listed blocks' labs
+    # assembled alone ...
+    assert all({"fpos", "fsrow", "fgrow"} <= set(inp) for inp in inputs)
+    tight = jax.jit(sim._forces_impl)(*ops, lists=inputs)
+    # ... and without the table rows (the sharded form): all N labs
+    # assembled, the listed rows taken
+    taken = jax.jit(sim._forces_impl)(
+        *ops, lists=[{"fpos": inp["fpos"]} for inp in inputs])
+    levels = set()
+    for k in range(len(sim.shapes)):
+        rows = np.asarray(inputs[k]["fpos"])
+        rows = rows[rows >= 0]
+        assert len(rows) == sim._force_blocks[k] <= sim._fcap[k]
+        assert len(rows) < sim._n_real          # a list, not the forest
+        masked = _mask_blocks(sim, ops[2], k)
+        assert masked and masked <= set(rows.tolist()), \
+            masked - set(rows.tolist())
+        levels |= {int(sim.forest.level[sim._order[r]]) for r in masked}
+        # the scale of a key: no cancellation in perimeter (a sum of
+        # positive weights), so forces scale with it through nu/h and p
+        assert float(full[k]["perimeter"]) > 0.1
+        for key in FORCE_KEYS:
+            a = float(full[k][key])
+            scale = max(abs(a), 1e-3 * float(full[k]["perimeter"]))
+            for got in (tight, taken):
+                b = float(got[k][key])
+                assert abs(a - b) <= 1e-5 * scale, (k, key, a, b)
+    return levels
+
+
+@pytest.mark.parametrize("angle", [0.0, 45.0, 90.0, 180.0])
+def test_force_lists_match_the_full_pass_two_fish(angle):
+    sim = _two_fish(angle)
+    sim.initialize()
+    assert len({int(l) for l in sim.forest.level[sim._order]}) > 1  # mixed
+    _assert_lists_hold(sim)
+
+
+def test_force_lists_hold_across_a_coarse_fine_edge():
+    sim = _half_climbed_fish()
+    levels = _assert_lists_hold(sim)
+    assert len(levels) >= 2, levels     # surface cells at two levels
+
+
+@pytest.mark.parametrize("field", ["vel", "chi_sdf"])
+def test_row_labs_equal_the_full_assembly(field):
+    """halo.assemble_labs_rows gives the labs of the rows it is asked
+    for exactly as the full assembly holds them — paint, copy rows and
+    interpolation rows across coarse-fine edges — for the vector set
+    and for two scalars through the scalar set at once."""
+    from cup2d_tpu.halo import assemble_labs_ordered, \
+        assemble_labs_rows, rows_of_blocks
+    sim = _half_climbed_fish()
+    _, ops = _force_operands(sim)
+    vel, obs = ops[0], ops[2]
+    blocks = np.arange(sim._n_real)[::2].astype(np.int32)   # every other
+    srows, grows = (rows_of_blocks(ix, blocks) for ix in sim._frow_index)
+    assert len(srows) and len(grows)        # walls and coarse-fine edges
+    pad = lambda a, n: jnp.asarray(np.concatenate(
+        [a, np.full(n, -1, np.int32)]))
+    lists = (pad(blocks, 7), pad(srows, 5), pad(grows, 11))
+    if field == "vel":
+        x, t = vel, sim._tables["vec4t"]
+        want = assemble_labs_ordered(x, t)
+    else:
+        x, t = jnp.stack([obs.chi, obs.sdf], axis=1), sim._tables["sca4t"]
+        want = jnp.concatenate([assemble_labs_ordered(x[:, :1], t),
+                                assemble_labs_ordered(x[:, 1:], t)], 1)
+    got = np.asarray(assemble_labs_rows(x, t, *lists))[:len(blocks)]
+    want = np.asarray(want)[blocks]
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+def test_force_capacity_grows_before_the_dispatch():
+    """A list that outgrows its capacity grows it when the list is
+    built — before any dispatch — and says so; nothing is truncated."""
+    from cup2d_tpu.resilience import set_event_log
+
+    class Log:
+        rows = []
+
+        def emit(self, **row):
+            self.rows.append(row)
+
+    sim = _two_fish(0.0)
+    sim.initialize()
+    caps = list(sim._fcap)
+    assert sim._fcap_growths == 0 and all(c & (c - 1) == 0 for c in caps)
+    sim._refresh()
+    sim._shape_inputs()
+    assert sim._fcap == caps and sim._fcap_growths == 0     # pre-sized
+    sim._fcap = [4, caps[1]]
+    sim._frcap[0] = 1
+    set_event_log(Log())
+    try:
+        _assert_lists_hold(sim)
+    finally:
+        set_event_log(None)
+    n = sim._force_blocks[0]
+    assert 4 < n <= sim._fcap[0] and sim._fcap[0] & (sim._fcap[0] - 1) == 0
+    assert sim._fcap_growths == 1
+    (ev,) = [r for r in Log.rows if r["event"] == "force_cap_grow"]
+    assert (ev["shape"], ev["blocks"], ev["cap"], ev["growths"]) \
+        == (0, n, sim._fcap[0], 1)
+    assert 1 < ev["rows"] <= ev["row_cap"] == sim._frcap[0]
+
+
+def test_force_lists_null_without_shapes():
+    from cup2d_tpu.profiling import MetricsRecorder
+    cfg = SimConfig(**{**_FCFG, "level_max": 2, "level_start": 1})
+    sim = AMRSim(cfg, shapes=[])
+    r = MetricsRecorder().record(sim, sim.step_once(dt=1e-3))
+    assert r["n_blocks"] > 0
+    assert r["force_blocks"] is None and r["force_cap"] is None

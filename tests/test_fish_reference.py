@@ -7,7 +7,8 @@ against the program, at sizes a test run holds (ISSUE 28):
 - the reference's mass, centre and inertia of one rasterised fish
   against the program's uniform driver at three lengths;
 - telemetry schema 13: ``bodies`` and ``pad_blocks`` in a forest
-  record, null where there is no body / no forest;
+  record, null where there is no body / no forest; schema 14 (ISSUE
+  29): ``force_blocks`` and ``force_cap`` likewise;
 - the schema rule of ``compare``: records older than schema 13 leave the
   body numbers OUT, records of schema 13 without ``bodies`` read None.
 """
@@ -25,6 +26,7 @@ sys.path.insert(0, ROOT)
 CELL = "twofish-amr-l8.wake"
 SEEDS = (11, 2 ** 31 + 5, 4242424242)
 _RUNS: dict = {}
+_FILES: dict = {}       # what a run left beside its records, as text
 
 
 def _load(kind, name):
@@ -55,7 +57,14 @@ def _run(seed, capsys):
             rows = [json.loads(ln) for ln in f if ln.strip()]
         _RUNS[seed] = (json.loads(lines[-1]),
                        [r for r in rows if r.get("event") == "metrics"])
+        _FILES[seed] = {name: _text(name)
+                        for name in ("events.jsonl", "forces.csv")}
     return _RUNS[seed]
+
+
+def _text(name):
+    with open(os.path.join(ROOT, "benchmark_out", CELL, name)) as f:
+        return f.read()
 
 
 @pytest.mark.parametrize("seed", SEEDS)
@@ -75,11 +84,11 @@ def test_forest_start_up_against_the_reference(seed, capsys):
 def test_forest_record_carries_bodies_and_pad_blocks(capsys):
     from cup2d_tpu.profiling import METRICS_SCHEMA_VERSION
     _, records = _run(SEEDS[0], capsys)
-    assert METRICS_SCHEMA_VERSION == 13
+    assert METRICS_SCHEMA_VERSION == 14
     gets = {r["device_gets"] for r in records[12:]}
     assert gets == {2}, gets        # the step's pull + the guard's: as before
     for r in records:
-        assert r["schema"] == 13
+        assert r["schema"] == 14
         assert r["pad_blocks"] >= r["n_blocks"] > 0
         assert r["pad_blocks"] & (r["pad_blocks"] - 1) == 0     # a bucket
         (b,) = r["bodies"]
@@ -91,6 +100,27 @@ def test_forest_record_carries_bodies_and_pad_blocks(capsys):
     # the body moves: the path of its centre is in the records
     first, last = records[0]["bodies"][0], records[-1]["bodies"][0]
     assert np.hypot(*np.subtract(first["com"], last["com"])) > 1e-5
+
+
+def test_forest_record_carries_the_force_lists(capsys):
+    """Schema 14: the rows the surface-force pass ran over and the
+    capacity they are padded to, per body, in every forest record; the
+    capacity is a bucket sized before the first step and never grown."""
+    _, records = _run(SEEDS[0], capsys)
+    caps = {tuple(r["force_cap"]) for r in records}
+    assert len(caps) == 1, caps             # sticky: one capacity a run
+    for r in records:
+        (n,), (cap,) = r["force_blocks"], r["force_cap"]
+        assert 0 < n <= cap and cap & (cap - 1) == 0
+        assert n < r["n_blocks"]            # a list, not the forest
+    left = _FILES[SEEDS[0]]
+    assert "force_cap_grow" not in left["events.jsonl"]
+    # the diagnostics keep their cadence: one row a body a step
+    header, *rows = [ln.split(",")
+                     for ln in left["forces.csv"].splitlines() if ln]
+    assert header[:3] == ["time", "shape", "perimeter"]
+    assert len(rows) == len(records) and {r[1] for r in rows} == {"0"}
+    assert all(float(r[2]) > 0.0 for r in rows)     # a perimeter, each
 
 
 def test_uniform_records_null_where_nothing_applies():
@@ -106,10 +136,12 @@ def test_uniform_records_null_where_nothing_applies():
     rec.prime(empty)
     r = rec.record(empty, empty.step_once())
     assert r["bodies"] is None and r["pad_blocks"] is None
+    assert r["force_blocks"] is None and r["force_cap"] is None
     fish = Simulation(cfg, level=3, shapes=[
         FishShape(0.4, 1.0, 0.5, 0.0, cfg.min_h)])
     r = MetricsRecorder().record(fish, fish.step_once())
     assert r["pad_blocks"] is None          # no forest
+    assert r["force_blocks"] is None and r["force_cap"] is None
     (b,) = r["bodies"]
     assert b["mass"] == pytest.approx(fish.shapes[0].M)
     assert b["com"] == [float(c) for c in fish.shapes[0].com]

@@ -87,8 +87,12 @@ def _amr_sim():
 # "pd,pd,ns,ns" periodic channel); v13 the bodies and the pad bucket
 # (ISSUE 28): bodies — one entry a shape of com / angle / u / v / omega
 # / mass / inertia from the step's one existing pull, null without
-# shapes — and pad_blocks beside n_blocks, null off the forest.
-_SCHEMA_V13_KEYS = (
+# shapes — and pad_blocks beside n_blocks, null off the forest; v14 the
+# force pass's block lists (ISSUE 29): force_blocks — per shape, the
+# block rows the forest's surface-force reduction ran over — and
+# force_cap, the sticky power-of-two capacity they are padded to; null
+# without shapes and off the forest.
+_SCHEMA_V14_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     "umax", "dt_next",
     "poisson_iters", "poisson_residual",
@@ -100,6 +104,7 @@ _SCHEMA_V13_KEYS = (
     "energy", "div_linf",
     "n_blocks", "pad_blocks", "blocks_per_level", "refines", "coarsens",
     "bodies",
+    "force_blocks", "force_cap",
     "halo_real_bytes", "halo_padded_bytes",
     "jit_compiles", "device_gets", "state_gathers", "hbm_peak_bytes",
     "snap_ring_bytes", "replayed_steps",
@@ -113,15 +118,15 @@ _SCHEMA_V13_KEYS = (
 )
 
 
-def test_metrics_schema_v13_key_set_pinned():
+def test_metrics_schema_v14_key_set_pinned():
     from cup2d_tpu.profiling import METRICS_SCHEMA_VERSION
-    assert METRICS_SCHEMA_VERSION == 13
-    assert METRICS_KEYS == _SCHEMA_V13_KEYS
+    assert METRICS_SCHEMA_VERSION == 14
+    assert METRICS_KEYS == _SCHEMA_V14_KEYS
 
 
 @pytest.mark.slow   # ~17 s; duplicative tier-1 coverage: the frozen key
 #                     SET is pinned as a literal tuple in
-#                     test_metrics_schema_v13_key_set_pinned and the
+#                     test_metrics_schema_v14_key_set_pinned and the
 #                     uniform producer stream (every record, key-exact)
 #                     in test_cli_metrics_stream_and_post_report; the
 #                     AMR/bench records drilled here ride the identical
