@@ -29,8 +29,7 @@ Phases (default run, one chip):
   4  fleet server: turb2d pool, 4 slots serving 6 sessions
   5  kernel tiers, compiled: cavity under the four Pallas latches,
      the canonical case under FAS on the XLA tier and then with both
-     forest kernels (lab RHS + fused block update), and the round-4
-     single-op kernel's parity with the XLA RHS
+     forest kernels (lab RHS + fused block update)
 ``--chips 4`` runs ONLY the sharded phase: cavity and the canonical
 case over a 4-device mesh, each against the same argv on one device.
 
@@ -538,42 +537,6 @@ def phase5(sz: dict, refs: dict) -> None:
             run, sz, kernel_tier="pallas-fused", smoother_tier="strip")
         checks["parity_with_xla_tier"], par = run.parity(fas_ref, "xla")
         run.finish(checks, **par, **extra)
-
-    _r4_parity()
-
-
-def _r4_parity() -> None:
-    """The round-4 single-op kernel against the XLA RHS, bit for bit —
-    the check tests/test_pallas.py could never run in CI (the kernel
-    is compiled-TPU only; its compile is pinned by
-    tests/test_chip_compile.py)."""
-    import jax
-    import jax.numpy as jnp
-
-    from cup2d_tpu.ops.pallas_kernels import (advect_diffuse_rhs_pallas,
-                                              advect_supported)
-    from cup2d_tpu.ops.stencil import advect_diffuse_rhs
-    from cup2d_tpu.uniform import pad_vector
-
-    name, (ny, nx) = "5g-r4-kernel-parity", (128, 256)
-    if not advect_supported(ny, nx):
-        _emit({"phase": name, "ok": True,
-               "skipped": "compiled-TPU-only kernel; platform is "
-               f"{jax.devices()[0].platform}"})
-        return
-    vel = jnp.asarray(
-        np.random.default_rng(0).standard_normal((2, ny, nx)),
-        jnp.float32)
-    lab = pad_vector(vel, 3)
-    h, nu, dt = 1.0 / nx, 4e-5, 1e-3
-    ref = advect_diffuse_rhs(lab, 3, h, nu, dt)
-    got = advect_diffuse_rhs_pallas(lab, h, nu, dt, nx)
-    diff = float(jnp.max(jnp.abs(got - ref)))
-    _emit({"phase": name, "shape": [2, ny, nx],
-           "max_abs_diff_vs_xla": diff,
-           "checks": {"bit_equal": diff == 0.0}, "ok": diff == 0.0})
-    if diff != 0.0:
-        _faults.append(f"{name}: max|diff|={diff}")
 
 
 # ---------------------------------------------------------------------

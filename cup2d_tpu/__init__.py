@@ -35,7 +35,6 @@ _LAZY = {
     "Forest": ("cup2d_tpu.forest", "Forest"),
     "ShardedUniformSim": ("cup2d_tpu.parallel.mesh", "ShardedUniformSim"),
     "ShardedAMRSim": ("cup2d_tpu.parallel.forest_mesh", "ShardedAMRSim"),
-    "PhaseTimers": ("cup2d_tpu.profiling", "PhaseTimers"),
     "enable_compilation_cache": ("cup2d_tpu.cache",
                                  "enable_compilation_cache"),
 }

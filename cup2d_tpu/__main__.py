@@ -13,8 +13,7 @@ names (`/root/reference/main.cpp:6306-6341`, `run.sh:1-22`): e.g.
 By default this executes the adaptive (AMR) path, exactly like the
 reference. Extra flags beyond the reference: ``-level N`` (force a
 single-resolution uniform run at level N), ``-dtype``, ``-output DIR``,
-``-checkpointEvery N``, ``-restart DIR``, ``-maxSteps N``, ``-profile``
-(per-phase timer report + cells*steps/s at exit), and ``-fleet B``
+``-checkpointEvery N``, ``-restart DIR``, ``-maxSteps N``, and ``-fleet B``
 (fleet batching, fleet.py: advance B independent obstacle-free uniform
 cases in ONE fused dispatch — per-member device dt/clocks, one batched
 diag pull for the whole fleet, per-member supervision via
@@ -305,9 +304,6 @@ def main(argv=None, *, sim_out=None) -> int:
         sim_out.append(sim)
     if p.has("restart"):
         load_checkpoint(p("restart").asString(), sim)
-    if p.has("profile"):
-        from .profiling import PhaseTimers
-        sim.timers = PhaseTimers()
 
     if not fleet_n and hasattr(type(sim), "force_log_header"):
         # the obstacle-free sharded driver (ShardedUniformSim) computes
@@ -433,7 +429,7 @@ def main(argv=None, *, sim_out=None) -> int:
     # for step N is emitted when its verdict lands (during step N+1's
     # call, or at the final drain), labeled with the step's own
     # step/t/dt from the guard's record. The CALL-scoped host metrics
-    # (wall_ms, jit_compiles/device_gets deltas, phase_ms) therefore
+    # (wall_ms, jit_compiles/device_gets deltas) therefore
     # describe the call that resolved N — i.e. N+1's dispatch plus N's
     # lagged pull — a one-call skew that is CONSISTENT across those
     # fields (a compile spike and its wall cost land on the same row).
@@ -462,8 +458,8 @@ def main(argv=None, *, sim_out=None) -> int:
             capture_memory=not p.has("noMemLedger"),
             sink=spans_log).install()
         recorder = MetricsRecorder(sink=metrics_log, counters=counters,
-                                   timers=sim.timers, guard=guard,
-                                   server=server, flight=flight)
+                                   guard=guard, server=server,
+                                   flight=flight)
         recorder.prime(sim)
 
     def record(rec, wall_ms=None):
@@ -673,10 +669,6 @@ def main(argv=None, *, sim_out=None) -> int:
 
     if not uniform:
         sim.sync_fields()   # leave the slot fields dict current
-    if sim.timers is not None:
-        from .profiling import throughput
-        print(sim.timers.summary(), file=sys.stderr)
-        print(f"cup2d_tpu: {throughput(sim)}", file=sys.stderr)
     print(f"cup2d_tpu: done at t={sim.time:.6f} "
           f"after {sim.step_count} steps", file=sys.stderr)
     return 0

@@ -68,7 +68,6 @@ from .ops.stencil import advect_diffuse_rhs, divergence, dt_from_umax, \
 from .poisson import ForestFASCycle, _down2_mean, _up2_bilinear, \
     apply_block_precond_blocks, bicgstab, block_precond_matrix, \
     coarse_neumann_solve_dct, mg_solve
-from .profiling import NULL_TIMERS
 from .shapes_host import ShapeHostMixin
 
 
@@ -305,7 +304,6 @@ class AMRSim(ShapeHostMixin):
         self._npad_quiet = 0
         self.compute_forces_every = 1   # 0 disables the diagnostics pass
         self.force_log = None           # file-like, CSV rows
-        self.timers = None              # profiling.PhaseTimers, opt-in
         # cumulative regrid activity + shard comm-volume stats for the
         # telemetry stream (profiling.MetricsRecorder reports per-step
         # deltas; _comm_stats is populated by ShardedAMRSim)
@@ -384,8 +382,7 @@ class AMRSim(ShapeHostMixin):
         f = self.forest
         if self._tables_version == f.version:
             return
-        with (self.timers or NULL_TIMERS).phase("tables"), \
-                tracing.span("tables", step=int(self.step_count)):
+        with tracing.span("tables", step=int(self.step_count)):
             self._refresh_impl()
 
     def _refresh_impl(self):
@@ -432,41 +429,32 @@ class AMRSim(ShapeHostMixin):
         self._n_real = n_real
         self._mask = np.arange(n_pad) < n_real
 
-        tm = self.timers or NULL_TIMERS
         # one dense topology index shared by all 6-8 table builds
         topo = _TopoIndex(f, self._order)
-        with tm.phase("tables/build"):
-            raw = {
-                "vec3": build_tables(f, self._order, 3, True, 2,
-                                     topo=topo),
-                "vec1": build_tables(f, self._order, 1, False, 2,
-                                     topo=topo),
-                "sca1": build_tables(f, self._order, 1, False, 1,
-                                     topo=topo),
-                "vec1t": build_tables(f, self._order, 1, True, 2,
-                                      topo=topo),
-                "sca1t": build_tables(f, self._order, 1, True, 1,
-                                      topo=topo),
-            }
-            if self.shapes:
-                # chi tagging (g=4 scalar) + forces (g=4 vector)
-                raw["sca4t"] = build_tables(f, self._order, 4, True, 1,
-                                            topo=topo)
-                raw["vec4t"] = build_tables(f, self._order, 4, True, 2,
-                                            topo=topo)
+        raw = {
+            "vec3": build_tables(f, self._order, 3, True, 2, topo=topo),
+            "vec1": build_tables(f, self._order, 1, False, 2, topo=topo),
+            "sca1": build_tables(f, self._order, 1, False, 1, topo=topo),
+            "vec1t": build_tables(f, self._order, 1, True, 2, topo=topo),
+            "sca1t": build_tables(f, self._order, 1, True, 1, topo=topo),
+        }
+        if self.shapes:
+            # chi tagging (g=4 scalar) + forces (g=4 vector)
+            raw["sca4t"] = build_tables(f, self._order, 4, True, 1,
+                                        topo=topo)
+            raw["vec4t"] = build_tables(f, self._order, 4, True, 2,
+                                        topo=topo)
         # one async transfer for every table leaf (pad_tables returns
         # numpy on purpose; per-leaf jnp.asarray would synchronize per
         # array, one host sync per leaf on every regrid)
-        with tm.phase("tables/put"):
-            fc = build_face_copy(f, self._order, n_pad, topo)
-            self._tables = self._finalize_tables(raw, n_pad, fc)
-            # makeFlux variable-resolution Poisson operator (flux.py):
-            # structured per-face form on a single device; the sharded
-            # subclass overrides with the lab-table + ppermute-exchange
-            # form (_build_pois)
-            self._tables["pois"] = self._build_pois(topo, n_pad)
-        with tm.phase("tables/corr"):
-            self._corr = self._finalize_corr(topo, n_pad)
+        fc = build_face_copy(f, self._order, n_pad, topo)
+        self._tables = self._finalize_tables(raw, n_pad, fc)
+        # makeFlux variable-resolution Poisson operator (flux.py):
+        # structured per-face form on a single device; the sharded
+        # subclass overrides with the lab-table + ppermute-exchange
+        # form (_build_pois)
+        self._tables["pois"] = self._build_pois(topo, n_pad)
+        self._corr = self._finalize_corr(topo, n_pad)
         # two-level preconditioner maps: every cell's coarse cell on
         # the uniform level-c grid + its area weight (cells coarser
         # than c deposit into the coarse cell under their center —
@@ -517,10 +505,6 @@ class AMRSim(ShapeHostMixin):
         self._xc = jnp.asarray(xc, f.dtype)
         self._yc = jnp.asarray(yc, f.dtype)
         self._tables_version = f.version
-        # charge the async table/constant device_puts to "tables", not
-        # to the first step that consumes them
-        (self.timers or NULL_TIMERS).fence(
-            "tables", self._tables, self._corr)
 
     def _build_coarse_maps(self, n_pad: int, n_real: int):
         """Host build of the two-level transfer structure (see
@@ -2065,7 +2049,6 @@ class AMRSim(ShapeHostMixin):
         self._refresh()
         f = self.forest
         if not self.shapes:
-            tm = self.timers or NULL_TIMERS
             ordf = self._ordered_state()
             if dt is None:
                 # same cached-umax policy as the obstacle path: the
@@ -2078,19 +2061,18 @@ class AMRSim(ShapeHostMixin):
                 # the trajectory is bit-identical to the eager path —
                 # float()ing a device scalar and re-putting it is a
                 # lossless round trip).
-                with tm.phase("dt"):
-                    if self._next_umax is not None:
-                        # post-regrid: same 1.05 prolongation-overshoot
-                        # guard as the obstacle path (ADVICE r2)
-                        fac = (1.0 if self._next_umax_version
-                               == f.version else 1.05)
-                        dt_dev = self._dt_from_umax(
-                            fac * jnp.asarray(self._next_umax, f.dtype),
-                            self._hmin())
-                        dt = (dt_dev if self.async_diag
-                              else self._float_pull(dt_dev))
-                    else:
-                        dt = self.compute_dt()
+                if self._next_umax is not None:
+                    # post-regrid: same 1.05 prolongation-overshoot
+                    # guard as the obstacle path (ADVICE r2)
+                    fac = (1.0 if self._next_umax_version
+                           == f.version else 1.05)
+                    dt_dev = self._dt_from_umax(
+                        fac * jnp.asarray(self._next_umax, f.dtype),
+                        self._hmin())
+                    dt = (dt_dev if self.async_diag
+                          else self._float_pull(dt_dev))
+                else:
+                    dt = self.compute_dt()
             elif self._last_iters_dev is not None and not self.async_diag:
                 # explicit-dt callers still drain the iters scalar
                 # (async mode keeps it on device: the guard's lagged
@@ -2098,38 +2080,35 @@ class AMRSim(ShapeHostMixin):
                 self._float_pull(jnp.zeros((), f.dtype))
             exact = self.step_count < 10 or self._force_exact
             dt_dev = jnp.asarray(dt, f.dtype)
-            with tm.phase("flow"):
-                vel, pres, diag = self._step_jit(
-                    ordf["vel"], ordf["pres"], dt_dev,
-                    self._h, self._hsq_flat, self._maskv,
-                    self._tables["vec3"], self._tables["vec1"],
-                    self._tables["sca1"], self._tables["pois"],
-                    self._corr, self._use_coarse(exact),
-                    exact_poisson=exact)
-                self._set_ordered(vel=vel, pres=pres)
-                # end-state umax stays a DEVICE scalar — the next
-                # step's dt derives from it without an extra field
-                # reduction, and only its one-scalar pull touches host
-                self._next_umax = diag["umax"]
-                self._next_umax_version = f.version
-                if not exact:
-                    # iters ride the NEXT dt pull (see _float_pull).
-                    # Exact-startup counts are excluded: they converge
-                    # 3 orders deeper with a different M, and would
-                    # spuriously trip the production trigger on
-                    # compressed forests (code-review r4)
-                    self._last_iters_dev = diag["poisson_iters"]
-                if self.async_diag:
-                    diag = dict(diag)
-                    diag["dt"] = dt_dev      # the lagged clock's source
-                    self.step_count += 1
-                    return diag              # no fence: no host sync
-                diag = dict(diag)
-                # the EXACT dt used (host float here), for the guard's
-                # replay record — a time-difference reconstruction is
-                # off by an ulp (review PR 4)
-                diag["dt"] = float(dt)
-                tm.fence("flow", vel)   # charge flow to "flow"
+            vel, pres, diag = self._step_jit(
+                ordf["vel"], ordf["pres"], dt_dev,
+                self._h, self._hsq_flat, self._maskv,
+                self._tables["vec3"], self._tables["vec1"],
+                self._tables["sca1"], self._tables["pois"],
+                self._corr, self._use_coarse(exact),
+                exact_poisson=exact)
+            self._set_ordered(vel=vel, pres=pres)
+            # end-state umax stays a DEVICE scalar — the next
+            # step's dt derives from it without an extra field
+            # reduction, and only its one-scalar pull touches host
+            self._next_umax = diag["umax"]
+            self._next_umax_version = f.version
+            if not exact:
+                # iters ride the NEXT dt pull (see _float_pull).
+                # Exact-startup counts are excluded: they converge
+                # 3 orders deeper with a different M, and would
+                # spuriously trip the production trigger on
+                # compressed forests (code-review r4)
+                self._last_iters_dev = diag["poisson_iters"]
+            diag = dict(diag)
+            if self.async_diag:
+                diag["dt"] = dt_dev      # the lagged clock's source
+                self.step_count += 1
+                return diag              # no host sync
+            # the EXACT dt used (host float here), for the guard's
+            # replay record — a time-difference reconstruction is
+            # off by an ulp (review PR 4)
+            diag["dt"] = float(dt)
             self.time += dt
             self.step_count += 1
             return diag
@@ -2137,7 +2116,6 @@ class AMRSim(ShapeHostMixin):
         if not getattr(self, "_initialized", False):
             self.initialize()
             self._refresh()
-        tm = self.timers or NULL_TIMERS
         # run the external-write invalidation BEFORE the dt branch: an
         # external forest.fields write between steps (wver moved) must
         # drop the cached _next_dt/_next_umax here exactly as on the
@@ -2169,24 +2147,21 @@ class AMRSim(ShapeHostMixin):
                 # argument from an asserted comment into an enforced
                 # bound (ADVICE r2): any overshoot up to 5% now tightens
                 # dt instead of silently stretching CFL.
-                with tm.phase("dt"):
-                    dt = min(float(self._dt_from_umax(
-                        jnp.asarray(1.05 * self._next_umax, f.dtype),
-                        self._hmin())),
-                        self._kinematic_dt_cap())
+                dt = min(float(self._dt_from_umax(
+                    jnp.asarray(1.05 * self._next_umax, f.dtype),
+                    self._hmin())),
+                    self._kinematic_dt_cap())
             else:
-                with tm.phase("dt"):
-                    dt = min(self.compute_dt(), self._kinematic_dt_cap())
+                dt = min(self.compute_dt(), self._kinematic_dt_cap())
 
         # ongrid host part (main.cpp:3992-4207)
         cfg = self.cfg
         step = int(self.step_count)
-        with tm.phase("kinematics"), tracing.span("kinematics", step=step):
+        with tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
                 s.midline(self.time)
-        with tm.phase("rasterize"), \
-                tracing.span("shape_inputs", step=step):
+        with tracing.span("shape_inputs", step=step):
             inputs = self._shape_inputs()
 
         prescribed = jnp.asarray(
@@ -2197,25 +2172,22 @@ class AMRSim(ShapeHostMixin):
             and self.step_count % self.compute_forces_every == 0)
         hmin = self._hmin()
         ordf = self._ordered_state()
-        with tm.phase("flow"):
-            vel, pres, chi_new, scalars, forces = self._mega_jit(
-                ordf["vel"], ordf["pres"],
-                inputs, prescribed, jnp.asarray(dt, f.dtype), hmin,
-                self._h, self._hsq_flat, self._maskv,
-                self._xc, self._yc,
-                self._tables["vec3"], self._tables["vec1"],
-                self._tables["sca1"], self._tables["pois"],
-                self._tables.get("vec4t"), self._tables.get("sca4t"),
-                self._corr, self._use_coarse(exact),
-                exact_poisson=exact,
-                with_forces=with_forces)
-            self._set_ordered(vel=vel, pres=pres, chi=chi_new)
-            # the ONE host pull of the step
-            uvw, com, mass, inertia, dt_next, diag, forces = \
-                jax.device_get((*scalars, forces))
-            diag["dt"] = float(dt)    # exact replay record (see above)
-            # the scalar pull alone does not prove the fields landed
-            tm.fence("flow", vel)
+        vel, pres, chi_new, scalars, forces = self._mega_jit(
+            ordf["vel"], ordf["pres"],
+            inputs, prescribed, jnp.asarray(dt, f.dtype), hmin,
+            self._h, self._hsq_flat, self._maskv,
+            self._xc, self._yc,
+            self._tables["vec3"], self._tables["vec1"],
+            self._tables["sca1"], self._tables["pois"],
+            self._tables.get("vec4t"), self._tables.get("sca4t"),
+            self._corr, self._use_coarse(exact),
+            exact_poisson=exact,
+            with_forces=with_forces)
+        self._set_ordered(vel=vel, pres=pres, chi=chi_new)
+        # the ONE host pull of the step
+        uvw, com, mass, inertia, dt_next, diag, forces = \
+            jax.device_get((*scalars, forces))
+        diag["dt"] = float(dt)    # exact replay record (see above)
         self._sync_shape_scalars_np(com, mass, inertia)
         uvw_np = np.asarray(uvw, dtype=np.float64)
         for k, s in enumerate(self.shapes):
@@ -2232,8 +2204,7 @@ class AMRSim(ShapeHostMixin):
             # (exact-startup counts excluded, see the step_jit path)
             self._last_iters = int(diag["poisson_iters"])
         if with_forces:
-            with tm.phase("forces"):
-                self._record_forces(forces)
+            self._record_forces(forces)
 
         self.time += dt
         self.step_count += 1
@@ -2242,12 +2213,11 @@ class AMRSim(ShapeHostMixin):
     # -- regrid --------------------------------------------------------
     def adapt(self):
         """Tag / 2:1-balance / refine / coarsen (main.cpp:4657-5440)."""
-        # refresh BEFORE entering the phase: table time always lands in
-        # the top-level "tables" bucket, never nested under "adapt" (so
-        # profiling.throughput can sum phases without double counting)
+        # refresh BEFORE opening the span: table time always lands in
+        # the top-level "tables" span, never nested under "regrid" (the
+        # benchmark's tables_ms and regrid_ms read them apart)
         self._refresh()
-        with (self.timers or NULL_TIMERS).phase("adapt"), \
-                tracing.span("regrid", step=int(self.step_count)):
+        with tracing.span("regrid", step=int(self.step_count)):
             return self._adapt_impl()
 
     def _adapt_impl(self):
@@ -2452,7 +2422,6 @@ class AMRSim(ShapeHostMixin):
             self._tables["vec1t"], self._tables["sca1t"]))
         self._n_refined += R
         self._n_coarsened += G
-        (self.timers or NULL_TIMERS).fence("adapt", dict(f.fields))
 
     def _regrid_apply_impl(self, fields, order, parents, child_slots,
                            sib_slots, parent_slots, tv, ts):

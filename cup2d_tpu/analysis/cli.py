@@ -1,7 +1,6 @@
 """graftlint CLI — ``python -m cup2d_tpu.analysis``.
 
-rc semantics (pinned by tests/test_analysis.py the way bench's smoke
-test pins the bench CLI):
+rc semantics (pinned by tests/test_analysis.py):
 
 * 0 — clean: no unsuppressed findings
 * 1 — findings: the tree violates an invariant

@@ -50,7 +50,7 @@ ENV_LATCH_SITES = {
     # per-grid constructor latches (stored as self._kernel_tier /
     # self.solver_mode+self.fas_fmg). CUP2D_PREC (PR 9) is the
     # storage-precision contract of the fused tier: ONE read site in
-    # the whole package — fleet/mesh/bench consume the grid's stored
+    # the whole package — fleet and mesh consume the grid's stored
     # tier string, so a mid-run env mutation can never flip the
     # precision of a compiled step
     ("uniform.py", "UniformGrid.__init__"): {"CUP2D_PALLAS",
@@ -177,7 +177,6 @@ LEADING_DIM_SCOPES = {
     # fused_mg_down / fused_mg_up (ISSUE 26) ride the same pipeline
     "ops/pallas_kernels.py": ("fused_advect_heun", "fused_lab_rhs",
                               "fused_correction", "_per_member",
-                              "advect_diffuse_rhs_pallas",
                               "_fused_substage_sharded",
                               "fused_jacobi_sweeps", "fused_mg_down",
                               "fused_mg_up", "_strip_pipeline",

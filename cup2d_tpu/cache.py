@@ -2,7 +2,7 @@
 
 Adaptive runs compile one executable per (bucket, window-capacity)
 combination — tens of multi-second TPU compiles that are identical
-across process restarts of the same case. The CLI, bench and driver
+across process restarts of the same case. The CLI and driver
 entry points all funnel through here; library users can call it once
 before building a sim. Safe to call repeatedly.
 

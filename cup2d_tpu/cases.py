@@ -108,7 +108,7 @@ def periodic_table() -> BCTable:
 
 def periodic_channel_table() -> BCTable:
     """Periodic in x, no-slip walls in y — the mixed table the
-    fftd+tridiag solve (and its bench arm) exercises."""
+    fftd+tridiag solve exercises."""
     return BCTable(periodic(), periodic(), no_slip(), no_slip())
 
 

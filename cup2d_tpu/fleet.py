@@ -188,7 +188,6 @@ class FleetSim:
         self._active = None
         self.shapes: list = []    # obstacle-free by construction
         self.case: Optional[str] = None  # case-registry tag (cases.py)
-        self.timers = None
         self.force_log = None
         self._next_dt = None      # [B] device vector (end-state dt_next)
         self._force_exact = False
@@ -518,30 +517,19 @@ class FleetSim:
         if dt_dev.ndim == 0:
             dt_dev = jnp.full((self.members,), dt_dev, g.dtype)
         exact = self.step_count < 10 or self._force_exact
-        timers = self.timers
-        if timers is None:
-            from .profiling import NULL_TIMERS
-            timers = NULL_TIMERS
-        with timers.phase("step"):
-            self.state, diag = self._step(self.state, dt_dev,
-                                          self._active,
-                                          exact_poisson=exact)
-            diag = dict(diag)
-            if "dt" not in diag:
-                # unmasked path: every slot advances by the dispatched
-                # dt (the masked trace returns its own zeroed-dead-lane
-                # vector from inside the jit)
-                diag["dt"] = dt_dev   # rides the one pull
-            self._next_dt = diag["dt_next"]
-            if self.async_diag:
-                # -profile must still attribute device time to the
-                # phase (fence = the documented cost of profiling, as
-                # on the other drivers); the no-timers path stays
-                # fence-free
-                timers.fence("step", self.state.vel)
-                self.step_count += 1
-                return diag
-            diag = jax.device_get(diag)   # the natural phase fence
+        self.state, diag = self._step(self.state, dt_dev, self._active,
+                                      exact_poisson=exact)
+        diag = dict(diag)
+        if "dt" not in diag:
+            # unmasked path: every slot advances by the dispatched
+            # dt (the masked trace returns its own zeroed-dead-lane
+            # vector from inside the jit)
+            diag["dt"] = dt_dev   # rides the one pull
+        self._next_dt = diag["dt_next"]
+        if self.async_diag:
+            self.step_count += 1
+            return diag
+        diag = jax.device_get(diag)
         self.times = self.times + np.asarray(diag["dt"], np.float64)
         self.time = self._fleet_time()
         self.step_count += 1

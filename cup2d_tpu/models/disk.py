@@ -3,7 +3,7 @@
 The reference only ships the fish (`-shapes` parser, main.cpp:6378-6446),
 but its immersed-boundary method is shape-agnostic — the disk exercises
 penalization, the momentum solve, and forces with an analytic geometry
-(BASELINE.json configs 2 and 5: fixed cylinder / moving disk). It reuses
+(a fixed cylinder, a moving disk). It reuses
 the exact same device pipeline as the fish: a surface polygon for the SDF
 kernel and a midline-node table for the (identically zero) deformation
 velocity.

@@ -1,56 +1,45 @@
 """Pallas TPU kernels for the advection hot loop.
 
-Two generations live here:
+The PR-9 **megakernel tier** (``fused_advect_heun`` /
+``fused_lab_rhs`` / ``fused_correction``): one kernel per RK
+substage that reads the velocity from HBM ONCE, synthesizes the
+free-slip ghost halo in VMEM, runs the whole WENO5 + diffusion +
+Heun-update chain on double-buffered row strips, and writes the
+substage result once — attacking the per-op dispatch chain and
+its re-reads of the velocity. The divide-free
+WENO weight normalization (single reciprocal of the summed alpha
+per component, bit-trick reciprocal for the scale-invariant
+normalizer) is shared VERBATIM from ops/stencil._weno5_weights, so
+the kernel and the XLA chain cannot drift numerically.
 
-1. The round-4 single-op kernel (``advect_diffuse_rhs_pallas``): one
-   WENO5 advect-diffuse RHS evaluation over a pre-padded lab, y-strip
-   grid with double-buffered x-chunk DMA. The single op is bound by
-   VPU divides and lane-shift permutes, not by the HBM traffic a
-   Pallas rewrite of ONE op can eliminate; its time against the XLA
-   fusion on the current chip is not measured. Kept as the history
-   baseline; compiled in tests/test_chip_compile.py, bit-parity with
-   the XLA RHS checked on the chip by chip_smoke.py.
+Strip DMA scheme: strips are DMA'd whole (sublane-aligned, each HBM
+row read exactly once) into a ring of FOUR VMEM slots; the halo
+rows of strip i are taken from the resident neighbor strips i-1 and
+i+1, and strip i+2 prefetches while i computes (4 slots because
+{i-1, i, i+1, i+2} must be distinct mod the ring size — a ring of 3
+lets the prefetch overwrite the live top-halo strip). Scratch and
+DMA semaphores persist across sequential grid steps on TPU and in
+interpret mode (probed), which is what makes the cross-program ring
+legal.
 
-2. The PR-9 **megakernel tier** (``fused_advect_heun`` /
-   ``fused_lab_rhs`` / ``fused_correction``): one kernel per RK
-   substage that reads the velocity from HBM ONCE, synthesizes the
-   free-slip ghost halo in VMEM, runs the whole WENO5 + diffusion +
-   Heun-update chain on double-buffered row strips, and writes the
-   substage result once — attacking the per-op dispatch chain and
-   its re-reads of the velocity. The divide-free
-   WENO weight normalization (single reciprocal of the summed alpha
-   per component, bit-trick reciprocal for the scale-invariant
-   normalizer) is shared VERBATIM from ops/stencil._weno5_weights, so
-   the kernel and the XLA chain cannot drift numerically.
+The kernels are leading-dim agnostic like ops/stencil.py: operands
+are flattened to one leading batch axis L with per-batch
+(afac, dfac) scale rows, so the SAME kernel serves the solo
+UniformSim (L=1), member-batched FleetSim (L=B, per-member dt), and
+— in lab form — forest-block batches (L=N, per-block h). On a TPU
+the kernels are always Mosaic-compiled; interpret mode exists only
+on a CPU run (JAX_PLATFORMS=cpu, the tests — validation, not
+performance; see _on_accel). ISSUE 16 closed the last two refusals:
+non-free-slip BC tables ride the in-VMEM affine ghost synthesis
+(one executable per BC token), and the sharded x-split rides the
+halo-mode kernel (_fused_substage_sharded) behind shard_halo.
+fused_advect_heun_sharded's ppermute-before-interior exchange.
 
-   Strip DMA scheme: strips are DMA'd whole (sublane-aligned, each HBM
-   row read exactly once) into a ring of FOUR VMEM slots; the halo
-   rows of strip i are taken from the resident neighbor strips i-1 and
-   i+1, and strip i+2 prefetches while i computes (4 slots because
-   {i-1, i, i+1, i+2} must be distinct mod the ring size — a ring of 3
-   lets the prefetch overwrite the live top-halo strip). Scratch and
-   DMA semaphores persist across sequential grid steps on TPU and in
-   interpret mode (probed), which is what makes the cross-program ring
-   legal.
-
-   The kernels are leading-dim agnostic like ops/stencil.py: operands
-   are flattened to one leading batch axis L with per-batch
-   (afac, dfac) scale rows, so the SAME kernel serves the solo
-   UniformSim (L=1), member-batched FleetSim (L=B, per-member dt), and
-   — in lab form — forest-block batches (L=N, per-block h). On a TPU
-   the kernels are always Mosaic-compiled; interpret mode exists only
-   on a CPU run (JAX_PLATFORMS=cpu, the tests — validation, not
-   performance; see _on_accel). ISSUE 16 closed the last two refusals:
-   non-free-slip BC tables ride the in-VMEM affine ghost synthesis
-   (one executable per BC token), and the sharded x-split rides the
-   halo-mode kernel (_fused_substage_sharded) behind shard_halo.
-   fused_advect_heun_sharded's ppermute-before-interior exchange.
-
-   bf16 storage tier: operands stored bf16 in HBM, every VMEM
-   accumulation in f32 (strips are upcast on entry, the final substage
-   result is written back f32). Storage halves the bytes the roofline
-   charges for the dominant reads; the f32 path stays bit-pinned by
-   the goldens.
+bf16 storage tier: operands stored bf16 in HBM, every VMEM
+accumulation in f32 (strips are upcast on entry, the final substage
+result is written back f32). Storage halves the bytes the roofline
+charges for the dominant reads; the f32 path stays bit-pinned by
+the goldens.
 """
 
 from __future__ import annotations
@@ -141,92 +130,11 @@ def _core_seq(lab, afac, dfac):
     return jnp.stack(outs)
 
 
-# ===========================================================================
-# round-4 single-op kernel (pre-padded lab, opt-in history baseline)
-# ===========================================================================
-
-def _adv_kernel(by, bx, nch, fac_ref, vp_ref, out_ref, scratch, sem):
-    """One y-strip per grid step; double-buffered DMA over x-chunks so
-    copy latency hides behind the WENO chain of the previous chunk."""
-    i = pl.program_id(0)
-
-    def dma(slot, c):
-        return pltpu.make_async_copy(
-            vp_ref.at[:, pl.ds(i * by, by + 8),
-                      pl.ds(c * bx, bx + 2 * _GX)],
-            scratch.at[slot], sem.at[slot])
-
-    dma(0, 0).start()
-
-    def chunk(c, _):
-        slot = _rem(c, 2)
-
-        @pl.when(c + 1 < nch)
-        def _():
-            dma(1 - slot, c + 1).start()
-
-        dma(slot, c).wait()
-        # alignment-only ghosts dropped by VALUE slices (only the DMA'd
-        # memref shape must be tile-aligned): y 4 -> 3, x 64 -> 3
-        lab = scratch[slot, :, 1:-1, _GX - _G:_GX + _G + bx]
-        out_ref[:, :, pl.ds(c * bx, bx)] = _core_seq(
-            lab, fac_ref[0], fac_ref[1])
-        return 0
-
-    jax.lax.fori_loop(0, nch, chunk, 0)
-
-
 def _pick(n: int, pref) -> int:
     for b in pref:
         if n % b == 0:
             return b
     return 0
-
-
-@functools.partial(jax.jit, static_argnames=("ny", "nx"))
-def _advect_call(vlab_aligned, facs, ny, nx):
-    by = _pick(ny, (32, 16, 8))
-    # 1024 cap: the round-4 selection-form WENO (2 recons built from
-    # 10 selects) carries more live VMEM temporaries than the r2 form;
-    # 2048-wide chunks exceeded the 16M scoped-vmem limit by ~3M
-    bx = _pick(nx, (1024, 512, 256, 128))
-    nch = nx // bx
-    kernel = functools.partial(_adv_kernel, by, bx, nch)
-    return pl.pallas_call(
-        kernel,
-        grid=(ny // by,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            # ANY leaves the lab where it lives (HBM at these sizes)
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((2, by, nx), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, ny, nx), vlab_aligned.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, 2, by + 8, bx + 2 * _GX), vlab_aligned.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )(facs, vlab_aligned)
-
-
-def advect_supported(ny: int, nx: int) -> bool:
-    """Gate for the round-4 single-op kernel: TPU-compiled only (its
-    DMA idioms are Mosaic-specific and it exists for the measured
-    history + parity test, not as a fallback tier)."""
-    if not _on_accel():
-        return False
-    return bool(_pick(ny, (32, 16, 8))) and bool(
-        _pick(nx, (1024, 512, 256, 128)))
-
-
-def advect_diffuse_rhs_pallas(vlab, h, nu, dt, nx):
-    """Drop-in for `advect_diffuse_rhs(vlab, 3, h, nu, dt)` on a uniform
-    grid. vlab: [2, Ny+6, Nx+6] ghost-padded lab."""
-    ny = vlab.shape[-2] - 2 * _G
-    # re-pad to the aligned halo layout: y 3->4, x 3->64 per side
-    vlab = jnp.pad(vlab, ((0, 0), (1, 1), (_GX - _G, _GX - _G)))
-    facs = jnp.stack([-dt * h, nu * dt]).astype(vlab.dtype)
-    return _advect_call(vlab, facs, ny, nx)
 
 
 # ===========================================================================

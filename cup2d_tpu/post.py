@@ -15,9 +15,7 @@ line per file — solver iteration stats, dt/wall distributions, energy
 endpoints, divergence peak, recompile/transfer counters, final AMR
 shape, serving latency percentiles and the compile blame ledger.
 Truncated/torn rows (a SIGKILL'd run's last line) are counted as
-``truncated_records``, never raised. BENCH_*.json embeds the same
-summary shape (bench.py), so a bench result and a production run read
-as one trajectory.
+``truncated_records``, never raised.
 
 ``--trace`` exports a run's flushed span timeline (``spans.jsonl``
 plus its per-process ``.pN`` siblings and rotated segments, the

@@ -15,8 +15,7 @@ metrics-on run is bit-identical to metrics-off with equal
   invariants (kinetic energy, max |∇·u|), AMR shape (per-level block
   histogram, refine/coarsen counts), comm volume (real/padded halo
   bytes of the shard exchange plan), host counters (jit recompiles,
-  ``device_get`` pulls, HBM high-water mark) and per-phase wall times —
-  streamed as JSONL through the PR-2 ``resilience.EventLog`` machinery
+  ``device_get`` pulls, HBM high-water mark) — streamed as JSONL through the PR-2 ``resilience.EventLog`` machinery
   (process-0 writer on pods). The key set is frozen
   (:data:`METRICS_KEYS`, schema-stability golden test).
 - :class:`HostCounters`: process-wide host-side counters — jit
@@ -28,23 +27,12 @@ metrics-on run is bit-identical to metrics-off with equal
   ``CUP2D_TRACE=start:stop[:logdir]`` wraps exactly steps
   ``[start, stop)`` of a production run in ``jax.profiler`` so a
   TensorBoard trace costs only its window, not the whole run.
-- :class:`PhaseTimers`: per-phase wall-clock accumulation. Instrumented
-  code must synchronize inside each phase — pass the phase's device
-  outputs through :meth:`PhaseTimers.fence` (without that, async
-  dispatch attributes device time to whoever synchronizes next).
-  Enable on a sim with ``sim.timers = PhaseTimers()``; ``report()``
-  gives totals, means, and counts per phase.
-- ``throughput(sim)``: the north-star cells*steps/s metric from a sim's
-  counters (works for uniform and forest sims).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import time
-from collections import defaultdict
-from contextlib import contextmanager
 from typing import Optional
 
 import numpy as np
@@ -52,93 +40,6 @@ import numpy as np
 import jax
 
 from . import tracing
-
-
-class PhaseTimers:
-    """Accumulates wall time per named phase across steps."""
-
-    def __init__(self):
-        self.acc = defaultdict(float)
-        self.count = defaultdict(int)
-
-    @contextmanager
-    def phase(self, name: str):
-        """Time a host-side block. The caller is responsible for device
-        fencing (pass the phase's outputs through `fence`)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.acc[name] += time.perf_counter() - t0
-            self.count[name] += 1
-
-    def fence(self, name: str, *arrays):
-        """Block until ``arrays`` (arrays or pytrees of arrays) are
-        ready, so the enclosing ``phase(name)`` block charges their
-        device time to the right phase instead of to whoever
-        synchronizes next. Call INSIDE the phase block; returns the
-        arrays unchanged so it can wrap a phase's outputs in place.
-        Non-jax leaves (numpy tables) pass through untouched."""
-        for a in arrays:
-            if a is not None:
-                jax.block_until_ready(a)
-        return arrays
-
-    def report(self) -> dict:
-        return {
-            name: {
-                "total_s": self.acc[name],
-                "mean_ms": 1e3 * self.acc[name] / max(1, self.count[name]),
-                "count": self.count[name],
-            }
-            for name in sorted(self.acc)
-        }
-
-    def summary(self) -> str:
-        rows = [f"{k:>16s}: {v['total_s']:8.3f}s total "
-                f"{v['mean_ms']:8.2f}ms/call x{v['count']}"
-                for k, v in self.report().items()]
-        return "\n".join(rows)
-
-
-def throughput(sim) -> dict:
-    """cells*steps/s so far, from the sim's own counters. For forest
-    sims the live cell count is used (the adapted count varies; this is
-    the instantaneous grid, matching how the reference would report)."""
-    if hasattr(sim, "forest"):
-        cells = len(sim.forest.blocks) * sim.forest.bs ** 2
-    else:
-        # a fleet steps B member grids per dispatch (fleet.FleetSim)
-        cells = sim.grid.nx * sim.grid.ny * getattr(sim, "members", 1)
-    wall = getattr(sim, "timers", None)
-    # top-level phases are non-nested by construction (adapt() refreshes
-    # tables BEFORE opening its phase); "a/b"-named sub-phases break the
-    # parent down and are excluded from the wall total
-    total = (sum(v for k, v in wall.acc.items() if "/" not in k)
-             if wall else float("nan"))
-    return {
-        "cells": cells,
-        "steps": sim.step_count,
-        "sim_time": sim.time,
-        "wall_s": total,
-        "cells_steps_per_sec": (
-            cells * sim.step_count / total if wall and total > 0
-            else float("nan")),
-    }
-
-
-class _NullTimers:
-    """No-op stand-in so instrumented code needs no branches."""
-
-    @contextmanager
-    def phase(self, name):
-        yield
-
-    def fence(self, name, *arrays):
-        return arrays
-
-
-NULL_TIMERS = _NullTimers()
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +253,7 @@ class HostCounters:
 # always present; fields that do not apply to a path (AMR shape on a
 # uniform run, comm volume on a single device, counters when disabled)
 # are null — consumers key on names, never on presence.
-METRICS_SCHEMA_VERSION = 14
+METRICS_SCHEMA_VERSION = 15
 METRICS_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     # solver health + timestep state (the step's existing diag pull).
@@ -470,8 +371,9 @@ METRICS_KEYS = (
     # null until a capture lands). Null with no recorder attached —
     # all three are host state, same zero-pull discipline as counters
     "span_count", "compile_ms_total", "hbm_exec_bytes",
-    # merged PhaseTimers wall times (per-step deltas, ms)
-    "phase_ms",
+    # (schema v15 takes out "phase_ms", the per-phase wall times of the
+    # fencing timers that went with it: null in every record a run
+    # without -profile wrote. Readers ignore the key in older streams)
 )
 
 _SERVE_KEYS = ("active_members", "occupancy", "admitted", "evicted",
@@ -520,26 +422,23 @@ class MetricsRecorder:
     """Assembles one :data:`METRICS_KEYS` record per step and streams
     it through ``sink`` (a ``resilience.EventLog`` — process-0 JSONL,
     unified with the PR-2 event stream; ``None`` returns records
-    without writing, the bench path).
+    without writing, as the tests do).
 
     The record costs no device work: every diag scalar arrives in the
     step's one existing batched pull (on library paths that keep diag
     scalars on device, ONE `device_get` fetches the union — same policy
     as ``resilience.health_verdict``), the AMR histogram is host numpy
-    cached per topology version, and counters/timers are host state."""
+    cached per topology version, and the counters are host state."""
 
     def __init__(self, sink=None, counters: Optional[HostCounters] = None,
-                 timers: Optional[PhaseTimers] = None, guard=None,
-                 server=None, flight=None):
+                 guard=None, server=None, flight=None):
         self.sink = sink
         self.counters = counters
-        self.timers = timers
         self.guard = guard          # resilience.StepGuard, opt-in
         self.server = server        # fleet.FleetServer, opt-in (v7)
         self.flight = flight        # tracing.FlightRecorder, opt-in (v10)
         self._last_time: Optional[float] = None
         self._last_counters = counters.snapshot() if counters else None
-        self._last_phase: dict = dict(timers.acc) if timers else {}
         self._last_regrid = (0, 0)
         self._last_replayed = 0
         self._last_remesh_ms = 0.0
@@ -600,7 +499,7 @@ class MetricsRecorder:
         for k in _DIAG_KEYS:
             rec[k] = _jsonable(k, vals.get(k))
         # the active solve-path latch (schema v4): a host string — from
-        # the diag when a producer supplies one (bench), else the
+        # the diag when a producer supplies one, else the
         # driver's .poisson_mode property; never a device value
         pm = diag.get("poisson_mode")
         if pm is None and sim is not None:
@@ -643,7 +542,6 @@ class MetricsRecorder:
             member_health = None
         rec["member_health"] = member_health
         rec.update(self._flight_fields())
-        rec["phase_ms"] = self._phase_fields()
         if self.sink is not None:
             self.sink.emit(event="metrics", **rec)
         return rec
@@ -767,16 +665,6 @@ class MetricsRecorder:
         return {"span_count": int(f.span_count),
                 "compile_ms_total": round(f.compile_ms_total, 3),
                 "hbm_exec_bytes": int(hbm) if hbm else None}
-
-    def _phase_fields(self) -> Optional[dict]:
-        if self.timers is None:
-            return None
-        cur = dict(self.timers.acc)
-        out = {k: round(1e3 * (v - self._last_phase.get(k, 0.0)), 3)
-               for k, v in cur.items()
-               if v - self._last_phase.get(k, 0.0) > 0.0}
-        self._last_phase = cur
-        return out
 
 
 class ClientStreams:
@@ -930,7 +818,7 @@ def load_metrics_report(path: str) -> tuple:
 def summarize_metrics(records: list) -> dict:
     """Aggregate a metrics stream (list of record dicts — from
     :func:`load_metrics` or directly from a recorder) into the summary
-    `python -m cup2d_tpu.post --metrics` prints and `bench.py` embeds."""
+    `python -m cup2d_tpu.post --metrics` prints."""
     recs = [r for r in records if r.get("event", "metrics") == "metrics"]
 
     def col(key):

@@ -46,7 +46,6 @@ from .ops.obstacle import (
     solve_rigid_momentum,
     window_coords,
 )
-from .profiling import NULL_TIMERS
 from .shapes_host import ShapeHostMixin
 from .uniform import FlowState, UniformGrid, pad_scalar
 
@@ -122,7 +121,6 @@ class Simulation(ShapeHostMixin):
         self._dt = tracing.named_jit("sim.dt", jax.jit(g.compute_dt))
         self.compute_forces_every = 1   # 0 disables the diagnostics pass
         self.force_log: Optional[object] = None  # file-like, CSV rows
-        self.timers = None              # profiling.PhaseTimers, opt-in
         self._next_dt: Optional[float] = None  # from last step's umax
         # StepGuard's escalation rung forces the exact (tol-0) Poisson
         # solve on a retried step (resilience.py); OR-ed with the
@@ -412,7 +410,6 @@ class Simulation(ShapeHostMixin):
         cfg = self.cfg
         if not self.shapes:
             # obstacle-free: plain uniform step (no rasterization pass)
-            tm = self.timers or NULL_TIMERS
             if dt is None:
                 if self._next_dt is not None:
                     # host float on the sync path; under async_diag the
@@ -420,8 +417,7 @@ class Simulation(ShapeHostMixin):
                     # back into the dispatch — no host round trip
                     dt = self._next_dt
                 else:
-                    with tm.phase("dt"):
-                        dt = float(self._dt(self.state.vel))
+                    dt = float(self._dt(self.state.vel))
             exact = self.step_count < 10 or self._force_exact
             dt_dev = jnp.asarray(dt, g.dtype)
             if self.async_diag:
@@ -437,70 +433,56 @@ class Simulation(ShapeHostMixin):
                 # a host sync, the thing this mode removes.
                 self.step_count += 1
                 return diag
-            with tm.phase("flow"):
-                self.state, diag = self._flow_step_empty(
-                    self.state, dt_dev,
-                    exact_poisson=exact, obstacle_terms=False)
-                # ONE batched pull of the whole diag dict (same single
-                # transfer that used to fetch dt_next alone) — the
-                # health verdict then reads pure host scalars for free
-                diag = jax.device_get(diag)
-                # the EXACT dt used, for the guard's replay record —
-                # reconstructing it as time-after minus time-before
-                # rounds differently by an ulp (review PR 4)
-                diag["dt"] = float(dt)
-                self._next_dt = float(diag["dt_next"])
-                tm.fence("flow", self.state)
+            self.state, diag = self._flow_step_empty(
+                self.state, dt_dev,
+                exact_poisson=exact, obstacle_terms=False)
+            # ONE batched pull of the whole diag dict (same single
+            # transfer that used to fetch dt_next alone) — the
+            # health verdict then reads pure host scalars for free
+            diag = jax.device_get(diag)
+            # the EXACT dt used, for the guard's replay record —
+            # reconstructing it as time-after minus time-before
+            # rounds differently by an ulp (review PR 4)
+            diag["dt"] = float(dt)
+            self._next_dt = float(diag["dt_next"])
             self.time += dt
             self.step_count += 1
             return diag
         if not getattr(self, "_initialized", False):
             self.initialize()
-        tm = self.timers or NULL_TIMERS
         if dt is None:
             if self._next_dt is not None:
                 dt = min(self._next_dt, self._kinematic_dt_cap())
             else:
-                with tm.phase("dt"):
-                    dt = min(float(self._dt(self.state.vel)),
-                             self._kinematic_dt_cap())
+                dt = min(float(self._dt(self.state.vel)),
+                         self._kinematic_dt_cap())
 
         # ongrid host part (main.cpp:3992-4207)
         step = int(self.step_count)
-        with tm.phase("kinematics"), tracing.span("kinematics", step=step):
+        with tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
                 s.midline(self.time)
 
-        with tm.phase("rasterize"):
-            with tracing.span("shape_inputs", step=step):
-                inputs = self._shape_inputs()
-            obs = self._rasterize(inputs)
-            self._sync_shape_scalars(obs)
-            # fence the field outputs too (the scalar pull above only
-            # proves the scalars landed): device raster time must land
-            # in THIS phase, not in whoever synchronizes next
-            tm.fence("rasterize", obs)
+        with tracing.span("shape_inputs", step=step):
+            inputs = self._shape_inputs()
+        obs = self._rasterize(inputs)
+        self._sync_shape_scalars(obs)
 
         prescribed = jnp.asarray(
             [[s.u, s.v, s.omega] for s in self.shapes], dtype=g.dtype
         ) if self.shapes else jnp.zeros((0, 3), g.dtype)
         exact = self.step_count < 10 or self._force_exact
-        with tm.phase("flow"):
-            self.state, uvw, diag = self._flow_step(
-                self.state, obs, prescribed,
-                jnp.asarray(dt, g.dtype), exact_poisson=exact)
-            # the whole diag dict rides the ONE existing batched pull
-            # (previously dt_next alone): the health verdict and the
-            # driver's umax read then cost no further transfers
-            uvw_np, diag = jax.device_get((uvw, diag))
-            uvw_np = np.asarray(uvw_np, dtype=np.float64)
-            diag["dt"] = float(dt)    # exact replay record (see above)
-            self._next_dt = float(diag["dt_next"])
-            # the scalar pull alone does not prove the donated state
-            # landed; charge the field compute to "flow", not to the
-            # next phase that happens to touch it
-            tm.fence("flow", self.state)
+        self.state, uvw, diag = self._flow_step(
+            self.state, obs, prescribed,
+            jnp.asarray(dt, g.dtype), exact_poisson=exact)
+        # the whole diag dict rides the ONE existing batched pull
+        # (previously dt_next alone): the health verdict and the
+        # driver's umax read then cost no further transfers
+        uvw_np, diag = jax.device_get((uvw, diag))
+        uvw_np = np.asarray(uvw_np, dtype=np.float64)
+        diag["dt"] = float(dt)    # exact replay record (see above)
+        self._next_dt = float(diag["dt_next"])
         for k, s in enumerate(self.shapes):
             if s.free:
                 s.u, s.v, s.omega = uvw_np[k]
@@ -508,8 +490,7 @@ class Simulation(ShapeHostMixin):
 
         if self.shapes and self.compute_forces_every and \
                 self.step_count % self.compute_forces_every == 0:
-            with tm.phase("forces"):
-                self._log_forces(obs, uvw)
+            self._log_forces(obs, uvw)
 
         self.time += dt
         self.step_count += 1

@@ -121,8 +121,8 @@ def taylor_green_state(grid) -> "FlowState":
     """Taylor–Green vortex compatible with the free-slip box: u = sin cos,
     v = -cos sin has zero normal velocity at all four walls and decays
     analytically as exp(-2 nu pi^2 (1/Lx^2 + 1/Ly^2) t) — the validation
-    case SURVEY.md §4 prescribes. Shared by tests, bench.py and
-    __graft_entry__.py."""
+    case SURVEY.md §4 prescribes. Shared by the CLI's sharded run, the
+    tests and __graft_entry__.py."""
     x, y = grid.cell_centers()
     lx, ly = grid.cfg.extents
     u = np.sin(np.pi * x / lx) * np.cos(np.pi * y / ly)
@@ -652,7 +652,7 @@ class UniformGrid:
         update and the chi*div(u_def) RHS term — they are identically
         zero without shapes, but XLA cannot know that and spends ~4 ms
         of full-field passes on them at 8192^2. The obstacle-free
-        drivers (UniformSim, Simulation's empty branch, bench.py) pass
+        drivers (UniformSim, Simulation's empty branch) pass
         False; the shaped path never calls this (it penalizes in
         Simulation._flow_step_impl)."""
         cfg = self.cfg
@@ -691,7 +691,6 @@ class UniformSim:
         self.step_count = 0
         self.shapes: list = []          # obstacle-free by construction
         self.case: Optional[str] = None  # case-registry tag (cases.py)
-        self.timers = None
         self.force_log = None
         self._next_dt = None            # cached end-state dt_next
         # supervision hooks (resilience.StepGuard): escalation-rung

@@ -4,10 +4,9 @@ Every rule is demonstrated LIVE on a seeded-violation snippet compiled
 from strings (never from repo files, so the fixtures can't rot with
 the tree) next to a clean twin that must pass; the suppression syntax
 is pinned including its failure mode (an allow without a reason is a
-config error, rc 2); and the CLI is smoke-pinned the way
-test_bench_smoke.py pins bench — a real subprocess, rc semantics and
-one JSON line, with the ``--only env-latch`` run agreeing with the
-pytest wrapper in test_env_latch.py.
+config error, rc 2); and the CLI is smoke-pinned — a real
+subprocess, rc semantics and one JSON line, with the ``--only
+env-latch`` run agreeing with the pytest wrapper in test_env_latch.py.
 """
 
 import json
@@ -395,7 +394,7 @@ def test_analysis_package_never_imports_jax():
 
 
 # ---------------------------------------------------------------------------
-# CLI smoke (subprocess, like test_bench_smoke.py)
+# CLI smoke (subprocess)
 # ---------------------------------------------------------------------------
 
 def _run_cli(*args, inputs=None):

@@ -30,7 +30,7 @@ from cup2d_tpu.cases import cavity_table
 from cup2d_tpu.ops import pallas_kernels as pk
 from cup2d_tpu.parallel import shard_halo as sh
 
-N = 8192           # bench's primary uniform width
+N = 8192           # the cavity cell's width
 NB = 2048          # canonical levelStart-5 block bucket (2 x 32 x 32)
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -120,14 +120,6 @@ def _block_update():
     return fn, [((NB, 8, 8), F32)] * 3 + [((64, 64), F32)]
 
 
-def _round4_rhs():
-    # tests/test_pallas.py's always-skipped parity test, as the compile
-    # case (its run-time bit-parity is chip_smoke.py phase 5)
-    def fn(lab, dt):
-        return pk.advect_diffuse_rhs_pallas(lab, 1.0 / N, 4e-5, dt, N)
-    return fn, [((2, N + 6, N + 6), F32), ((), F32)]
-
-
 ONE_CHIP_CASES = {
     "fused_advect_heun-f32": lambda: _advect(False, None),
     "fused_advect_heun-bf16": lambda: _advect(True, None),
@@ -142,7 +134,6 @@ ONE_CHIP_CASES = {
     "fused_mg_up-bf16": lambda: _mg_up(BF16),
     "fused_lab_rhs": _lab_rhs,
     "fused_block_jacobi_update": _block_update,
-    "advect_diffuse_rhs_pallas": _round4_rhs,
 }
 
 

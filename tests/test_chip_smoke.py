@@ -61,7 +61,7 @@ def test_chip_smoke_rehearsal_uniform_and_fleet():
         "0-platform", "1a-tgv_periodic-default", "1b-tgv_periodic-fftd",
         "2-cavity", "4-fleet-serve", "5a-cavity-pallas",
         "5b-cavity-pallas-bf16", "5c-cavity-pallas-fas",
-        "5d-cavity-pallas-fas-bf16", "5g-r4-kernel-parity"))
+        "5d-cavity-pallas-fas-bf16"))
     by_phase = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert by_phase["0-platform"]["checks"]["native_available"] is True
     assert by_phase["0-platform"]["cache_dir_from"] == "checkout"

@@ -123,7 +123,7 @@ def test_fftd_one_application_converges(table, monkeypatch):
 def test_fftd_f32_production_criterion(monkeypatch):
     """The acceptance probe's tier-1 twin: cold mean-free RHS in f32 at
     128^2 meets the production Linf criterion in the single direct
-    application (the 1024^2 version is bench.py's fftd_periodic arm)."""
+    application."""
     g = _grid(periodic_table(), monkeypatch, level=4, dtype="float32")
     rhs = _mean_free((g.ny, g.nx), 12).astype(jnp.float32)
     res = g.pressure_solve(rhs)

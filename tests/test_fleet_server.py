@@ -337,6 +337,34 @@ def test_zero_recompile_steady_state_churn(tmp_path):
     log.close()
 
 
+def test_served_pool_fills_latency_histograms():
+    """A real served pool under churn fills all three pool-wide
+    latency distributions (tests/test_tracing.py holds the collector's
+    arithmetic alone): five sessions on three slots, so two requests
+    wait in the queue for a retirement before they are seated."""
+    from cup2d_tpu.tracing import ServingLatency
+
+    sim = _pool(3)
+    server = FleetServer(sim, latency=ServingLatency())
+    for n in range(5):
+        st = _session_state(sim.grid, n % 3)
+        server.submit(FleetRequest(
+            client_id=f"c{n:03d}", state=st,
+            t_end=1.9 * float(sim._member_dt(st.vel))))
+    steps = 0
+    while server.retired < 5 and steps < 12:
+        server.step()
+        steps += 1
+    assert server.admitted == server.retired == 5 and server.evicted == 0
+    pool = server.latency.report()["pool"]
+    assert pool["queue_wait"]["count"] == 5, pool
+    assert pool["admit_to_first_step"]["count"] == 5, pool
+    # every fused step is observed once for each client it carried
+    assert pool["step"]["count"] >= 10, pool
+    for kind in ServingLatency.KINDS:
+        assert pool[kind]["p99_ms"] >= pool[kind]["p50_ms"] > 0, pool
+
+
 def test_zero_recompile_bc_pallas_pool_churn(tmp_path, monkeypatch):
     """ISSUE-16 acceptance: the zero-recompile contract extends to a
     BC'd fused-kernel pool. All BC coefficients are trace-time

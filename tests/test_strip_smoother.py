@@ -350,23 +350,43 @@ def test_bicgstab_same_iterations_either_hierarchy():
     assert iters["strip"] == iters["xla"] > 0, iters
 
 
+def multiscale_state(grid):
+    """O(1) velocity with genuine multi-scale divergence: a shear-layer
+    pair, a mid-scale mode, and a non-solenoidal mode at a FIXED 64
+    cells/wavelength, which keeps the Poisson load resolution-invariant
+    (undivided divergence ~ A^2 * h * k stays constant when k grows
+    with N). Free-slip-compatible normal components (sin -> 0 at the
+    walls) keep the box BCs consistent."""
+    x, y = grid.cell_centers()
+    lx, ly = grid.cfg.extents
+    xs, ys = np.pi * x / lx, np.pi * y / ly
+    m = max(grid.nx // 64, 32)
+    u = (np.sin(xs) * np.cos(ys)
+         + 0.25 * np.sin(8 * xs) * np.cos(8 * ys)
+         + 0.3 * np.sin(m * xs) * np.sin(m * ys))
+    v = (-np.cos(xs) * np.sin(ys)
+         + 0.25 * np.sin(16 * ys) * np.sin(16 * xs)
+         + 0.3 * np.sin(m * ys) * np.sin(m * xs))
+    vel = jnp.asarray(np.stack([u, v]), dtype=grid.dtype)
+    return grid.zero_state()._replace(vel=vel)
+
+
 def test_bf16_leg_mg_solve_same_criterion():
     """The tentpole's convergence contract: bf16 legs under mg_solve's
     f32 true-residual outer loop converge by the SAME Linf criterion
     with iters within +1 of the f32-leg arm (iterative refinement —
-    the legs only shape the correction). The probe is the REALISTIC
-    bench RHS (vortex-field divergence at production tol_rel): on a
+    the legs only shape the correction). The probe is a REALISTIC
+    RHS (vortex-field divergence at production tol_rel): on a
     white-noise RHS at tol_rel 1e-4 the bf16 correction's resolution
     floor costs 29-vs-19 cycles — the +1 claim is a claim about
     production solves, not adversarial spectra."""
     from cup2d_tpu.ops.stencil import divergence_rhs
     from cup2d_tpu.uniform import UniformGrid, pad_vector
-    from bench import bench_state
 
     cfg = SimConfig(bpdx=1, bpdy=1, level_max=1, level_start=0,
                     extent=1.0, nu=4e-5, cfl=0.5, dtype="float32")
     grid = UniformGrid(cfg, level=4)        # 128^2 probe
-    st = bench_state(grid)
+    st = multiscale_state(grid)
     dt = jnp.asarray(0.5 * grid.h, grid.dtype)
     b = divergence_rhs(pad_vector(st.vel, 1), pad_vector(st.udef, 1),
                        st.chi, 1, grid.h, dt)

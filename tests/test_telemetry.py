@@ -3,8 +3,8 @@ additions): the frozen metrics schema, the zero-extra-sync contract
 (metrics-on bit-identical to metrics-off with EQUAL device_get counts —
 the PR-2 trace-count harness extended), the physics-invariant watchdog
 against injected wrong-but-finite corruption, the steady-state
-recompile/transfer-count guard, phase-timer fencing, and the windowed
-trace driver."""
+recompile/transfer-count guard, the readers' contract for streams of
+older schemas, and the windowed trace driver."""
 
 import glob
 import json
@@ -19,9 +19,8 @@ from cup2d_tpu.config import SimConfig
 from cup2d_tpu.faults import FaultPlan
 from cup2d_tpu.models import DiskShape
 from cup2d_tpu.profiling import (METRICS_KEYS, HostCounters,
-                                 MetricsRecorder, NULL_TIMERS,
-                                 PhaseTimers, TraceWindow, load_metrics,
-                                 summarize_metrics)
+                                 MetricsRecorder, TraceWindow,
+                                 load_metrics, summarize_metrics)
 from cup2d_tpu.resilience import (EventLog, PhysicsWatchdog, StepGuard)
 from cup2d_tpu.sim import Simulation
 
@@ -91,8 +90,11 @@ def _amr_sim():
 # force pass's block lists (ISSUE 29): force_blocks — per shape, the
 # block rows the forest's surface-force reduction ran over — and
 # force_cap, the sticky power-of-two capacity they are padded to; null
-# without shapes and off the forest.
-_SCHEMA_V14_KEYS = (
+# without shapes and off the forest; v15 takes phase_ms OUT (ISSUE 30:
+# the fencing phase timers went, and the key was null in every record
+# a run without them wrote) — readers ignore it in older streams
+# (test_older_schema_streams_still_load).
+_SCHEMA_V15_KEYS = (
     "schema", "step", "t", "dt", "wall_ms",
     "umax", "dt_next",
     "poisson_iters", "poisson_residual",
@@ -114,24 +116,68 @@ _SCHEMA_V14_KEYS = (
     "active_members", "occupancy", "admitted", "evicted",
     "queue_depth",
     "span_count", "compile_ms_total", "hbm_exec_bytes",
-    "phase_ms",
 )
 
 
-def test_metrics_schema_v14_key_set_pinned():
+def test_metrics_schema_v15_key_set_pinned():
     from cup2d_tpu.profiling import METRICS_SCHEMA_VERSION
-    assert METRICS_SCHEMA_VERSION == 14
-    assert METRICS_KEYS == _SCHEMA_V14_KEYS
+    assert METRICS_SCHEMA_VERSION == 15
+    assert METRICS_KEYS == _SCHEMA_V15_KEYS
+
+
+# the keys each older schema did not have yet (all three had phase_ms):
+# the streams a run of that time left behind
+_NOT_YET_IN = {
+    12: ("pad_blocks", "bodies", "force_blocks", "force_cap"),
+    13: ("force_blocks", "force_cap"),
+    14: (),
+}
+
+
+@pytest.mark.parametrize("schema", sorted(_NOT_YET_IN))
+def test_older_schema_streams_still_load(schema, tmp_path, capsys):
+    """The readers' contract that lets a key leave: a metrics.jsonl
+    written under an older schema — with phase_ms, without the keys
+    that came later — loads, summarises and prints through
+    post --metrics; unknown keys are ignored, missing keys read null."""
+    from cup2d_tpu import post
+
+    absent = _NOT_YET_IN[schema]
+    keys = [k for k in _SCHEMA_V15_KEYS if k not in absent]
+    path = tmp_path / "metrics.jsonl"
+    with open(path, "w") as f:
+        for n in (1, 2, 3):
+            rec = dict.fromkeys(keys)
+            rec.update(schema=schema, step=n, t=0.1 * n, dt=0.1,
+                       wall_ms=2.0, umax=1.0, poisson_iters=n,
+                       poisson_converged=True, energy=0.5,
+                       div_linf=1e-3, n_blocks=64, jit_compiles=0,
+                       device_gets=2,
+                       phase_ms={"flow": 1.5} if n == 2 else None)
+            assert set(rec) == set(keys) | {"phase_ms"}
+            f.write(json.dumps({"event": "metrics", "wall": 1.0 * n,
+                                **rec}) + "\n")
+    recs = load_metrics(str(path))
+    assert [r["step"] for r in recs] == [1, 2, 3]
+    assert all(r.get(k) is None for r in recs for k in absent)
+    s = summarize_metrics(recs)
+    assert s["steps"] == 3 and s["n_blocks_last"] == 64
+    assert s["poisson_iters"] == {"mean": 2.0, "max": 3.0}
+    assert "phase_ms" not in s
+    capsys.readouterr()
+    assert post.main(["--metrics", str(path)]) == 0
+    printed = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert printed["steps"] == 3 and printed["truncated_records"] == 0
 
 
 @pytest.mark.slow   # ~17 s; duplicative tier-1 coverage: the frozen key
 #                     SET is pinned as a literal tuple in
-#                     test_metrics_schema_v14_key_set_pinned and the
+#                     test_metrics_schema_v15_key_set_pinned and the
 #                     uniform producer stream (every record, key-exact)
 #                     in test_cli_metrics_stream_and_post_report; the
-#                     AMR/bench records drilled here ride the identical
+#                     AMR records drilled here ride the identical
 #                     MetricsRecorder.record_step path
-def test_metrics_schema_stable_uniform_amr_bench():
+def test_metrics_schema_stable_uniform_amr():
     gold = set(METRICS_KEYS)
 
     # uniform driver path
@@ -174,9 +220,8 @@ def test_metrics_schema_stable_uniform_amr_bench():
     assert ar["poisson_mode"] == "bicgstab+jacobi"
     assert ar["precond_cycles"] == 2 * ar["poisson_iters"] + 1
 
-    # bench path (record_step without a sim): same key set, so a
-    # BENCH_*.json telemetry block and a run's metrics.jsonl are one
-    # schema
+    # record_step without a sim (a caller that holds host scalars
+    # only): same key set
     host_diag = {k: r[k] for k in ("umax", "dt_next", "poisson_iters",
                                    "poisson_residual",
                                    "poisson_converged",
@@ -225,7 +270,7 @@ def test_metrics_kernel_tier_bc_suffix(monkeypatch):
     immune to a drain-time latch change) and mirrored by the recorder's
     diag-first pull — alongside the v8 bc_table token it suffixes. The
     default free-slip table keeps the bare PR-9 string (pinned above in
-    test_metrics_schema_stable_uniform_amr_bench)."""
+    test_metrics_schema_stable_uniform_amr)."""
     from cup2d_tpu.cases import cavity_table
     from cup2d_tpu.uniform import UniformSim, taylor_green_state
     monkeypatch.setenv("CUP2D_PALLAS", "1")
@@ -470,37 +515,6 @@ def test_steady_state_zero_recompiles_bounded_transfers():
 
 
 # ---------------------------------------------------------------------------
-# phase timers: fence exists, attributes, and the report covers phases
-# ---------------------------------------------------------------------------
-
-def test_phase_timers_fence_and_report():
-    sim = _sim()
-    sim.timers = PhaseTimers()          # pre-PR3 this crashed: only
-    sim.step_once()                     # _NullTimers had fence()
-    rep = sim.timers.report()
-    for phase in ("rasterize", "flow"):
-        assert phase in rep and rep[phase]["count"] == 1
-    # fence passes arrays through unchanged (same contract as
-    # NULL_TIMERS) and accepts pytrees
-    x = jnp.ones(3)
-    out = sim.timers.fence("x", x, {"a": x})
-    assert out[0] is x
-    assert NULL_TIMERS.fence("x", x)[0] is x
-
-
-@pytest.mark.slow   # ~11-25 s (fresh AMR init); the fence mechanism
-#                     itself is tier-1-covered by the uniform test above
-def test_phase_timers_fence_amr():
-    sim = _amr_sim()
-    sim.timers = PhaseTimers()
-    sim.initialize()
-    sim.adapt()
-    sim.step_once()
-    rep = sim.timers.report()
-    assert "tables" in rep and "flow" in rep
-
-
-# ---------------------------------------------------------------------------
 # windowed device tracing
 # ---------------------------------------------------------------------------
 
@@ -543,6 +557,15 @@ def test_trace_window_wraps_exact_steps(tmp_path):
 # CLI end-to-end (in-process): metrics stream + post --metrics report
 # ---------------------------------------------------------------------------
 
+_CLI_BOX = [
+    "-bpdx", "1", "-bpdy", "1", "-levelMax", "1", "-levelStart", "0",
+    "-Rtol", "2", "-Ctol", "1", "-extent", "1", "-CFL", "0.4",
+    "-tend", "1", "-lambda", "1e6", "-nu", "0.001",
+    "-poissonTol", "1e-3", "-poissonTolRel", "1e-2",
+    "-maxPoissonRestarts", "0", "-maxPoissonIterations", "100",
+    "-AdaptSteps", "20", "-tdump", "0", "-dtype", "float64"]
+
+
 def test_cli_metrics_stream_and_post_report(tmp_path, monkeypatch,
                                             capsys):
     from cup2d_tpu import post
@@ -551,17 +574,9 @@ def test_cli_metrics_stream_and_post_report(tmp_path, monkeypatch,
     monkeypatch.delenv("CUP2D_FAULTS", raising=False)
     monkeypatch.delenv("CUP2D_TRACE", raising=False)
     out = tmp_path / "run"
-    rc = main([
-        "-bpdx", "1", "-bpdy", "1", "-levelMax", "1", "-levelStart", "0",
-        "-Rtol", "2", "-Ctol", "1", "-extent", "1", "-CFL", "0.4",
-        "-tend", "1", "-lambda", "1e6", "-nu", "0.001",
-        "-poissonTol", "1e-3", "-poissonTolRel", "1e-2",
-        "-maxPoissonRestarts", "0", "-maxPoissonIterations", "100",
-        "-AdaptSteps", "20", "-tdump", "0", "-level", "3",
-        "-dtype", "float64",
-        "-shapes", "angle=0 L=0.25 xpos=0.5 ypos=0.5",
-        "-output", str(out), "-maxSteps", "3",
-    ])
+    rc = main(_CLI_BOX + [
+        "-level", "3", "-shapes", "angle=0 L=0.25 xpos=0.5 ypos=0.5",
+        "-output", str(out), "-maxSteps", "3"])
     assert rc == 0
     recs = load_metrics(str(out / "metrics.jsonl"))
     ms = [r for r in recs if r.get("event") == "metrics"]
@@ -577,3 +592,25 @@ def test_cli_metrics_stream_and_post_report(tmp_path, monkeypatch,
         capsys.readouterr().out.strip().splitlines()[-1])
     assert summary["steps"] == 3
     assert summary["source"].endswith("metrics.jsonl")
+
+
+def test_profile_flag_is_gone(tmp_path, monkeypatch, capsys):
+    """``-profile`` switched the fencing phase timers on; they went
+    (ISSUE 30), so the flag now reads as any flag the parser does not
+    know: the run is the plain run, no record carries phase_ms and no
+    phase table or throughput line is printed at exit."""
+    from cup2d_tpu.__main__ import main
+
+    monkeypatch.delenv("CUP2D_FAULTS", raising=False)
+    monkeypatch.delenv("CUP2D_TRACE", raising=False)
+    out = tmp_path / "run"
+    rc = main(_CLI_BOX + ["-level", "2", "-profile",
+                          "-output", str(out), "-maxSteps", "2"])
+    assert rc == 0
+    ms = [r for r in load_metrics(str(out / "metrics.jsonl"))
+          if r.get("event") == "metrics"]
+    assert [r["step"] for r in ms] == [1, 2]
+    assert all("phase_ms" not in r for r in ms)
+    err = capsys.readouterr().err
+    assert "ms/call" not in err and "cells_steps_per_sec" not in err
+    assert "done at t=" in err

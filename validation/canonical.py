@@ -1,6 +1,6 @@
 """The ONE definition of the canonical two-fish case for validation
-tooling (run.sh, /root/reference/run.sh:1-22). device_time, trace_ops
-and golden all measure/pin THIS case — a flag drifting in one copy
+tooling (run.sh, /root/reference/run.sh:1-22). golden, scale_proof,
+init_compiles and chip_smoke.py all measure/pin THIS case — a flag drifting in one copy
 would silently make them describe different physics (ADVICE r3)."""
 
 from __future__ import annotations

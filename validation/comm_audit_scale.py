@@ -8,7 +8,7 @@ synthetic vortex forest (hundreds of blocks per shard), adding 64
 devices:
 
   phase A (TPU or CPU, once):  grow the synthetic forest to >= 1e4
-      blocks exactly like validation/device_time.py, then checkpoint it
+      blocks exactly like validation/scale_proof.py, then checkpoint it
       (topology + fields) to --state DIR.
   phase B (CPU, per device count / exchange mode): restore the
       checkpoint into a ShardedAMRSim on an N-virtual-device mesh and

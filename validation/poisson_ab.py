@@ -1,8 +1,8 @@
 """Forest Poisson solve-path A/B: production iters/step per path.
 
 Builds a near-uniform obstacle-free forest at a chosen block count,
-seeds a multi-scale velocity field (the bench_state recipe on the
-forest), and measures ONE production solve (cold deltap — the
+seeds a multi-scale velocity field (smooth modes plus seeded noise on
+the forest), and measures ONE production solve (cold deltap — the
 worst-case production RHS) plus a short warm train under each solve
 path:
 
@@ -47,7 +47,7 @@ import numpy as np
 
 
 def _seed_multiscale(sim):
-    """Seed the bench's multi-scale divergence-bearing field, each
+    """Seed a multi-scale divergence-bearing field, each
     active block sampled analytically at its OWN resolution."""
     import jax.numpy as jnp
 
@@ -77,7 +77,7 @@ def build_forest_sim(bpd: int = 8, level_start: int = 2,
                      tol_rel: float = 1e-2):
     """Obstacle-free AMRSim on the uniform level_start grid
     (bpd*2^level_start squared blocks), regridding disabled, seeded
-    with the bench's multi-scale divergence-bearing field."""
+    with a multi-scale divergence-bearing field."""
     from cup2d_tpu.amr import AMRSim
     from cup2d_tpu.config import SimConfig
 

@@ -96,8 +96,6 @@ def main():
 
     from cup2d_tpu.cache import enable_compilation_cache
     enable_compilation_cache()
-    from cup2d_tpu.profiling import PhaseTimers
-
     from validation.canonical import build_canonical_sim
 
     ctol = args.ctol if args.ctol is not None else args.rtol / 5.0
@@ -109,7 +107,6 @@ def main():
     else:
         sim = build_canonical_sim(levelmax=args.levelmax, rtol=args.rtol,
                                   ctol=ctol)
-    sim.timers = PhaseTimers()
     t0 = time.perf_counter()
     sim.initialize()
     print(json.dumps({"phase": "init", "wall_s": round(
@@ -158,7 +155,6 @@ def main():
             float(np.median(table_walls)), 2) if table_walls else None,
         "tables_s_max": round(
             float(np.max(table_walls)), 2) if table_walls else None,
-        "timers": sim.timers.summary() if sim.timers else None,
     }), flush=True)
 
 
