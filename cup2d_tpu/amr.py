@@ -34,6 +34,7 @@ reproduce previously-seen shapes hit the XLA compile cache.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -298,10 +299,13 @@ class AMRSim(ShapeHostMixin):
         self._frow_index = None                  # (_finalize_tables)
         self._force_blocks = None
         self._fcap_growths = 0
-        # sticky block-axis padding (see _refresh_impl)
+        # sticky block-axis padding (see _refresh_impl) and, beside it,
+        # the sticky row capacities of every table set (_sticky_caps)
         self._npad_hwm = 128
         self._npad_floor = 128    # reserve_blocks raises this
         self._npad_quiet = 0
+        self._tcap = {}     # set -> its capacities
+        self._tneed = {}    # set -> the live counts of its last build
         self.compute_forces_every = 1   # 0 disables the diagnostics pass
         self.force_log = None           # file-like, CSV rows
         # cumulative regrid activity + shard comm-volume stats for the
@@ -365,6 +369,9 @@ class AMRSim(ShapeHostMixin):
         self._regrid_jit = tracing.named_jit(
             "amr.regrid", jax.jit(
                 self._regrid_apply_impl, donate_argnums=0))
+        # not donated: a caller may still hold the slot arrays it read
+        self._sync_jit = tracing.named_jit(
+            "amr.sync", jax.jit(self._flush_impl))
 
     def reserve_blocks(self, n: int):
         """Pre-size the padded block axis so every jitted executable
@@ -407,6 +414,17 @@ class AMRSim(ShapeHostMixin):
         # reuse; if the forest stays a quarter of the bucket for 10
         # consecutive rebuilds (a genuinely decayed run, not a
         # transient), the bucket steps down one power of two.
+        #
+        # Every OTHER dimension a program of the regrid cycle (step,
+        # tags, regrid, flush) sees is sticky the same way, so that a
+        # run compiles each of them once: the two row counts and the
+        # interpolation width of each halo set and the flux-correction
+        # rows (``_tcap``, _sticky_caps — handed to halo.pad_tables and
+        # flux.build_flux_corr as their ``caps``), the raster windows
+        # and force lists (``_wcap``, ``_fcap``, ``_frcap``), the
+        # regrid's refine/compress rows (_apply_regrid) and the flush
+        # index (``sync_p`` below). The table capacities start over
+        # only when the block bucket itself steps down.
         n_bucket = max(128, 1 << n_real.bit_length())
         if n_bucket >= self._npad_hwm:
             self._npad_hwm = n_bucket
@@ -417,6 +435,7 @@ class AMRSim(ShapeHostMixin):
             if self._npad_quiet >= 10:
                 self._npad_hwm //= 2
                 self._npad_quiet = 0
+                self._tcap.clear()
         else:
             self._npad_quiet = 0
         n_pad = self._npad_hwm
@@ -428,6 +447,11 @@ class AMRSim(ShapeHostMixin):
             np.full(n_pad - n_real, pad_slot, np.int32)])
         self._n_real = n_real
         self._mask = np.arange(n_pad) < n_real
+        # the flush's index (_flush_impl): the same rows, but its pad
+        # rows point past every slot capacity and are DROPPED, so the
+        # flush is one shape per (n_pad, capacity) like everything else
+        # on the block axis and leaves every inactive slot as it was
+        sync_p = np.where(self._mask, order_p, np.iinfo(np.int32).max)
 
         # one dense topology index shared by all 6-8 table builds
         topo = _TopoIndex(f, self._order)
@@ -493,6 +517,7 @@ class AMRSim(ShapeHostMixin):
         self._maskv = jnp.asarray(
             self._mask.reshape(-1, 1, 1, 1).astype(fdt))
         self._order_j = jnp.asarray(order_p)
+        self._sync_j = jnp.asarray(sync_p)
         # cell centers per active block (device, for obstacle kernels)
         bs = f.bs
         ar = np.arange(bs) + 0.5
@@ -655,16 +680,65 @@ class AMRSim(ShapeHostMixin):
     _FAST_SETS = {"vec3": True, "vec1": False, "sca1": False,
                   "sca4t": True, "vec4t": True}
 
+    def _sticky_caps(self, name: str, need: tuple) -> tuple:
+        """The ``caps`` rule this sim hands to halo.pad_tables and
+        flux.build_flux_corr for table set ``name``: one sticky
+        capacity per dimension of ``need`` — (simple rows,
+        interpolation rows, interpolation width K) of a halo set,
+        (rows,) of the flux correction — a high-water mark like the
+        block axis' ``_npad_hwm``, so that a table's shape is the same
+        after every rebuild. A row count above its capacity raises it
+        to the bucket of 1.3 x the need (the rule of ``_fcap`` /
+        ``_frcap``; power-of-two buckets also let the runs of one
+        configuration share executables whatever their seed) and, past
+        the first build, says so: each growth is a new variant of every
+        program that takes the set. K is a stencil's width, not a
+        count that wanders: its mark is rounded up to a multiple of 8
+        and given no headroom — a lab assembly costs 3 ns a (row x K)
+        element on the chip, pad or live (PERF.md section 6, PR 32)."""
+        rows, old = need[:2], self._tcap.get(name, (0,) * len(need))
+        new = tuple(c if 0 < c >= n else _bucket(int(1.3 * n))
+                    for n, c in zip(rows, old)) \
+            + tuple(max(c, -(-k // 8) * 8)
+                    for k, c in zip(need[2:], old[2:]))
+        self._tneed[name] = need
+        if new != old:
+            self._tcap[name] = new
+            if any(old):
+                from .resilience import record_event
+                dims = ("gs", "gg", "k") if len(need) == 3 else ("m",)
+                for d, n, o, c in zip(dims, need, old, new):
+                    if c != o:
+                        record_event(event="table_cap_grow",
+                                     step=int(self.step_count), set=name,
+                                     dim=d, need=n, old=o, new=c)
+        return new
+
+    def _reserve_table_rows(self, factor: int):
+        """Raise the sticky row capacities to the buckets of ``factor``
+        times the rows of the tables as built last (the interpolation
+        width is a stencil's, not a count: left alone); the next
+        _refresh() pads to them. initialize() calls it on the climbed
+        forest."""
+        for name, need in self._tneed.items():
+            cap = self._tcap[name]
+            self._tcap[name] = tuple(
+                max(c, _bucket(factor * n))
+                for n, c in zip(need[:2], cap)) + cap[2:]
+        self._tables_version = -1
+
     # table placement hooks (ShardedAMRSim splits the hot-loop sets
     # into per-device rows + a surface-exchange plan)
     def _finalize_tables(self, raw: dict, n_pad: int, fc=None) -> dict:
         out = {}
         for k, t in raw.items():
+            caps = functools.partial(self._sticky_caps, k)
             if fc is not None and k in self._FAST_SETS:
                 out[k] = make_fast_tables(t, fc[0], fc[1], n_pad,
-                                          corners=self._FAST_SETS[k])
+                                          corners=self._FAST_SETS[k],
+                                          caps=caps)
             else:
-                out[k] = pad_tables(t, n_pad)
+                out[k] = pad_tables(t, n_pad, caps)
         self._frow_index = self._force_row_index(out)
         return jax.device_put(out)
 
@@ -697,13 +771,15 @@ class AMRSim(ShapeHostMixin):
         forces the table form for A/B measurements."""
         if self._pois_mode == "tables":
             t = build_poisson_tables(self.forest, self._order, topo=topo)
-            return jax.device_put(pad_tables(t, n_pad))
+            return jax.device_put(pad_tables(
+                t, n_pad, functools.partial(self._sticky_caps, "pois")))
         return jax.device_put(build_poisson_structured(
             self.forest, self._order, n_pad, topo=topo))
 
     def _finalize_corr(self, topo, n_pad: int):
-        return build_flux_corr(self.forest, self._order, n_pad=n_pad,
-                               topo=topo)
+        return build_flux_corr(
+            self.forest, self._order, n_pad=n_pad, topo=topo,
+            caps=functools.partial(self._sticky_caps, "corr"))
 
     # ------------------------------------------------------------------
     # ordered working state
@@ -760,12 +836,23 @@ class AMRSim(ShapeHostMixin):
         if not self._ord_dirty:
             return
         f = self.forest
-        order = jnp.asarray(self._order)
-        for name, x in self._ord.items():
-            f.fields[name] = f.fields[name].at[order].set(
-                x[:self._n_real])
+        f.fields.update(
+            self._sync_jit(dict(f.fields), self._ord, self._sync_j))
         self._ord_key = (f.version, f.fields.wver)
         self._ord_dirty = False
+
+    @staticmethod
+    def _flush_impl(fields, ordf, sync):
+        """THE flush (sync_fields' program, and the head of the regrid's):
+        every ordered array [n_pad, dim, BS, BS] scattered to its slots
+        through the padded index ``sync`` [n_pad] (_refresh_impl). No
+        shape holds the live block count — a flush sliced to it
+        compiled eleven one-op programs at every regrid, 0.6 s on the
+        chip, each too small for the persistent cache. The pad rows are
+        out of range and dropped: no inactive slot is written."""
+        return {**fields, **{
+            name: fields[name].at[sync].set(x, mode="drop")
+            for name, x in ordf.items()}}
 
     def fields(self) -> dict:
         """Slot-layout fields, guaranteed current.
@@ -1950,6 +2037,13 @@ class AMRSim(ShapeHostMixin):
             self._write_chi(obs)
             if not self.adapt():
                 break
+        # the climbed forest is the smallest of the run: the wake behind
+        # the bodies brings 1.2-1.9 x its table rows within 1000 steps
+        # of the reference case (PERF.md section 6, PR 32). Room for
+        # twice the rows is made HERE, in set-up, so that no step a few
+        # hundred into the run grows a capacity and compiles for it
+        self._refresh()
+        self._reserve_table_rows(2)
         obs = self._rasterize()
         self._write_chi(obs)
         self._sync_shape_scalars(obs)
@@ -2369,9 +2463,11 @@ class AMRSim(ShapeHostMixin):
         """
         f = self.forest
         # the prolongation/restriction gathers read the slot-layout
-        # fields — flush the ordered working state first (pre-regrid
-        # order is still valid here)
-        self.sync_fields()
+        # fields — the dispatch flushes the ordered working state into
+        # them first (the pre-regrid order and flush index are still
+        # valid there). Clean state is flushed too (its own slots'
+        # values, written back): one executable, not one a case
+        ordf = self._ord if self._ord_dirty else self._ordered_state()
         ordpos = {int(s): k for k, s in enumerate(self._order)}
         R, G = len(refine_keys), len(groups)
         # one executable per pad bucket: padding refine/compress rows to
@@ -2416,21 +2512,26 @@ class AMRSim(ShapeHostMixin):
         parent_slots[G:] = dead
 
         f.fields.update(self._regrid_jit(
-            dict(f.fields), self._order_j,
+            dict(f.fields), ordf, self._sync_j, self._order_j,
             jnp.asarray(parents), jnp.asarray(child_slots.reshape(-1)),
             jnp.asarray(sib_slots), jnp.asarray(parent_slots),
             self._tables["vec1t"], self._tables["sca1t"]))
+        # flushed; _ord / _ord_key describe the old topology and the
+        # next _ordered_state() gathers anew
+        self._ord_dirty = False
         self._n_refined += R
         self._n_coarsened += G
 
-    def _regrid_apply_impl(self, fields, order, parents, child_slots,
-                           sib_slots, parent_slots, tv, ts):
-        """Device half of _apply_regrid: per field, Taylor prolongation
+    def _regrid_apply_impl(self, fields, ordf, sync, order, parents,
+                           child_slots, sib_slots, parent_slots, tv, ts):
+        """Device half of _apply_regrid: the flush of the ordered
+        working state (_flush_impl), then per field, Taylor prolongation
         of the refined parents (2nd-order, tensorial g=1 labs) scattered
         to the 4 child slots, then 4->1 averaging restriction of the
         compression groups scattered to the parent slot. Pad rows source
         a pad lab row / the dead slot — finite garbage, never read
         unmasked."""
+        fields = self._flush_impl(fields, ordf, sync)
         out = {}
         for name, field in fields.items():
             t = tv if field.shape[1] == 2 else ts

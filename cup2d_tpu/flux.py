@@ -42,7 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .forest import Forest
-from .halo import Expr, HaloTables, _TopoIndex, build_tables
+from .halo import Expr, HaloTables, _TopoIndex, build_tables, \
+    table_buckets
 
 # face order = the reference's BlockCase d[0..3] (main.cpp:513-517)
 _FACES = ((-1, 0), (1, 0), (0, -1), (0, 1))  # Xm, Xp, Ym, Yp
@@ -481,9 +482,9 @@ class FluxCorrTables(NamedTuple):
     D[fidx2]), where D is a [n_active * 4 * BS, dim] face-deposit array.
     One row per coarse edge cell whose face abuts a finer neighbor (the
     reference's fillcase0+fillcase1 combination). Rows are padded to
-    power-of-two buckets (``valid`` = 0, dest pointing at a dead pad-row
-    cell) so the jitted step's argument shapes survive regrids — same
-    rationale as halo.pad_tables."""
+    the caller's capacity (``valid`` = 0, dest pointing at a dead
+    pad-row cell) so the jitted step's argument shapes survive regrids —
+    same rationale, and the same ``caps`` argument, as halo.pad_tables."""
 
     dest: jnp.ndarray    # [M] into ordered cell layout [n_active*BS*BS]
     cidx: jnp.ndarray    # [M] coarse block's own face deposit
@@ -500,11 +501,14 @@ jax.tree_util.register_pytree_node(
 
 
 def build_flux_corr(forest: Forest, order: np.ndarray,
-                    n_pad: int = 0, topo=None) -> FluxCorrTables:
+                    n_pad: int = 0, topo=None,
+                    caps=table_buckets) -> FluxCorrTables:
     """Topology-only; shared by every corrected kernel (the per-kernel
     physics lives in the deposit arrays). ``n_pad`` > len(order) enables
     shape-stable row padding (pad rows target the first pad block's
-    cell 0, which the caller's mask discards). Rows are built vectorized
+    cell 0, which the caller's mask discards) to ``caps((rows,))[0]``,
+    the caller's rule as in halo.pad_tables (AMRSim: sticky; default:
+    the instantaneous power-of-two bucket). Rows are built vectorized
     per face over the dense topology index (the per-block Python loop
     was O(blocks*faces*BS) host time per regrid); index math mirrors
     `_fine_subface`, asserted equal by tests/test_flux.py."""
@@ -550,7 +554,8 @@ def build_flux_corr(forest: Forest, order: np.ndarray,
     m_real = len(dest)
     if n_pad:
         assert n_pad > n_real
-        m = max(64, 1 << max(0, (m_real - 1)).bit_length())
+        m, = caps((m_real,))
+        assert m >= m_real
         dead = n_real * bs * bs
         dest = np.concatenate([dest, np.full(m - m_real, dead, np.int64)])
         cidx = np.concatenate([cidx, np.zeros(m - m_real, np.int64)])

@@ -529,6 +529,15 @@ def _bucket(n: int, lo: int = 64) -> int:
     return max(lo, 1 << max(0, (n - 1)).bit_length())
 
 
+def table_buckets(need):
+    """The capacities of a table whose caller holds none (pad_tables,
+    flux.build_flux_corr): the instantaneous power-of-two buckets of
+    the live ``need`` — (simple rows, interpolation rows, interpolation
+    width K) of a halo set, (rows,) of the flux correction."""
+    return tuple(_bucket(n) for n in need[:2]) \
+        + tuple(_bucket(k, lo=4) for k in need[2:])
+
+
 # ---------------------------------------------------------------------------
 # Same-level face-copy fast path (round 5)
 #
@@ -665,10 +674,12 @@ def filter_face_rows(t: HaloTables, mask: np.ndarray,
 
 
 def make_fast_tables(t: HaloTables, nb: np.ndarray, mask: np.ndarray,
-                     n_pad: int, corners: bool) -> FastHalo:
-    """Filter covered rows, pad, and bundle with the face-copy arrays.
+                     n_pad: int, corners: bool,
+                     caps=table_buckets) -> FastHalo:
+    """Filter covered rows, pad (``caps`` as in pad_tables: it sees the
+    FILTERED row counts), and bundle with the face-copy arrays.
     Arrays stay numpy so the caller's single device_put ships them."""
-    ft = pad_tables(filter_face_rows(t, mask, corners), n_pad)
+    ft = pad_tables(filter_face_rows(t, mask, corners), n_pad, caps)
     return FastHalo(t=ft, nb=nb, mask=mask, corners=corners)
 
 
@@ -694,15 +705,24 @@ def _fast_paint(x: jnp.ndarray, labs: jnp.ndarray, fh: FastHalo,
                           fh.corners)
 
 
-def pad_tables(t: HaloTables, n_pad: int) -> HaloTables:
+def pad_tables(t: HaloTables, n_pad: int,
+               caps=table_buckets) -> HaloTables:
     """Pad a table set so its array shapes are stable across regrids:
-    the block axis to ``n_pad`` (> the real block count), row counts and
-    the interpolation width K to power-of-two buckets. XLA keys compiled
-    executables on argument shapes — without this every regrid would
-    retrace the jitted step (the exact r1 cost block-bucketing was meant
-    to remove, VERDICT weak #6). Pad rows write zeros into the first
-    PAD-row lab cell (index n_real*L*L — valid precisely because
-    n_pad > n_real) and gather field cell 0 with zero weight."""
+    the block axis to ``n_pad`` (> the real block count), the two row
+    counts and the interpolation width K to ``caps(need)`` — a rule the
+    CALLER holds, from the live (simple rows, interpolation rows, K) to
+    the capacities to pad to, none below its need. AMRSim passes its
+    sticky high-water marks (amr.AMRSim._sticky_caps: one capacity a
+    set and dimension, kept beside the block axis' own ``_npad_hwm``),
+    so that no program of a run sees a live count in a shape; a caller
+    that holds nothing gets ``table_buckets``, the instantaneous
+    power-of-two buckets. XLA keys compiled executables on argument
+    shapes — without this every regrid would retrace the jitted step
+    (the exact r1 cost block-bucketing was meant to remove, VERDICT
+    weak #6). Pad rows write zeros into the first PAD-row lab cell
+    (index n_real*L*L — valid precisely because n_pad > n_real) and
+    gather field cell 0 with zero weight, so the padded program
+    computes what the unpadded one does whatever the capacity."""
     n_real = t.n_active
     assert n_pad > n_real
     dead = n_real * t.L * t.L
@@ -711,9 +731,9 @@ def pad_tables(t: HaloTables, n_pad: int) -> HaloTables:
         return np.pad(np.asarray(a), (0, n - a.shape[0]),
                       constant_values=fill)
 
-    gs = _bucket(t.dest_s.shape[0])
-    gg = _bucket(t.dest.shape[0])
-    k = max(4, 1 << max(0, (t.idx.shape[1] - 1)).bit_length())
+    need = (t.dest_s.shape[0], t.dest.shape[0], t.idx.shape[1])
+    gs, gg, k = caps(need)
+    assert gs >= need[0] and gg >= need[1] and k >= need[2], need
     sign = np.zeros((gs, t.dim), np.asarray(t.sign).dtype)
     sign[:t.sign.shape[0]] = t.sign
     idx = np.zeros((gg, k), np.int32)
