@@ -52,8 +52,8 @@ Catalog:
 
 ``turb2d``
     Seeded decaying 2D turbulence: random-phase vorticity spectrum
-    E(k) ~ k / (1 + (k/k0)^4), velocity synthesized host-side from
-    the streamfunction by CENTERED differences (discretely
+    E(k) ~ k / (1 + (k/k0)^4), phases drawn host-side, velocity
+    synthesized from the streamfunction by CENTERED differences (discretely
     divergence-free by construction — Dx Dy psi == Dy Dx psi).
     Deterministic per seed; fleet members get seed + slot so a
     member-batched fleet serves an ensemble.
@@ -77,7 +77,7 @@ class CaseSpec:
     driver with ``sim.case`` set; ``default_level`` is the validation
     resolution (CLI ``-level`` overrides); ``fleet_ok`` marks cases
     whose obstacle-free state can ride the fleet slot pool;
-    ``initial_vel(grid, m) -> [2, Ny, Nx]`` (numpy) is member ``m``'s
+    ``initial_vel(grid, m) -> [2, Ny, Nx]`` (array) is member ``m``'s
     starting velocity at the case's default parameters (None: fluid at
     rest) — what ``build`` installs and ``initial_states`` serves."""
 
@@ -129,13 +129,12 @@ def _periodic_sim(cfg: SimConfig, lvl: int, mesh, members: int):
 
 def _install_vel(sim, members: int, vel_fn):
     """Overwrite the zero-state velocity with ``vel_fn(grid, m) ->
-    [2, Ny, Nx]`` (numpy), stacked over fleet slots."""
+    [2, Ny, Nx]`` (numpy or device array), stacked over fleet slots."""
     import jax.numpy as jnp
-    import numpy as np
 
     g = sim.grid
     if members > 0:
-        v = np.stack([vel_fn(g, m) for m in range(members)])
+        v = jnp.stack([jnp.asarray(vel_fn(g, m)) for m in range(members)])
     else:
         v = vel_fn(g, 0)
     sim.state = sim.state._replace(
@@ -210,9 +209,9 @@ def build_turb2d(level: Optional[int] = None, nu: float = 1e-4,
                  cfl: float = 0.4):
     """Seeded decaying 2D turbulence on the doubly-periodic unit box.
 
-    IC synthesis is host-side numpy (deterministic per seed, no
+    The phases are host-side numpy (deterministic per seed, no
     device RNG): a random-phase streamfunction with energy spectrum
-    E(k) ~ k / (1 + (k/k0)^4), inverse-FFT'd to the grid, then
+    E(k) ~ k / (1 + (k/k0)^4), inverse-FFT'd to the grid and
     differenced CENTRALLY to velocity (u = D_y psi, v = -D_x psi) so
     the discrete centered divergence vanishes identically, and scaled
     to rms speed ``urms``. Fleet members draw seed + slot index — one
@@ -231,29 +230,44 @@ def build_turb2d(level: Optional[int] = None, nu: float = 1e-4,
 def turb2d_vel(grid, m: int = 0, seed: int = 0, k0: float = 6.0,
                urms: float = 1.0):
     """Member ``m`` draws seed + m, so members (and served sessions)
-    are different flows."""
+    are different flows. The phases are drawn on the host (numpy, one
+    stream per seed, as ever); amplitudes, differences and the two
+    inverse transforms run where the grid lives, in the grid's
+    precision — the host's complex128 ``ifft2`` and its temporaries
+    were 33 s of a run's set-up at 8192^2 (ISSUE 34)."""
+    import jax
+    import jax.numpy as jnp
     import numpy as np
-    ny, nx, h = grid.ny, grid.nx, grid.h
     rng = np.random.default_rng(seed + m)
-    kx = np.fft.fftfreq(nx, d=1.0 / nx)
-    ky = np.fft.fftfreq(ny, d=1.0 / ny)
-    KX, KY = np.meshgrid(kx, ky, indexing="xy")
-    kk = np.sqrt(KX ** 2 + KY ** 2)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        # E(k) ~ k/(1+(k/k0)^4); psi-hat amplitude
-        # ~ sqrt(E(k)/k)/k (vorticity = k^2 psi-hat)
-        amp = np.where(
-            kk > 0,
-            np.sqrt(kk / (1.0 + (kk / k0) ** 4)) / (kk ** 1.5),
-            0.0)
-    phase = np.exp(2j * np.pi * rng.random((ny, nx)))
-    psi = np.fft.ifft2(amp * phase).real
-    # centered differences on the wrap: discretely div-free
-    u = (np.roll(psi, -1, axis=0) - np.roll(psi, 1, axis=0)) / (2.0 * h)
-    v = -(np.roll(psi, -1, axis=1) - np.roll(psi, 1, axis=1)) / (2.0 * h)
-    rms = np.sqrt(np.mean(u ** 2 + v ** 2))
-    s = urms / rms if rms > 0 else 1.0
-    return np.stack([u * s, v * s])
+    theta = jnp.asarray(2.0 * np.pi * rng.random((grid.ny, grid.nx)),
+                        grid.dtype)
+    return jax.jit(_turb2d_synth)(theta, grid.h, k0, urms)
+
+
+def _turb2d_synth(theta, h, k0, urms):
+    """[2, Ny, Nx] from the phases: psi-hat = amp * exp(i theta), u =
+    D_y Re(psi), v = -D_x Re(psi) with D the centred difference on the
+    wrap (discretely div-free: Dx Dy psi == Dy Dx psi), scaled to rms
+    speed ``urms``. The differences are taken mode by mode — D turns
+    mode k into i sin(2 pi k / n) / h times itself — because
+    differencing psi on the grid costs 1/h of its digits, which f32
+    does not have at 8192^2."""
+    import jax.numpy as jnp
+    ny, nx = theta.shape
+    ky = jnp.fft.fftfreq(ny, d=1.0 / ny).astype(theta.dtype)[:, None]
+    kx = jnp.fft.fftfreq(nx, d=1.0 / nx).astype(theta.dtype)[None, :]
+    k2 = kx * kx + ky * ky
+    # E(k) ~ k/(1+(k/k0)^4); psi-hat amplitude ~ sqrt(E(k)/k)/k
+    # (vorticity = k^2 psi-hat) = 1/(k sqrt(1+(k/k0)^4)), 0 at k = 0
+    amp = jnp.where(k2 > 0, 1.0 / jnp.sqrt(
+        jnp.where(k2 > 0, k2, 1.0) * (1.0 + (k2 / (k0 * k0)) ** 2)), 0.0)
+    psi_hat = amp * jnp.exp(1j * theta)
+    u = jnp.fft.ifft2(
+        1j * jnp.sin(2.0 * jnp.pi * ky / ny) / h * psi_hat).real
+    v = -jnp.fft.ifft2(
+        1j * jnp.sin(2.0 * jnp.pi * kx / nx) / h * psi_hat).real
+    rms = jnp.sqrt(jnp.mean(u * u + v * v))
+    return jnp.stack([u, v]) * jnp.where(rms > 0, urms / rms, 1.0)
 
 
 def build_cavity(level: Optional[int] = None, re: float = 100.0,

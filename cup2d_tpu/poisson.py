@@ -127,6 +127,20 @@ class MultigridPreconditioner:
         # matching edge signs come in as 0 through edge_signs, so the
         # Jacobi diagonal keeps the interior -4 on periodic rows.
         self.periodic = (bool(periodic[0]), bool(periodic[1]))
+        # all four faces wrap (ISSUE 34): the Jacobi diagonal is -4 on
+        # every row of every level, so a constant goes through sweeps,
+        # restriction and prolongation as a constant and M(1) comes out
+        # EXACTLY constant (-322 at 64^2, x4 a level: ~ -1e6 at 8192^2)
+        # — in the operator's null space, where no residual sees it. A
+        # tol-0 start-up solve that iterates on past the f32 floor then
+        # lets the mean of x drift without bound (1e12 seen at 128^2)
+        # until lap(x) is rounding noise: 12 of 120 start-up solves at
+        # 128^2 came back with a residual of 1e-4..4e-2. With a wall
+        # anywhere the edge rows break the constant and Krylov damps
+        # it. So here each COARSE level takes its residual mean-free
+        # (_range): what is left is the finest level's own sweeps,
+        # M(1) = -(nu1 + nu2) omega / 4.
+        self._const_null = all(self.periodic)
         if any(self.periodic):
             if edge_signs is None:
                 raise ValueError(
@@ -345,10 +359,19 @@ class MultigridPreconditioner:
         self._note()
         return self._fcycle(r.astype(self.dtype), 0).astype(self.out_dtype)
 
+    def _range(self, r, lvl):
+        """``r`` as the cycle's level ``lvl`` takes it: mean-free on the
+        coarse levels of an all-periodic hierarchy (see __init__),
+        untouched everywhere else."""
+        if self._const_null and lvl >= 1:
+            with tracing.scope("mg_transfer"):
+                return r - jnp.mean(r, axis=(-2, -1), keepdims=True)
+        return r
+
     def _fcycle(self, r, lvl):
         if lvl == len(self.shapes) - 1:
-            return self._smooth(jnp.zeros_like(r), r, lvl, 24,
-                                from_zero=True)
+            return self._smooth(jnp.zeros_like(r), self._range(r, lvl),
+                                lvl, 24, from_zero=True)
         # same full-weighting restriction (+x4 undivided scale) as the
         # V-cycle below
         with tracing.scope("mg_transfer"):
@@ -360,6 +383,7 @@ class MultigridPreconditioner:
         return self._cycle(r, lvl, e0=e0)
 
     def _cycle(self, r, lvl, e0=None):
+        r = self._range(r, lvl)
         if lvl == len(self.shapes) - 1:
             # coarsest: enough Jacobi sweeps to wash out the local modes;
             # the global constant mode is BiCGSTAB's job, not M's

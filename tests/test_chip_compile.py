@@ -176,6 +176,13 @@ def test_jacobi_halo_sweep_compiles_on_4_chip_mesh(mesh4):
 def _cavity_step_text(one_chip, **grid_kw):
     """The benchmark cell's step (8192^2 f32 cavity, bicgstab +
     multigrid), compiled for the described v5e: the executable's text."""
+    grid, compiled = _step_compiled(one_chip, cavity_table(1.0), **grid_kw)
+    return grid, compiled.as_text()
+
+
+def _step_compiled(one_chip, bc, **grid_kw):
+    """One uniform 8192^2 f32 production step under table ``bc``,
+    compiled for the described v5e."""
     from cup2d_tpu.config import SimConfig
     from cup2d_tpu.uniform import FlowState, UniformGrid
 
@@ -183,7 +190,7 @@ def _cavity_step_text(one_chip, **grid_kw):
         cfg = SimConfig(bpdx=1, bpdy=1, level_max=1, level_start=0,
                         extent=1.0, dtype="float32", nu=1e-4, cfl=0.4,
                         poisson_tol=1e-4, poisson_tol_rel=1e-3)
-        grid = UniformGrid(cfg, 10, bc=cavity_table(1.0), **grid_kw)
+        grid = UniformGrid(cfg, 10, bc=bc, **grid_kw)
         assert (grid.ny, grid.nx) == (N, N)
 
         def field(*lead):
@@ -197,10 +204,10 @@ def _cavity_step_text(one_chip, **grid_kw):
             return grid.step(state, dt, exact_poisson=False,
                              obstacle_terms=False)
 
-        text = jax.jit(step, donate_argnums=(0,)).lower(
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(
             state, jax.ShapeDtypeStruct((), F32, sharding=one_chip)
-        ).compile().as_text()
-    return grid, text
+        ).compile()
+    return grid, compiled
 
 
 def test_cavity_step_with_fused_legs_compiles_on_v5e(one_chip,
@@ -252,3 +259,32 @@ def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
             "poisson_solve", "krylov", "mg_cycle", "mg_smooth",
             "mg_transfer", "mg_coarse", "project_correct",
             "diag"} <= seen, seen
+
+
+def test_periodic_step_compiles_on_v5e(one_chip, monkeypatch):
+    """The doubly-periodic cell's step (``turb2d-8192.solo``, ISSUE 34:
+    8192^2 f32, all four faces wrap), compiled for the described v5e
+    with ``_on_accel`` held true: the hierarchy still picks the XLA
+    legs (no strip form on a wrap), so no Mosaic call is in the
+    executable; the multigrid scopes are, with the coarse levels' mean
+    removal among the transfers; and arguments plus temporaries leave
+    room on a 16 GB chip for the snapshot ring beside them."""
+    import re
+
+    from cup2d_tpu.cases import periodic_table
+
+    monkeypatch.setattr(pk, "_on_accel", lambda: True)
+    grid, compiled = _step_compiled(one_chip, periodic_table())
+    assert (grid.smoother_tier, grid.mg.fused_levels) == ("xla", 0)
+    assert grid.mg._const_null and len(grid.mg.shapes) == 11
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    seen = {part for n in names for part in n.split("/")}
+    assert {"advect", "substage0", "substage1", "poisson_rhs",
+            "poisson_solve", "krylov", "mg_cycle", "mg_smooth",
+            "mg_transfer", "mg_coarse", "project_correct",
+            "diag"} <= seen, seen
+    assert any("mg_transfer" in n and "reduce" in n for n in names)
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10e9

@@ -185,11 +185,14 @@ def test_cell_is_in_the_manifest():
         manifest = json.load(f)
     cell = _load("workloads", CELL)
     config = _load("configs", cell["config"])
-    assert manifest["configs"][-1] == {
+    def entry(kind, name):
+        return next(e for e in manifest[kind] if e["name"] == name)
+
+    assert entry("configs", config["name"]) == {
         "name": config["name"], "source": config["source"],
         "file": f"benchmark/configs/{config['name']}.json",
         "reduced": [], "why": config["why"]}
-    assert manifest["workloads"][-1] == {
+    assert entry("workloads", CELL) == {
         "name": CELL, "config": config["name"], "traffic": "wake",
         "chips": 1, "why": cell["why"]}
     named = {m["name"]: m
