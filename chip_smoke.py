@@ -23,7 +23,8 @@ speed claim — the benchmark owns those.
 
 Phases (default run, one chip):
   0  platform: versions, device, cache directory, native helper
-  1  uniform periodic: tgv_periodic default solver, then CUP2D_POIS=fftd
+  1  uniform periodic: tgv_periodic with the solver its table selects
+     (the direct solve, PR 35), then the same asked for by CUP2D_POIS=fftd
   2  uniform walls: cavity
   3  canonical adaptive: the reference two-fish case, levelMax 8
   4  fleet server: turb2d pool, 4 slots serving 6 sessions
@@ -348,10 +349,13 @@ def phase1(sz: dict) -> None:
         decay_model = 1.0 + dt_mean / (8.0 * nu)
         checks["ke_follows_analytic_decay"] = ke_err < TGV_KE_BAR
         checks["ke_decay_rate"] = abs(decay_ratio / decay_model - 1.0) < 0.1
-        if env:
-            checks["fftd_one_application"] = all(
-                r["poisson_mode"] == "fftd" and r["poisson_iters"] == 1
-                for r in run.records)
+        # the table selects the direct solve (1a) and the latch asks
+        # for it (1b): one application a step either way
+        checks["fftd_one_application"] = all(
+            r["poisson_mode"] == "fftd" and r["poisson_iters"] == 1
+            and r["precond_cycles"] == 0 for r in run.records)
+        checks["fftd_chosen_by"] = run.sim.grid.fftd_by == (
+            "env" if env else "table")
         run.finish(checks, grid=run.grid(), ke_rel_err_max=ke_err,
                    ke_decay_over_analytic=decay_ratio,
                    ke_decay_over_analytic_expected=decay_model)

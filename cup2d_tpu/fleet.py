@@ -86,7 +86,7 @@ from .ops.stencil import (
     heun_substage,
     laplacian5_neumann,
 )
-from .poisson import bicgstab, fft_diag_solve, mg_solve, project_correct
+from .poisson import bicgstab, mg_solve, project_correct
 from .uniform import FlowState, UniformGrid, pad_vector, taylor_green_state
 
 
@@ -317,13 +317,8 @@ class FleetSim:
         bit-tight)."""
         g = self.grid
         cfg = self.cfg
-        if g.solver_mode == "fftd":
-            return fft_diag_solve(
-                g.laplacian, rhs, g._fft_plan,
-                tol=0.0 if exact else cfg.poisson_tol,
-                tol_rel=0.0 if exact else cfg.poisson_tol_rel,
-                member_axis=True,
-            )
+        if g.runs_direct(exact):
+            return g.direct_solve(rhs, exact, member_axis=True)
         if g.solver_mode == "fas" and not exact:
             return mg_solve(
                 g.laplacian, rhs, g.mg,
@@ -516,7 +511,7 @@ class FleetSim:
         dt_dev = jnp.asarray(dt, g.dtype)
         if dt_dev.ndim == 0:
             dt_dev = jnp.full((self.members,), dt_dev, g.dtype)
-        exact = self.step_count < 10 or self._force_exact
+        exact = g.exact_request(self.step_count < 10, self._force_exact)
         self.state, diag = self._step(self.state, dt_dev, self._active,
                                       exact_poisson=exact)
         diag = dict(diag)
@@ -617,7 +612,7 @@ class FleetSim:
             dt = float(self._member_dt(st.vel))
         st, diag = self._member_step(
             st, jnp.asarray(dt, self.grid.dtype),
-            exact_poisson=bool(exact),
+            exact_poisson=exact,
             obstacle_terms=bool(self.shaped))
         self.set_member_state(m, st)
         diag = dict(diag)

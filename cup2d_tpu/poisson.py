@@ -1017,9 +1017,9 @@ class FFTDiagPlan:
     sequential along their axes — there is no shard_map form, and the
     mesh's x-split always shards one of the two (periodic x: the
     transform axis; periodic y only: the scan axis).
-    ``UniformGrid.attach_mesh`` refuses the fftd latch outright (see
-    also parallel/shard_halo.py); sharded periodic cases run under
-    bicgstab/fas, whose wrap stencils GSPMD partitions correctly.
+    ``UniformGrid.attach_mesh`` refuses the fftd latch outright and drops
+    a plan the table selected (parallel/shard_halo.py); sharded periodic
+    cases run under bicgstab/fas, whose wrap stencils GSPMD partitions.
     """
 
     def __init__(self, ny: int, nx: int, dtype, px: bool, py: bool,

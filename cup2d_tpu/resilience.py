@@ -1049,12 +1049,18 @@ class StepGuard:
                         # preconditioner branch
                         sim._coarse_on, sim._last_iters = rtrig
                         sim._last_iters_dev = None
-                    if rexact:
+                    # a start-up step is exact by its own count:
+                    # forcing it too would name the ladder's Krylov
+                    # backstop where the direct solve ran
+                    # (UniformGrid.exact_request) and break the
+                    # bit-exact replay
+                    forced = rexact and sim.step_count >= 10
+                    if forced:
                         sim._force_exact = True
                     try:
                         sim.step_once(dt=rdt)
                     finally:
-                        if rexact:
+                        if forced:
                             sim._force_exact = False
                     if sim.time == t0:
                         # async driver: settle the clock from the
@@ -1541,7 +1547,8 @@ class FleetStepGuard(StepGuard):
                         self.faults.apply_pre_step(sim, step=step0)
                         if self.faults is not None else ())
                     diag = sim.member_step_once(
-                        m, dt=retry_dt, exact=(exact or step0 < 10))
+                        m, dt=retry_dt,
+                        exact=sim.grid.exact_request(step0 < 10, exact))
                     mv = _host_scalars(diag, _PULL_KEYS)
                     v2 = self._one_member_verdict(m, mv, step0)
                     if v2.ok:

@@ -418,7 +418,8 @@ class Simulation(ShapeHostMixin):
                     dt = self._next_dt
                 else:
                     dt = float(self._dt(self.state.vel))
-            exact = self.step_count < 10 or self._force_exact
+            exact = g.exact_request(self.step_count < 10,
+                                    self._force_exact)
             dt_dev = jnp.asarray(dt, g.dtype)
             if self.async_diag:
                 self.state, diag = self._flow_step_empty(
@@ -472,7 +473,7 @@ class Simulation(ShapeHostMixin):
         prescribed = jnp.asarray(
             [[s.u, s.v, s.omega] for s in self.shapes], dtype=g.dtype
         ) if self.shapes else jnp.zeros((0, 3), g.dtype)
-        exact = self.step_count < 10 or self._force_exact
+        exact = g.exact_request(self.step_count < 10, self._force_exact)
         self.state, uvw, diag = self._flow_step(
             self.state, obs, prescribed,
             jnp.asarray(dt, g.dtype), exact_poisson=exact)

@@ -229,6 +229,9 @@ class UniformGrid:
                             else "fas" if pois in ("fas", "fas-f")
                             else "bicgstab")
         self.fas_fmg = pois == "fas-f"
+        # who chose a direct solve: "env" (CUP2D_POIS=fftd) or "table"
+        # (the boundary table below); None under an iterative mode
+        self.fftd_by = "env" if pois == "fftd" else None
         self.level = lvl
         self.nx = cfg.bpdx * cfg.bs << lvl
         self.ny = cfg.bpdy * cfg.bs << lvl
@@ -250,7 +253,21 @@ class UniformGrid:
             # periodic axis flags (ISSUE 20): wrap shifts in the
             # operator/divergence/gradient stencils
             self._paxes = periodic_axes(self.bc)
-        # FFT-diagonalized direct solve (CUP2D_POIS=fftd): the plan's
+        # the table picks the solver (ISSUE 35): a box that wraps on
+        # BOTH axes diagonalizes completely, and one transform pair
+        # with a spectral divide replaces the Krylov train (the chip:
+        # turb2d-8192.solo against the cavity, PERF.md). An explicit
+        # CUP2D_POIS wins; one periodic axis (the tridiagonal form)
+        # and every wall table keep bicgstab+mg — no cell has timed
+        # them; spatial axes that will be sharded (spmd_safe) have no
+        # form of the transform (see attach_mesh); and the chip's
+        # compiler refuses a float64 transform ("Unexpected operand
+        # type for FFT: c128", PERF.md §6 PR 35), so f64 — the CPU
+        # validation precision — keeps the solver that runs on both.
+        if (pois == "" and self._paxes == (True, True) and not spmd_safe
+                and self.dtype == jnp.float32):
+            self.solver_mode, self.fftd_by = "fftd", "table"
+        # FFT-diagonalized direct solve: the plan's
         # transforms/eigenvalues/tridiagonal elimination coefficients
         # are host-precomputed once per grid. Needs >= 1 periodic
         # direction — a wall-only box has nothing to diagonalize.
@@ -394,6 +411,22 @@ class UniformGrid:
     def precond(self, r: jnp.ndarray) -> jnp.ndarray:
         return apply_block_precond(r, self.p_inv, self.cfg.bs)
 
+    def exact_request(self, startup: bool, forced: bool):
+        """The static ``exact_poisson`` value of one step, truthy for
+        the ten tol-0 start-up steps and for the supervision ladder's
+        escalate rung (``_force_exact``). Where the direct solve is
+        the production method the rung must not run again the method
+        whose verdict just failed: it names the Krylov backstop — its
+        own step variant, compiled on its first use only."""
+        if forced and self.solver_mode == "fftd":
+            return "krylov"
+        return bool(startup or forced)
+
+    def runs_direct(self, exact) -> bool:
+        """Whether a solve under request ``exact`` is the plan's one
+        application (every request but the ladder's "krylov")."""
+        return self.solver_mode == "fftd" and exact != "krylov"
+
     @property
     def poisson_mode(self) -> str:
         """The active solve-path latch, for the telemetry stream
@@ -453,6 +486,12 @@ class UniformGrid:
         (shard_halo.overlap_jacobi_sweeps). The default Krylov
         preconditioner cycles stay on the GSPMD form whose
         sharded==single equality is already pinned."""
+        if self.fftd_by == "table":
+            # a direct solve the TABLE picked gives way to the mesh:
+            # the grid runs bicgstab+mg, as a sharded periodic box
+            # always has (self.mg was built for exactly that)
+            self.solver_mode, self.fftd_by = "bicgstab", None
+            self._fft_plan = None
         if self.solver_mode == "fftd":
             # documented refusal (ISSUE 20): the FFT transform and the
             # per-mode tridiagonal scan are whole-array sequential
@@ -481,6 +520,18 @@ class UniformGrid:
                 smoother=("strip" if self._kernel_tier != "xla"
                           else "xla"))
 
+    def direct_solve(self, rhs: jnp.ndarray, exact,
+                     member_axis: bool = False):
+        """The plan's one application, shared with the fleet's
+        member-batched solve; the compile ledger's component note says
+        who chose it (``selected=table`` | ``selected=env``)."""
+        tracing.note_component(f"poisson.fftd[selected={self.fftd_by}]")
+        return fft_diag_solve(
+            self.laplacian, rhs, self._fft_plan,
+            tol=0.0 if exact else self.cfg.poisson_tol,
+            tol_rel=0.0 if exact else self.cfg.poisson_tol_rel,
+            member_axis=member_axis)
+
     def pressure_solve(self, rhs: jnp.ndarray, exact: bool = False):
         """Solve lap(dp) = rhs (undivided). ``exact`` reproduces the
         reference's first-10-steps override — tol 0 with 100 restarts
@@ -489,19 +540,19 @@ class UniformGrid:
         r2 builds' hardcoded f32 relative floor (grid-dependent magic,
         VERDICT r2 #8) exact mode now runs at tol 0 and exits through
         the solver's stall detector at whatever the actual precision
-        floor is, with a tight refresh cadence so the exit is prompt."""
+        floor is, with a tight refresh cadence so the exit is prompt.
+        ``exact="krylov"`` (``exact_request``) is that tol-0 Krylov
+        solve on a grid whose production method is the direct one."""
         cfg = self.cfg
-        if self.solver_mode == "fftd":
-            # direct solve (CUP2D_POIS=fftd): exact to the precision
-            # floor in ONE application — the tol-0 "exact" startup
-            # request needs no escalation path, it simply reports the
-            # floor through the benign stalled bit exactly like
-            # bicgstab's tol-0 stall exit.
-            return fft_diag_solve(
-                self.laplacian, rhs, self._fft_plan,
-                tol=0.0 if exact else cfg.poisson_tol,
-                tol_rel=0.0 if exact else cfg.poisson_tol_rel,
-            )
+        if self.runs_direct(exact):
+            # direct solve: exact to the precision floor in ONE
+            # application — the tol-0 "exact" startup request simply
+            # reports the floor through the benign stalled bit exactly
+            # like bicgstab's tol-0 stall exit. The supervision
+            # ladder's escalate rung asks for "krylov" instead
+            # (exact_request) and falls through to the tol-0 Krylov
+            # solve below.
+            return self.direct_solve(rhs, exact)
         if self.solver_mode == "fas" and not exact:
             # production solves as pure MG cycles (CUP2D_POIS=fas):
             # 1 A-apply + 1 V-cycle per iteration vs Krylov's 2 + 2.
@@ -601,7 +652,7 @@ class UniformGrid:
         cycles). A host-derived count would desynchronize from the
         device iters under the lagged verdict, so this rides the same
         diag pull as the iters themselves."""
-        if self.solver_mode == "fftd":
+        if self.runs_direct(exact):
             # direct solve: no hierarchy cycles at all
             return jnp.zeros_like(res.iters)
         if self.solver_mode == "fas" and not exact:
@@ -749,7 +800,7 @@ class UniformSim:
                 dt = self._next_dt
             else:
                 dt = float(self._dt(self.state.vel))
-        exact = self.step_count < 10 or self._force_exact
+        exact = g.exact_request(self.step_count < 10, self._force_exact)
         dt_dev = jnp.asarray(dt, g.dtype)
         self.state, diag = self._step(
             self.state, dt_dev,

@@ -5,8 +5,8 @@ test run holds:
   Fourier solve against a dense solve of the wrap Laplacian, and the
   harness's seeded start on a box whose faces wrap;
 - the program against that reference on seeded starts at 64^2, under
-  the Krylov default and under ``CUP2D_POIS=fftd``, inside the cell's
-  own limits;
+  the direct solve its table selects (ISSUE 35) and under the Krylov
+  backstop of the supervision ladder, inside the cell's own limits;
 - the cell through ``benchmark/run.py --rehearsal``: ``correct`` true
   with all four numbers compared and the solver, smoother tier and
   table of the run in every record; the six planted faults
@@ -109,12 +109,16 @@ def test_seeded_start_wraps():
     assert 3.5 < div_max[128] / div_max[256] < 4.5, div_max
 
 
-def _program_rows(config, seed, n):
+def _program_rows(config, seed, n, krylov=False):
+    """``krylov``: every step through the ladder's escalate entry
+    (``_force_exact``), which on this table is how the tol-0 Krylov
+    solve is reached since the table selects the direct one."""
     from benchmark import seeded
     from cup2d_tpu import cases
     a = config["case"]["args"]
     sim = cases.build_turb2d(level=config["grid"]["level"], nu=a["nu"],
                              dtype=a["dtype"], cfl=a["cfl"])
+    sim._force_exact = krylov
     sim.state = sim.state._replace(
         vel=seeded.start_velocity(config, seed))
     rows = []
@@ -129,20 +133,23 @@ def _program_rows(config, seed, n):
     return sim, rows
 
 
-@pytest.mark.parametrize("pois", ["", "fftd"])
+@pytest.mark.parametrize("arm", ["selected", "krylov"])
 @pytest.mark.parametrize("seed", SEEDS)
-def test_program_follows_the_periodic_reference(seed, pois, monkeypatch):
+def test_program_follows_the_periodic_reference(seed, arm, monkeypatch):
     """``UniformSim`` on the all-periodic table, 20 steps from a seeded
     start at 64^2 (ten tol-0 start-up steps and ten production ones),
     against ``uniform_periodic.follow``: every gap inside the cell's
-    limits, under the Krylov default and under the direct solve."""
+    limits, under the direct solve the table selects and under the
+    Krylov solve of the ladder's escalate entry."""
     from benchmark import seeded
     from benchmark.references import uniform_periodic as ref
-    monkeypatch.setenv("CUP2D_POIS", pois)
+    monkeypatch.delenv("CUP2D_POIS", raising=False)
     cell, config = _files()
     n, g, ph = 20, config["grid"], config["physics"]
-    sim, theirs = _program_rows(config, seed, n)
-    assert sim.poisson_mode == ("fftd" if pois else "bicgstab+mg")
+    sim, theirs = _program_rows(config, seed, n, krylov=arm == "krylov")
+    assert sim.poisson_mode == "fftd" and sim.grid.fftd_by == "table"
+    iters = [r["poisson_iters"] for r in theirs]
+    assert (min(iters) > 1) if arm == "krylov" else (set(iters) == {1})
     assert sim.bc_table == config["bc_table"]
     ours = ref.follow(seeded.start_velocity(config, seed), n,
                       h=g["extent"] / g["nx"], nu=ph["nu"], cfl=ph["cfl"])
@@ -240,7 +247,8 @@ def test_records_say_which_solver_and_tier_were_timed(capsys):
     for r in records:
         assert (r["poisson_mode"], r["smoother_tier"], r["kernel_tier"],
                 r["bc_table"], r["case"]) == (
-            "bicgstab+mg", "xla", "xla", "pd,pd,pd,pd", "turb2d")
+            "fftd", "xla", "xla", "pd,pd,pd,pd", "turb2d")
+        assert (r["poisson_iters"], r["precond_cycles"]) == (1, 0)
 
 
 # the planted faults (the cavity's five and wall paint for wrap ghosts,
@@ -288,7 +296,9 @@ def test_periodic_hierarchy_keeps_the_constant_out(n):
 
 @pytest.mark.parametrize("seed", [5, 3, 7])
 def test_startup_solves_come_back_at_the_floor(seed, monkeypatch):
-    """Tol-0 start-up solves on the all-periodic table at 128^2, their
+    """Tol-0 Krylov solves on the all-periodic table at 128^2 (through
+    the ladder's escalate entry: the table selects the direct solve
+    for the start-up steps themselves, ISSUE 35), their
     TRUE residual Linf(b - lap x) taken from the returned x: on the
     parent's ``poisson.py`` these starts came back with 1.6 (seed 5,
     step 1), 2.6 and 8.5e-2 (seed 3, steps 1 and 8), 0.19 and 2e-2
@@ -308,7 +318,9 @@ def test_startup_solves_come_back_at_the_floor(seed, monkeypatch):
     monkeypatch.setattr(UniformGrid, "pressure_solve", true_residual)
     _, config = _files()
     config["grid"].update(level=4, ny=128, nx=128)
-    _, rows = _program_rows(config, seed, 8 if seed == 3 else 4)
+    monkeypatch.delenv("CUP2D_POIS", raising=False)
+    _, rows = _program_rows(config, seed, 8 if seed == 3 else 4,
+                            krylov=True)
     assert all(r["poisson_iters"] > 20 for r in rows), rows
     assert all(r["residual"] <= 1e-5 for r in rows), rows
 

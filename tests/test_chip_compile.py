@@ -180,9 +180,10 @@ def _cavity_step_text(one_chip, **grid_kw):
     return grid, compiled.as_text()
 
 
-def _step_compiled(one_chip, bc, **grid_kw):
-    """One uniform 8192^2 f32 production step under table ``bc``,
-    compiled for the described v5e."""
+def _step_compiled(one_chip, bc, exact_poisson=False, **grid_kw):
+    """One uniform 8192^2 f32 step under table ``bc`` (the production
+    one unless ``exact_poisson`` asks otherwise), compiled for the
+    described v5e."""
     from cup2d_tpu.config import SimConfig
     from cup2d_tpu.uniform import FlowState, UniformGrid
 
@@ -201,7 +202,7 @@ def _step_compiled(one_chip, bc, **grid_kw):
                           us=field(2), udef=field(2))
 
         def step(state, dt):
-            return grid.step(state, dt, exact_poisson=False,
+            return grid.step(state, dt, exact_poisson=exact_poisson,
                              obstacle_terms=False)
 
         compiled = jax.jit(step, donate_argnums=(0,)).lower(
@@ -261,30 +262,44 @@ def test_cavity_step_keeps_its_scopes_on_v5e(one_chip):
             "diag"} <= seen, seen
 
 
-def test_periodic_step_compiles_on_v5e(one_chip, monkeypatch):
-    """The doubly-periodic cell's step (``turb2d-8192.solo``, ISSUE 34:
-    8192^2 f32, all four faces wrap), compiled for the described v5e
-    with ``_on_accel`` held true: the hierarchy still picks the XLA
-    legs (no strip form on a wrap), so no Mosaic call is in the
-    executable; the multigrid scopes are, with the coarse levels' mean
-    removal among the transfers; and arguments plus temporaries leave
-    room on a 16 GB chip for the snapshot ring beside them."""
+@pytest.mark.parametrize("exact", [False, "krylov"],
+                         ids=["production", "backstop"])
+def test_periodic_step_compiles_on_v5e(one_chip, monkeypatch, exact):
+    """The doubly-periodic cell's step (``turb2d-8192.solo``: 8192^2
+    f32, all four faces wrap), compiled for the described v5e with
+    ``_on_accel`` held true. The production step is the direct solve
+    the table selects (ISSUE 35): the ``fft_diag`` scope and no scope
+    of the hierarchy. The supervision ladder's backstop variant is the
+    Krylov solve: the hierarchy still picks the XLA legs (no strip
+    form on a wrap), the multigrid scopes are in the executable, with
+    the coarse levels' mean removal among the transfers. Neither holds
+    a Mosaic call, and arguments plus temporaries leave room on a
+    16 GB chip for the snapshot ring beside them."""
     import re
 
     from cup2d_tpu.cases import periodic_table
 
     monkeypatch.setattr(pk, "_on_accel", lambda: True)
-    grid, compiled = _step_compiled(one_chip, periodic_table())
+    monkeypatch.delenv("CUP2D_POIS", raising=False)
+    grid, compiled = _step_compiled(one_chip, periodic_table(),
+                                    exact_poisson=exact)
+    assert (grid.poisson_mode, grid.fftd_by) == ("fftd", "table")
     assert (grid.smoother_tier, grid.mg.fused_levels) == ("xla", 0)
     assert grid.mg._const_null and len(grid.mg.shapes) == 11
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     names = re.findall(r'op_name="([^"]*)"', text)
     seen = {part for n in names for part in n.split("/")}
-    assert {"advect", "substage0", "substage1", "poisson_rhs",
-            "poisson_solve", "krylov", "mg_cycle", "mg_smooth",
-            "mg_transfer", "mg_coarse", "project_correct",
-            "diag"} <= seen, seen
-    assert any("mg_transfer" in n and "reduce" in n for n in names)
+    step = {"advect", "substage0", "substage1", "poisson_rhs",
+            "poisson_solve", "project_correct", "diag"}
+    hierarchy = {"krylov", "mg_cycle", "mg_smooth", "mg_transfer",
+                 "mg_coarse"}
+    if exact == "krylov":
+        assert step | hierarchy <= seen, seen
+        assert "fft_diag" not in seen
+        assert any("mg_transfer" in n and "reduce" in n for n in names)
+    else:
+        assert step | {"fft_diag"} <= seen, seen
+        assert not hierarchy & seen, hierarchy & seen
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10e9

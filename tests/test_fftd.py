@@ -8,6 +8,14 @@ Contracts pinned here:
   is inert) and reports poisson_mode "fftd" (doubly periodic, pure
   spectral divide) or "fftd+tridiag" (one periodic axis, per-mode
   Thomas systems on the wall axis).
+- Selection (ISSUE 35): with CUP2D_POIS unset the boundary table
+  picks — both axes wrap, float32 and no sharded spatial axis ->
+  "fftd", every other table (and float64: the chip has no c128
+  transform) "bicgstab+mg"; an explicit value wins; a mesh attached
+  to a SELECTED grid falls back to "bicgstab+mg", to an explicit
+  "fftd" it is refused. The Krylov arm on a wrap table is named
+  explicitly below: ``pressure_solve(exact="krylov")`` (the
+  supervision ladder's escalate entry) or the mesh fall-back.
 - Direct-solve correctness: one application reaches the production
   Linf criterion (iters == 1, converged) on the doubly-periodic box
   AND both mixed channels; the solution agrees with converged
@@ -29,13 +37,15 @@ Contracts pinned here:
   steady-state churn with jit_compiles == 0.
 """
 
+import json
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from cup2d_tpu.bc import BCTable, no_slip, periodic
-from cup2d_tpu.cases import (make_sim, periodic_channel_table,
-                             periodic_table)
+from cup2d_tpu.cases import (cavity_table, make_sim,
+                             periodic_channel_table, periodic_table)
 from cup2d_tpu.config import SimConfig
 
 
@@ -84,8 +94,135 @@ def test_fftd_latch_and_mode_strings(monkeypatch):
     assert gy.poisson_mode == "fftd+tridiag"
 
 
+def _free_slip():
+    return None
+
+
+_SELECTION = {
+    # id: (table, CUP2D_POIS, grid kwargs, attach a mesh, poisson_mode
+    #      and who chose the direct solve | the refusal)
+    "wrap-both": (periodic_table, "", {"dtype": "float32"}, False,
+                  ("fftd", "table")),
+    # the chip has no float64 transform: f64 keeps the solver that
+    # runs on both platforms
+    "wrap-both-f64": (periodic_table, "", {}, False,
+                      ("bicgstab+mg", None)),
+    "wrap-x": (periodic_channel_table, "", {}, False,
+               ("bicgstab+mg", None)),
+    "wrap-y": (_py_channel_table, "", {}, False, ("bicgstab+mg", None)),
+    "cavity-walls": (cavity_table, "", {}, False, ("bicgstab+mg", None)),
+    "free-slip": (_free_slip, "", {}, False, ("bicgstab+mg", None)),
+    "wrap-both-env-fas": (periodic_table, "fas", {}, False, ("fas", None)),
+    "wrap-both-env-fas-f": (periodic_table, "fas-f", {}, False,
+                            ("fas-f", None)),
+    "wrap-both-env-fftd": (periodic_table, "fftd", {}, False,
+                           ("fftd", "env")),
+    "wrap-x-env-fftd": (periodic_channel_table, "fftd", {}, False,
+                        ("fftd+tridiag", "env")),
+    "wrap-both-sharded-axes": (periodic_table, "",
+                               {"dtype": "float32", "spmd_safe": True},
+                               False, ("bicgstab+mg", None)),
+    "wrap-both-then-mesh": (periodic_table, "", {"dtype": "float32"},
+                            True, ("bicgstab+mg", None)),
+    "wrap-both-env-fftd-then-mesh": (periodic_table, "fftd", {}, True,
+                                     "fftd cannot attach"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SELECTION))
+def test_solver_is_selected_from_the_table(case, monkeypatch):
+    """ISSUE 35: which solver a uniform grid runs, from what it can
+    see — its boundary table, an explicit CUP2D_POIS, a mesh."""
+    from cup2d_tpu.uniform import UniformGrid
+    table, pois, kw, mesh, want = _SELECTION[case]
+    monkeypatch.setenv("CUP2D_POIS", pois)
+    kw = dict(kw)
+    spmd = kw.pop("spmd_safe", False)
+    g = UniformGrid(_cfg(**kw), level=3, bc=table(), spmd_safe=spmd)
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            g.attach_mesh(object())
+        return
+    if mesh:
+        assert g.poisson_mode == "fftd"      # selected, then dropped
+        g.attach_mesh(object())
+    assert (g.poisson_mode, g.fftd_by) == want
+    assert (g._fft_plan is not None) == (g.solver_mode == "fftd")
+    # the request a driver hands the step: truthy for start-up and
+    # for the ladder alike; the ladder's names Krylov exactly where
+    # the direct solve is the production method
+    assert g.exact_request(True, False) is True
+    assert g.exact_request(False, False) is False
+    assert g.exact_request(False, True) == (
+        "krylov" if g.solver_mode == "fftd" else True)
+    rhs = _mean_free((g.ny, g.nx), 21).astype(g.dtype)
+    res = g.pressure_solve(rhs)
+    assert bool(res.converged)
+    # no hierarchy cycle is counted exactly where the direct solve ran
+    assert (int(g.precond_cycles(res, False)) == 0) == (
+        g.solver_mode == "fftd")
+
+
+def test_ladder_escalates_a_selected_grid_to_krylov(monkeypatch,
+                                                    tmp_path):
+    """ISSUE 35, the backstop: on a grid whose direct solve the table
+    selected, a planted non-converged verdict walks the ladder to its
+    escalate rung, and the rung runs the tol-0 Krylov solve — not the
+    method whose verdict just failed once more — in a step variant
+    that no start-up or production step had compiled."""
+    from cup2d_tpu.faults import FaultPlan
+    from cup2d_tpu.resilience import EventLog, StepGuard
+    from cup2d_tpu.tracing import FlightRecorder
+
+    monkeypatch.delenv("CUP2D_POIS", raising=False)
+    krylov = "uniform.step[exact_poisson=krylov]"
+    flight = FlightRecorder(spans=False, capture_memory=False).install()
+    try:
+        sim = make_sim("turb2d", level=3, seed=7, dtype="float32")
+        assert (sim.poisson_mode, sim.grid.fftd_by) == ("fftd", "table")
+        guard = StepGuard(
+            sim, faults=FaultPlan("poisson_giveup@12*2"),
+            event_log=EventLog(str(tmp_path / "events.jsonl")))
+        recs = []
+        while sim.step_count < 12:
+            recs.append(guard.step())
+        before = {r["label"]: r
+                  for r in flight.ledger_report()["executables"]}
+        while sim.step_count < 16:
+            recs.append(guard.step())
+        recs += guard.drain()
+    finally:
+        flight.uninstall()
+    after = {r["label"]: r for r in flight.ledger_report()["executables"]}
+    # start-up (steps 1-10) and production had compiled, both the
+    # direct solve and nothing of the hierarchy; the backstop had not
+    for label in ("uniform.step[exact_poisson=True]",
+                  "uniform.step[exact_poisson=False]"):
+        assert before[label]["components"] == [
+            "poisson.fft_diag_solve", "poisson.fftd[selected=table]"]
+    assert krylov not in before
+    assert after[krylov]["compiles"] == 1
+    assert "poisson.bicgstab" in after[krylov]["components"]
+    assert not any(c.startswith("poisson.fft")
+                   for c in after[krylov]["components"])
+    with open(tmp_path / "events.jsonl") as f:
+        actions = [e["action"] for e in map(json.loads, f)
+                   if e.get("event") == "recovery"]
+    assert actions == ["retry", "escalate"]
+    by_step = {r["step"]: r for r in recs if r}
+    assert sorted(by_step) == list(range(1, 17))
+    for step, r in by_step.items():
+        # the run's solver is what it was; only the escalated step
+        # (the failed step 12 run again) iterated
+        assert r.get("poisson_mode", sim.poisson_mode) == "fftd"
+        if step == 13:
+            assert r["poisson_iters"] > 1 and r["precond_cycles"] > 2
+        else:
+            assert (r["poisson_iters"], r["precond_cycles"]) == (1, 0)
+    assert not sim._force_exact and sim.poisson_mode == "fftd"
+
+
 def test_fftd_refuses_wall_only_box(monkeypatch):
-    from cup2d_tpu.cases import cavity_table
     with pytest.raises(ValueError, match="at least one periodic"):
         _grid(cavity_table(), monkeypatch)
     with pytest.raises(ValueError, match="at least one periodic"):
@@ -164,8 +301,11 @@ def test_fftd_periodic_box_matches_bicgstab(monkeypatch):
     solvers produce the SAME mean-free solution."""
     rhs = _mean_free((64, 64), 14)
     xf = _grid(periodic_table(), monkeypatch).pressure_solve(rhs).x
+    # the Krylov arm by name (in float32 this table selects the
+    # direct solve, and the ladder's entry is how Krylov is reached)
     gb = _grid(periodic_table(), monkeypatch, pois=None)
-    rb = gb.pressure_solve(rhs, exact=True)
+    rb = gb.pressure_solve(rhs, exact="krylov")
+    assert int(rb.iters) > 1
     xb = np.asarray(rb.x) - float(jnp.mean(rb.x))
     np.testing.assert_allclose(np.asarray(xf), xb, atol=5e-9)
 
@@ -196,12 +336,19 @@ def test_fftd_member_batched_matches_solo(monkeypatch):
                                    np.asarray(solo.x), atol=1e-12)
 
 
-def test_fftd_fleet_trajectory_matches_solo(monkeypatch):
+@pytest.mark.parametrize("pois", ["fftd", ""], ids=["env", "selected"])
+def test_fftd_fleet_trajectory_matches_solo(pois, monkeypatch):
     """A member-batched periodic fleet steps bit-close to the solo sim
-    under fftd: same IC in every slot, one fused dispatch."""
-    monkeypatch.setenv("CUP2D_POIS", "fftd")
-    fs = make_sim("tgv_periodic", level=2, members=3, dtype="float64")
-    solo = make_sim("tgv_periodic", level=2, dtype="float64")
+    under fftd, asked for or selected by the table (the fleet and the
+    solo driver build the same grid): same IC in every slot, one fused
+    dispatch."""
+    monkeypatch.setenv("CUP2D_POIS", pois)
+    # the table selects in float32 alone (the chip's transform)
+    dtype, atol = ("float64", 1e-12) if pois else ("float32", 1e-6)
+    fs = make_sim("tgv_periodic", level=2, members=3, dtype=dtype)
+    solo = make_sim("tgv_periodic", level=2, dtype=dtype)
+    assert fs.poisson_mode == solo.poisson_mode == "fftd"
+    assert fs.grid.fftd_by == ("env" if pois else "table")
     dt = 1e-3
     for _ in range(3):
         fs.step_once(dt)
@@ -209,7 +356,7 @@ def test_fftd_fleet_trajectory_matches_solo(monkeypatch):
     vs = np.asarray(solo.state.vel)
     vf = np.asarray(fs.state.vel)
     for m in range(3):
-        np.testing.assert_allclose(vf[m], vs, atol=1e-12)
+        np.testing.assert_allclose(vf[m], vs, atol=atol)
 
 
 # ---------------------------------------------------------------------------
@@ -283,10 +430,12 @@ def test_bicgstab_mg_converges_on_periodic(table, monkeypatch):
     """The ITERATIVE path must also honor wrap stencils (periodicity
     persists under coarsening) — it is the fftd A/B baseline and the
     only sharded-periodic option."""
+    # float64: the table selects the direct solve in float32 alone
     g = _grid(table, monkeypatch, pois=None)
+    assert g.poisson_mode == "bicgstab+mg"
     rhs = _mean_free((g.ny, g.nx), 16)
     res = g.pressure_solve(rhs)
-    assert bool(res.converged)
+    assert bool(res.converged) and int(g.precond_cycles(res, False)) > 1
     lin = float(jnp.max(jnp.abs(rhs - g.laplacian(res.x))))
     tgt = max(g.cfg.poisson_tol,
               g.cfg.poisson_tol_rel * float(jnp.max(jnp.abs(rhs))))
