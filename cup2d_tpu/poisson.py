@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tracing
+from .ops.dft import HartleyPlan2D
 
 
 def block_precond_matrix(bs: int, dtype=np.float64) -> np.ndarray:
@@ -990,8 +991,11 @@ class FFTDiagPlan:
     """Host-precomputed plan for the FFT-diagonalized direct Poisson
     solve of the undivided per-face Laplacian (bc.py periodic kind).
 
-    * ``px and py`` (fully-periodic box): 2D real FFT, pointwise
-      divide by lam_y(m) + lam_x(k), inverse FFT. The (0, 0) nullspace
+    * ``px and py`` (fully-periodic box): 2D transform, pointwise
+      divide by lam_y(m) + lam_x(k), inverse transform. The transform
+      is the real Hartley pair as matmul stages on the MXU
+      (``ops.dft.HartleyPlan2D``) wherever both lengths split into
+      factors <= 256, XLA's real FFT elsewhere. The (0, 0) nullspace
       mode is pinned to zero, so the returned solution is exactly
       mean-free (the projection's mean removal is then a no-op).
     * one periodic direction: real FFT along it, then one TRIDIAGONAL
@@ -1035,6 +1039,20 @@ class FFTDiagPlan:
         self.dtype = jnp.dtype(dtype)
         sx_lo, sx_hi, sy_lo, sy_hi = edge_signs
         if px and py:
+            self.pin = True     # the zeroed (0,0) mode IS the pin
+            self.dft = HartleyPlan2D.build(ny, nx, self.dtype)
+            if self.dft is not None:
+                # the MXU transform's spectrum is [..., nx', ny'] in its
+                # slot order: one eigenvalue vector per axis in that
+                # order (f64 on the host: 2 cos x - 2 cancels in f32
+                # near k = 0), the divide formed from them in solve
+                def lam(freq, n):
+                    return jnp.asarray(
+                        2.0 * np.cos(2.0 * np.pi * freq / n) - 2.0,
+                        self.dtype)
+                self.lx = lam(self.dft.x.freq, nx)
+                self.ly = lam(self.dft.y.freq, ny)
+                return
             lx = 2.0 * np.cos(
                 2.0 * np.pi * np.arange(nx // 2 + 1) / nx) - 2.0
             ly = 2.0 * np.cos(2.0 * np.pi * np.arange(ny) / ny) - 2.0
@@ -1042,7 +1060,6 @@ class FFTDiagPlan:
             mask = lam < -1e-12
             ilam = np.where(mask, 1.0 / np.where(mask, lam, 1.0), 0.0)
             self.ilam = jnp.asarray(ilam, self.dtype)
-            self.pin = True     # the zeroed (0,0) mode IS the pin
             return
         # single periodic direction: transform length n_t, tridiagonal
         # system length n_s with the wall axis's signs
@@ -1081,9 +1098,19 @@ class FFTDiagPlan:
         Leading axes (the fleet's member batch) ride the same
         transforms — the mode axis is embarrassingly parallel."""
         if self.px and self.py:
-            F = jnp.fft.rfft2(b)
-            x = jnp.fft.irfft2(F * self.ilam, s=(self.ny, self.nx))
-            return x.astype(b.dtype)
+            if self.dft is None:
+                tracing.note_component("poisson.fftd_dft[xla]")
+                F = jnp.fft.rfft2(b)
+                x = jnp.fft.irfft2(F * self.ilam, s=(self.ny, self.nx))
+                return x.astype(b.dtype)
+            tracing.note_component(f"poisson.fftd_dft[{self.dft.note()}]")
+            s = self.dft.forward(b)                  # [..., nx', ny']
+            lam = self.lx[:, None] + self.ly[None, :]
+            mask = lam < -1e-12
+            # the Hartley pair is unnormalized: 1 / (ny nx) rides here
+            ilam = jnp.where(mask, 1.0 / (self.ny * self.nx)
+                             / jnp.where(mask, lam, 1.0), 0.0)
+            return self.dft.inverse(s * ilam).astype(b.dtype)
         swap = not self.px        # py-only: transposed px-only problem
         if swap:
             b = jnp.swapaxes(b, -1, -2)

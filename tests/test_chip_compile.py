@@ -268,7 +268,8 @@ def test_periodic_step_compiles_on_v5e(one_chip, monkeypatch, exact):
     """The doubly-periodic cell's step (``turb2d-8192.solo``: 8192^2
     f32, all four faces wrap), compiled for the described v5e with
     ``_on_accel`` held true. The production step is the direct solve
-    the table selects (ISSUE 35): the ``fft_diag`` scope and no scope
+    the table selects: the ``fft_diag`` scope, its transform pair as
+    eight matmul stages (each 8192 axis split 128 x 64), and no scope
     of the hierarchy. The supervision ladder's backstop variant is the
     Krylov solve: the hierarchy still picks the XLA legs (no strip
     form on a wrap), the multigrid scopes are in the executable, with
@@ -301,5 +302,10 @@ def test_periodic_step_compiles_on_v5e(one_chip, monkeypatch, exact):
     else:
         assert step | {"fft_diag"} <= seen, seen
         assert not hierarchy & seen, hierarchy & seen
+        # the transform pair as matmuls: two stages an axis, forward
+        # and inverse, and no other convolution in the step
+        convs = re.findall(r' convolution\(.*op_name="([^"]*)"', text)
+        assert len(convs) == 8, convs
+        assert all("fft_diag/dot_general" in n for n in convs), convs
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 10e9

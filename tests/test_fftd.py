@@ -26,6 +26,11 @@ Contracts pinned here:
   transform — batched == solo per member, iters == 1 for every
   member (the freeze contract is trivially inert: no member can
   observe another's iteration count).
+- The doubly-periodic transform as matmuls (ops/dft.py): forward and
+  inverse against numpy's real FFT in f64 and f32, the solve against
+  the XLA transform form it replaced, that form kept for a length
+  with no factorization, the compile ledger's note naming which was
+  built, and a member batch BIT-equal to solo solves.
 - Loud refusal everywhere the diagonalization cannot go: wall-only
   tables (nothing to diagonalize), the device-mesh x-split (it shards
   the transform or scan axis), AMRSim (uniform-family token), the
@@ -199,7 +204,8 @@ def test_ladder_escalates_a_selected_grid_to_krylov(monkeypatch,
     for label in ("uniform.step[exact_poisson=True]",
                   "uniform.step[exact_poisson=False]"):
         assert before[label]["components"] == [
-            "poisson.fft_diag_solve", "poisson.fftd[selected=table]"]
+            "poisson.fft_diag_solve", "poisson.fftd[selected=table]",
+            "poisson.fftd_dft[mxu,y=64,x=64]"]
     assert krylov not in before
     assert after[krylov]["compiles"] == 1
     assert "poisson.bicgstab" in after[krylov]["components"]
@@ -357,6 +363,139 @@ def test_fftd_fleet_trajectory_matches_solo(pois, monkeypatch):
     vf = np.asarray(fs.state.vel)
     for m in range(3):
         np.testing.assert_allclose(vf[m], vs, atol=atol)
+
+
+# ---------------------------------------------------------------------------
+# the doubly-periodic transform pair as matmuls (ops/dft.py)
+# ---------------------------------------------------------------------------
+
+# one dense stage (<= 256, 96 not a power of two) and two stages (384, 512)
+DFT_SHAPES = [(16, 16), (64, 32), (256, 256), (384, 512), (96, 96)]
+DFT_RTOL = {np.float64: 1e-10, np.float32: 1e-5}
+
+
+def _hartley_of(x):
+    """numpy's separable Hartley spectrum of a real field, from rfft2:
+    the Hermitian half extended to the full DFT F, then
+    H[ky, kx] = Re F[ky, -kx] - Im F[ky, kx]
+    (cas a cas b = cos(a - b) + sin(a + b))."""
+    ny, nx = x.shape
+    half = np.fft.rfft2(x)
+    F = np.empty((ny, nx), complex)
+    F[:, :nx // 2 + 1] = half
+    kx = np.arange(nx // 2 + 1, nx)
+    F[:, kx] = np.conj(half[(-np.arange(ny)) % ny][:, nx - kx])
+    return F[:, (-np.arange(nx)) % nx].real - F.imag
+
+
+def _rel(a, b):
+    return float(np.max(np.abs(np.asarray(a) - b)) / np.max(np.abs(b)))
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32],
+                         ids=["f64", "f32"])
+@pytest.mark.parametrize("shape", DFT_SHAPES,
+                         ids=[f"{a}x{b}" for a, b in DFT_SHAPES])
+def test_fftd_dft_matches_numpy(shape, dtype):
+    """The matmul transform against numpy's real FFT: the forward
+    spectrum, read through its slot order, is the Hartley spectrum
+    rfft2 implies; the inverse of that spectrum is irfft2's field."""
+    from cup2d_tpu.ops.dft import HartleyPlan2D
+    ny, nx = shape
+    plan = HartleyPlan2D.build(ny, nx, dtype)
+    x = np.random.default_rng(ny + nx).standard_normal(shape)
+    ref = _hartley_of(x)[np.ix_(plan.y.freq, plan.x.freq)].T   # [nx', ny']
+    fwd = plan.forward(jnp.asarray(x, dtype))
+    assert fwd.dtype == dtype
+    assert _rel(fwd, ref) < DFT_RTOL[dtype]
+    back = plan.inverse(jnp.asarray(ref, dtype)) / (ny * nx)
+    assert _rel(back, np.fft.irfft2(np.fft.rfft2(x), s=shape)) \
+        < DFT_RTOL[dtype]
+
+
+@pytest.mark.parametrize("shape", [(64, 64), (384, 512), (96, 160)],
+                         ids=["64x64", "384x512", "96x160"])
+def test_fftd_dft_solve_matches_xla_transform(shape):
+    """In f32, FFTDiagPlan.solve on the MXU form agrees with the real
+    FFT form it replaced (rfft2, the f64 reciprocal eigenvalues with
+    the (0, 0) mode zeroed, irfft2) on a seeded right-hand side."""
+    from cup2d_tpu.poisson import FFTDiagPlan
+    ny, nx = shape
+    plan = FFTDiagPlan(ny, nx, jnp.float32, True, True, (1.0,) * 4)
+    assert plan.dft is not None
+    b = np.asarray(_mean_free(shape, 21), np.float32)
+    lx = 2.0 * np.cos(2.0 * np.pi * np.arange(nx // 2 + 1) / nx) - 2.0
+    ly = 2.0 * np.cos(2.0 * np.pi * np.arange(ny) / ny) - 2.0
+    lam = ly[:, None] + lx[None, :]
+    ilam = np.where(lam < -1e-12, 1.0 / np.where(lam < -1e-12, lam, 1.0),
+                    0.0).astype(np.float32)
+    ref = jnp.fft.irfft2(jnp.fft.rfft2(jnp.asarray(b)) * ilam, s=shape)
+    x = plan.solve(jnp.asarray(b))
+    assert x.dtype == jnp.float32
+    assert _rel(x, np.asarray(ref)) < 1e-5
+
+
+def test_fftd_dft_keeps_xla_where_no_factorization():
+    """A length with no split into parts <= 256 (2 * 257) keeps the
+    XLA transform, and that solve is still the direct one."""
+    from cup2d_tpu.ops.dft import split
+    from cup2d_tpu.poisson import FFTDiagPlan
+    assert split(2 * 257) is None and split(8192) == (128, 64)
+    plan = FFTDiagPlan(2 * 257, 64, jnp.float64, True, True, (1.0,) * 4)
+    assert plan.dft is None and plan.ilam.shape == (2 * 257, 33)
+    b = _mean_free((2 * 257, 64), 22)
+    x = plan.solve(b)
+    lap = (jnp.roll(x, 1, 0) + jnp.roll(x, -1, 0) + jnp.roll(x, 1, 1)
+           + jnp.roll(x, -1, 1) - 4.0 * x)
+    assert float(jnp.max(jnp.abs(lap - b))) < 1e-10
+
+
+@pytest.mark.parametrize("shape,note", [
+    ((64, 64), "mxu,y=64,x=64"),
+    ((384, 512), "mxu,y=24x16,x=32x16"),
+    ((2 * 257, 64), "xla"),
+], ids=["one-stage", "two-stage", "xla"])
+def test_fftd_dft_component_note_names_the_transform(shape, note):
+    """The compile ledger's component note says which transform the
+    plan built, so every run records whether the MXU form engaged."""
+    import jax
+
+    from cup2d_tpu import tracing
+    from cup2d_tpu.poisson import FFTDiagPlan
+    plan = FFTDiagPlan(*shape, jnp.float32, True, True, (1.0,) * 4)
+    flight = tracing.FlightRecorder(spans=False,
+                                    capture_memory=False).install()
+    try:
+        tracing.named_jit("probe.fftd", jax.jit(plan.solve))(
+            jnp.zeros(shape, jnp.float32)).block_until_ready()
+    finally:
+        flight.uninstall()
+    rows = {r["label"]: r for r in flight.ledger_report()["executables"]}
+    assert rows["probe.fftd"]["components"] == [f"poisson.fftd_dft[{note}]"]
+
+
+@pytest.mark.parametrize("shape", [(64, 64), (384, 512)],
+                         ids=["one-stage", "two-stage"])
+def test_fftd_dft_member_batched_is_bit_equal_to_solo(shape):
+    """The fleet's member axis is a batch dimension of every matmul:
+    a [3, ny, nx] doubly-periodic solve is BIT-equal to three solo
+    solves, through the whole direct-solve contract."""
+    from cup2d_tpu.poisson import FFTDiagPlan, fft_diag_solve
+    plan = FFTDiagPlan(*shape, jnp.float32, True, True, (1.0,) * 4)
+    rhs = _mean_free((3,) + shape, 23).astype(jnp.float32)
+
+    def lap(x):
+        return (jnp.roll(x, 1, -2) + jnp.roll(x, -1, -2)
+                + jnp.roll(x, 1, -1) + jnp.roll(x, -1, -1) - 4.0 * x)
+
+    batched = fft_diag_solve(lap, rhs, plan, tol=1e-4, tol_rel=1e-3,
+                             member_axis=True)
+    assert bool(jnp.all(batched.converged))
+    for m in range(3):
+        solo = fft_diag_solve(lap, rhs[m], plan, tol=1e-4, tol_rel=1e-3)
+        np.testing.assert_array_equal(np.asarray(batched.x[m]),
+                                      np.asarray(solo.x))
+        assert float(batched.residual[m]) == float(solo.residual)
 
 
 # ---------------------------------------------------------------------------
