@@ -121,6 +121,16 @@ THE FLIGHT RECORDER (tracing.py, PR 18) rides the same zero-extra-sync
 discipline: span timeline (``<output>/spans.jsonl``, export with
 ``python -m cup2d_tpu.post --trace``), compile/HBM ledger and serving
 latency histograms (both summarized into ``metrics.jsonl`` at exit).
+The spans' names are one vocabulary, ``tracing.SPANS``: the loop's
+``step`` > ``dispatch`` / ``snapshot`` / ``verdict`` and ``record``;
+on the forest ``regrid`` and ``tables``, the rebuild of the lookup
+tables, with its parts ``tables.topo``, ``tables.halo`` (a halo set
+each, attribute ``set``), ``tables.faces``, ``tables.pad``,
+``tables.flux``, ``tables.coarse`` and ``tables.geom``; and inside a
+shaped step's ``dispatch`` its host parts in order, ``dt`` (``pulled``
+where it round-trips a scalar), ``kinematics``, ``shape_inputs``,
+``enqueue``, ``pull`` (the step's one blocking ``jax.device_get``: the
+device's wait is in it) and ``bodies``.
 Knobs: ``-noSpans``, ``-spansLog PATH``, ``-noMemLedger``,
 ``-logRotateMB N`` (size-capped rotation of metrics/clients/spans
 JSONL streams — default off), ``CUP2D_SPANS=0|N`` (disable spans /

@@ -392,7 +392,10 @@ class AMRSim(ShapeHostMixin):
         with tracing.span("tables", step=int(self.step_count)):
             self._refresh_impl()
 
-    def _refresh_impl(self):
+    def _block_axis(self):
+        """The ordered block axis of the forest as it stands: sets
+        ``_order``, ``_n_real`` and ``_mask``; returns the padded row
+        count and the step's and the flush's padded block indices."""
         f = self.forest
         self._order = f.order()
         n_real = len(self._order)
@@ -452,33 +455,43 @@ class AMRSim(ShapeHostMixin):
         # flush is one shape per (n_pad, capacity) like everything else
         # on the block axis and leaves every inactive slot as it was
         sync_p = np.where(self._mask, order_p, np.iinfo(np.int32).max)
+        return n_pad, order_p, sync_p
 
-        # one dense topology index shared by all 6-8 table builds
-        topo = _TopoIndex(f, self._order)
-        raw = {
-            "vec3": build_tables(f, self._order, 3, True, 2, topo=topo),
-            "vec1": build_tables(f, self._order, 1, False, 2, topo=topo),
-            "sca1": build_tables(f, self._order, 1, False, 1, topo=topo),
-            "vec1t": build_tables(f, self._order, 1, True, 2, topo=topo),
-            "sca1t": build_tables(f, self._order, 1, True, 1, topo=topo),
-        }
+    def _refresh_impl(self):
+        # the rebuild's parts as spans nested in ``tables`` (tracing.SPANS;
+        # the benchmark's tables_halo_ms and tables_flux_ms read them)
+        f = self.forest
+        with tracing.span("tables.topo"):
+            n_pad, order_p, sync_p = self._block_axis()
+            n_real = self._n_real
+            # one dense topology index shared by all 6-8 table builds
+            topo = _TopoIndex(f, self._order)
+        # (set, g, tensorial, dim): chi tagging (g=4 scalar) and the
+        # forces (g=4 vector) only where there are shapes
+        sets = [("vec3", 3, True, 2), ("vec1", 1, False, 2),
+                ("sca1", 1, False, 1), ("vec1t", 1, True, 2),
+                ("sca1t", 1, True, 1)]
         if self.shapes:
-            # chi tagging (g=4 scalar) + forces (g=4 vector)
-            raw["sca4t"] = build_tables(f, self._order, 4, True, 1,
-                                        topo=topo)
-            raw["vec4t"] = build_tables(f, self._order, 4, True, 2,
-                                        topo=topo)
+            sets += [("sca4t", 4, True, 1), ("vec4t", 4, True, 2)]
+        raw = {}
+        for name, g, tensorial, dim in sets:
+            with tracing.span("tables.halo", set=name):
+                raw[name] = build_tables(f, self._order, g, tensorial,
+                                         dim, topo=topo)
+        with tracing.span("tables.faces"):
+            fc = build_face_copy(f, self._order, n_pad, topo)
         # one async transfer for every table leaf (pad_tables returns
         # numpy on purpose; per-leaf jnp.asarray would synchronize per
         # array, one host sync per leaf on every regrid)
-        fc = build_face_copy(f, self._order, n_pad, topo)
-        self._tables = self._finalize_tables(raw, n_pad, fc)
+        with tracing.span("tables.pad"):
+            self._tables = self._finalize_tables(raw, n_pad, fc)
         # makeFlux variable-resolution Poisson operator (flux.py):
         # structured per-face form on a single device; the sharded
         # subclass overrides with the lab-table + ppermute-exchange
         # form (_build_pois)
-        self._tables["pois"] = self._build_pois(topo, n_pad)
-        self._corr = self._finalize_corr(topo, n_pad)
+        with tracing.span("tables.flux"):
+            self._tables["pois"] = self._build_pois(topo, n_pad)
+            self._corr = self._finalize_corr(topo, n_pad)
         # two-level preconditioner maps: every cell's coarse cell on
         # the uniform level-c grid + its area weight (cells coarser
         # than c deposit into the coarse cell under their center —
@@ -498,10 +511,17 @@ class AMRSim(ShapeHostMixin):
         if self.step_count >= 10:
             self._coarse_cw = None
         else:
-            self._build_coarse_maps(n_pad, n_real)
+            with tracing.span("tables.coarse"):
+                self._build_coarse_maps(n_pad, n_real)
+        with tracing.span("tables.geom"):
+            self._put_geometry(n_pad, n_real, order_p, sync_p)
+        self._tables_version = f.version
 
+    def _put_geometry(self, n_pad: int, n_real: int, order_p, sync_p):
+        """The per-block geometry the step programs take, on the device:
+        spacings, the mask, the ordered and flush indices, cell centres."""
+        f = self.forest
         h = f.h_per_block(self._order)
-
         hp = np.concatenate([h, np.ones(n_pad - n_real)])
         hsqp = np.concatenate([h * h, np.zeros(n_pad - n_real)])
         # shape on the HOST (numpy reshapes), transfer once: the eager
@@ -529,7 +549,6 @@ class AMRSim(ShapeHostMixin):
         yc[:n_real] = y0[:, None, None] + ar[None, :, None] * h[:, None, None]
         self._xc = jnp.asarray(xc, f.dtype)
         self._yc = jnp.asarray(yc, f.dtype)
-        self._tables_version = f.version
 
     def _build_coarse_maps(self, n_pad: int, n_real: int):
         """Host build of the two-level transfer structure (see
@@ -2210,47 +2229,60 @@ class AMRSim(ShapeHostMixin):
         if not getattr(self, "_initialized", False):
             self.initialize()
             self._refresh()
-        # run the external-write invalidation BEFORE the dt branch: an
-        # external forest.fields write between steps (wver moved) must
-        # drop the cached _next_dt/_next_umax here exactly as on the
-        # obstacle-free path, or one step runs at the stale dt — a
-        # silent CFL violation (ADVICE r3 medium)
-        self._ordered_state()
-        if self._last_iters_dev is not None:
-            # a pending obstacle-free iters scalar must be drained on
-            # entry to the shaped path (shapes appended mid-run): left
-            # pending, a later _float_pull would overwrite the fresher
-            # megastep-set _last_iters with this stale count and
-            # perturb the two-level trigger (ADVICE r4)
-            self._float_pull(jnp.zeros((), f.dtype))
-        if dt is None:
-            # prefer the dt the PREVIOUS megastep computed on device —
-            # a fresh compute_dt() is a full host<->device round trip
-            if self._next_dt is not None and \
-                    self._next_dt_version == f.version:
-                dt = min(self._next_dt, self._kinematic_dt_cap())
-            elif self._next_umax is not None:
-                # a regrid invalidated the layout, not the physics: the
-                # velocity field is the same water, re-gridded (2nd-order
-                # prolongation can overshoot umax by a few %, well inside
-                # the CFL-0.5 slack). Only hmin can change; recompute dt
-                # from the cached end-state umax through the SAME shared
-                # arithmetic — one scalar round trip instead of a full
-                # field reduction + compile after every adapt.
-                # The 1.05 factor turns the prolongation-overshoot
-                # argument from an asserted comment into an enforced
-                # bound (ADVICE r2): any overshoot up to 5% now tightens
-                # dt instead of silently stretching CFL.
-                dt = min(float(self._dt_from_umax(
-                    jnp.asarray(1.05 * self._next_umax, f.dtype),
-                    self._hmin())),
-                    self._kinematic_dt_cap())
-            else:
-                dt = min(self.compute_dt(), self._kinematic_dt_cap())
+        step = int(self.step_count)
+        # the step's host parts as spans nested in ``dispatch``
+        # (tracing.SPANS): dt, kinematics, shape_inputs, enqueue, pull,
+        # bodies
+        with tracing.span("dt", step=step) as sp:
+            # run the external-write invalidation BEFORE the dt branch:
+            # an external forest.fields write between steps (wver moved)
+            # must drop the cached _next_dt/_next_umax here exactly as
+            # on the obstacle-free path, or one step runs at the stale
+            # dt — a silent CFL violation (ADVICE r3 medium)
+            self._ordered_state()
+            # a scalar round trip: a pending drain, or no dt cached from
+            # the previous megastep on this forest
+            pulled = self._last_iters_dev is not None or (
+                dt is None and (self._next_dt is None
+                                or self._next_dt_version != f.version))
+            if self._last_iters_dev is not None:
+                # a pending obstacle-free iters scalar must be drained
+                # on entry to the shaped path (shapes appended mid-run):
+                # left pending, a later _float_pull would overwrite the
+                # fresher megastep-set _last_iters with this stale count
+                # and perturb the two-level trigger (ADVICE r4)
+                self._float_pull(jnp.zeros((), f.dtype))
+            if dt is None:
+                # prefer the dt the PREVIOUS megastep computed on device
+                # — a fresh compute_dt() is a full host<->device round
+                # trip
+                if self._next_dt is not None and \
+                        self._next_dt_version == f.version:
+                    dt = min(self._next_dt, self._kinematic_dt_cap())
+                elif self._next_umax is not None:
+                    # a regrid invalidated the layout, not the physics:
+                    # the velocity field is the same water, re-gridded
+                    # (2nd-order prolongation can overshoot umax by a
+                    # few %, well inside the CFL-0.5 slack). Only hmin
+                    # can change; recompute dt from the cached end-state
+                    # umax through the SAME shared arithmetic — one
+                    # scalar round trip instead of a full field
+                    # reduction + compile after every adapt.
+                    # The 1.05 factor turns the prolongation-overshoot
+                    # argument from an asserted comment into an enforced
+                    # bound (ADVICE r2): any overshoot up to 5% now
+                    # tightens dt instead of silently stretching CFL.
+                    dt = min(float(self._dt_from_umax(
+                        jnp.asarray(1.05 * self._next_umax, f.dtype),
+                        self._hmin())),
+                        self._kinematic_dt_cap())
+                else:
+                    dt = min(self.compute_dt(), self._kinematic_dt_cap())
+            if sp is not None:
+                sp.attrs["pulled"] = pulled
 
         # ongrid host part (main.cpp:3992-4207)
         cfg = self.cfg
-        step = int(self.step_count)
         with tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
@@ -2258,47 +2290,54 @@ class AMRSim(ShapeHostMixin):
         with tracing.span("shape_inputs", step=step):
             inputs = self._shape_inputs()
 
-        prescribed = jnp.asarray(
-            [[s.u, s.v, s.omega] for s in self.shapes], dtype=f.dtype)
         exact = self.step_count < 10 or self._force_exact
         with_forces = bool(
             self.compute_forces_every
             and self.step_count % self.compute_forces_every == 0)
-        hmin = self._hmin()
-        ordf = self._ordered_state()
-        vel, pres, chi_new, scalars, forces = self._mega_jit(
-            ordf["vel"], ordf["pres"],
-            inputs, prescribed, jnp.asarray(dt, f.dtype), hmin,
-            self._h, self._hsq_flat, self._maskv,
-            self._xc, self._yc,
-            self._tables["vec3"], self._tables["vec1"],
-            self._tables["sca1"], self._tables["pois"],
-            self._tables.get("vec4t"), self._tables.get("sca4t"),
-            self._corr, self._use_coarse(exact),
-            exact_poisson=exact,
-            with_forces=with_forces)
-        self._set_ordered(vel=vel, pres=pres, chi=chi_new)
+        with tracing.span("enqueue", step=step):
+            prescribed = jnp.asarray(
+                [[s.u, s.v, s.omega] for s in self.shapes], dtype=f.dtype)
+            hmin = self._hmin()
+            ordf = self._ordered_state()
+            vel, pres, chi_new, scalars, forces = self._mega_jit(
+                ordf["vel"], ordf["pres"],
+                inputs, prescribed, jnp.asarray(dt, f.dtype), hmin,
+                self._h, self._hsq_flat, self._maskv,
+                self._xc, self._yc,
+                self._tables["vec3"], self._tables["vec1"],
+                self._tables["sca1"], self._tables["pois"],
+                self._tables.get("vec4t"), self._tables.get("sca4t"),
+                self._corr, self._use_coarse(exact),
+                exact_poisson=exact,
+                with_forces=with_forces)
+            self._set_ordered(vel=vel, pres=pres, chi=chi_new)
         # the ONE host pull of the step
-        uvw, com, mass, inertia, dt_next, diag, forces = \
-            jax.device_get((*scalars, forces))
-        diag["dt"] = float(dt)    # exact replay record (see above)
-        self._sync_shape_scalars_np(com, mass, inertia)
-        uvw_np = np.asarray(uvw, dtype=np.float64)
-        for k, s in enumerate(self.shapes):
-            if s.free:
-                s.u, s.v, s.omega = uvw_np[k]
-        diag["bodies"] = self._bodies_record()
-        self._next_dt = float(dt_next)
-        self._next_dt_version = f.version
-        self._next_umax = float(diag["umax"])
-        self._next_umax_version = f.version
-        if not exact:
-            # the megastep's single pull already carried the iteration
-            # count — feed the production two-level trigger directly
-            # (exact-startup counts excluded, see the step_jit path)
-            self._last_iters = int(diag["poisson_iters"])
-        if with_forces:
-            self._record_forces(forces)
+        with tracing.span("pull", step=step):
+            uvw, com, mass, inertia, dt_next, diag, forces = \
+                jax.device_get((*scalars, forces))
+        with tracing.span("bodies", step=step):
+            diag["dt"] = float(dt)    # exact replay record (see above)
+            self._sync_shape_scalars_np(com, mass, inertia)
+            uvw_np = np.asarray(uvw, dtype=np.float64)
+            for k, s in enumerate(self.shapes):
+                if s.free:
+                    s.u, s.v, s.omega = uvw_np[k]
+            diag["bodies"] = self._bodies_record()
+            self._next_dt = float(dt_next)
+            self._next_dt_version = f.version
+            self._next_umax = float(diag["umax"])
+            self._next_umax_version = f.version
+            if not exact:
+                # the megastep's single pull already carried the
+                # iteration count — feed the production two-level
+                # trigger directly (exact-startup counts excluded, see
+                # the step_jit path)
+                self._last_iters = int(diag["poisson_iters"])
+            if with_forces:
+                self._record_forces(forces)
+            # the step's device temporaries are released here, inside
+            # the span, and not on return, where no span holds the time
+            del scalars, inputs, prescribed, hmin
 
         self.time += dt
         self.step_count += 1

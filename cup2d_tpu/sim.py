@@ -451,15 +451,23 @@ class Simulation(ShapeHostMixin):
             return diag
         if not getattr(self, "_initialized", False):
             self.initialize()
-        if dt is None:
-            if self._next_dt is not None:
-                dt = min(self._next_dt, self._kinematic_dt_cap())
-            else:
-                dt = min(float(self._dt(self.state.vel)),
-                         self._kinematic_dt_cap())
+        # the step's host parts under the forest's span names
+        # (tracing.SPANS): dt, kinematics, shape_inputs, enqueue, pull,
+        # bodies; the rasterisation and its pull of the bodies' mass
+        # and centre come between shape_inputs and enqueue, unnamed
+        step = int(self.step_count)
+        with tracing.span("dt", step=step) as sp:
+            pulled = dt is None and self._next_dt is None
+            if dt is None:
+                if self._next_dt is not None:
+                    dt = min(self._next_dt, self._kinematic_dt_cap())
+                else:
+                    dt = min(float(self._dt(self.state.vel)),
+                             self._kinematic_dt_cap())
+            if sp is not None:
+                sp.attrs["pulled"] = pulled
 
         # ongrid host part (main.cpp:3992-4207)
-        step = int(self.step_count)
         with tracing.span("kinematics", step=step):
             for s in self.shapes:
                 s.advect(dt, cfg.extents)
@@ -470,28 +478,29 @@ class Simulation(ShapeHostMixin):
         obs = self._rasterize(inputs)
         self._sync_shape_scalars(obs)
 
-        prescribed = jnp.asarray(
-            [[s.u, s.v, s.omega] for s in self.shapes], dtype=g.dtype
-        ) if self.shapes else jnp.zeros((0, 3), g.dtype)
         exact = g.exact_request(self.step_count < 10, self._force_exact)
-        self.state, uvw, diag = self._flow_step(
-            self.state, obs, prescribed,
-            jnp.asarray(dt, g.dtype), exact_poisson=exact)
+        with tracing.span("enqueue", step=step):
+            prescribed = jnp.asarray(
+                [[s.u, s.v, s.omega] for s in self.shapes], dtype=g.dtype)
+            self.state, uvw, diag = self._flow_step(
+                self.state, obs, prescribed,
+                jnp.asarray(dt, g.dtype), exact_poisson=exact)
         # the whole diag dict rides the ONE existing batched pull
         # (previously dt_next alone): the health verdict and the
         # driver's umax read then cost no further transfers
-        uvw_np, diag = jax.device_get((uvw, diag))
-        uvw_np = np.asarray(uvw_np, dtype=np.float64)
-        diag["dt"] = float(dt)    # exact replay record (see above)
-        self._next_dt = float(diag["dt_next"])
-        for k, s in enumerate(self.shapes):
-            if s.free:
-                s.u, s.v, s.omega = uvw_np[k]
-        diag["bodies"] = self._bodies_record()
-
-        if self.shapes and self.compute_forces_every and \
-                self.step_count % self.compute_forces_every == 0:
-            self._log_forces(obs, uvw)
+        with tracing.span("pull", step=step):
+            uvw_np, diag = jax.device_get((uvw, diag))
+        with tracing.span("bodies", step=step):
+            uvw_np = np.asarray(uvw_np, dtype=np.float64)
+            diag["dt"] = float(dt)    # exact replay record (see above)
+            self._next_dt = float(diag["dt_next"])
+            for k, s in enumerate(self.shapes):
+                if s.free:
+                    s.u, s.v, s.omega = uvw_np[k]
+            diag["bodies"] = self._bodies_record()
+            if self.compute_forces_every and \
+                    self.step_count % self.compute_forces_every == 0:
+                self._log_forces(obs, uvw)
 
         self.time += dt
         self.step_count += 1

@@ -8,9 +8,18 @@ FleetServer churn):
 
 1. **Span timeline** — hierarchical wall-clock spans (``span("step")``
    nesting ``dispatch``/``verdict``/``snapshot``/``mirror``/
-   ``recover``/``remesh``/``admit``/``evict``/``regrid``) recorded
-   lock-free per process into a bounded ring, flushed through the
-   EventLog writer (cold path: shutdown or ring-full), exported to a
+   ``recover``/``remesh``/``admit``/``evict``/``regrid``; :data:`SPANS`
+   is the whole vocabulary). The forest's host side has names down to
+   its parts: ``tables`` (the lookup-table rebuild after a regrid)
+   nests ``tables.topo``, ``tables.halo`` (one a halo set, attribute
+   ``set``), ``tables.faces``, ``tables.pad``, ``tables.flux``,
+   ``tables.coarse`` and ``tables.geom``; a shaped step's ``dispatch``
+   nests ``dt`` (attribute ``pulled`` where it round-trips a scalar),
+   ``kinematics``, ``shape_inputs``, ``enqueue``, ``pull`` (the step's
+   one ``jax.device_get``: a fence, so the device's wait is in it) and
+   ``bodies`` (what the host does with the pulled values). Spans are
+   recorded lock-free per process into a bounded ring, flushed through
+   the EventLog writer (cold path: shutdown or ring-full), exported to a
    Chrome/Perfetto ``trace.json`` by ``python -m cup2d_tpu.post
    --trace``. Inside a ``CUP2D_TRACE`` window (and only there:
    ``profiling.TraceWindow`` raises :func:`set_profiling`) every span
@@ -87,6 +96,18 @@ _LABEL_STACK: list = []     # innermost active named_jit label
 _SUPPRESS = [0]             # >0: backend compiles are ledger-internal
 _NULL = nullcontext()       # shared, reentrant — the recorder-off span
 _PROFILING = False          # a CUP2D_TRACE window is open (TraceWindow)
+
+# the host's parts, as the span timeline and a device trace's
+# ``cup2d:<name>`` annotations name them: the run loop and its guard,
+# the supervision ladder's rungs (``span(action)``), the fleet server's
+# slot changes, the forest's regrid and table rebuild with the parts of
+# the rebuild, and the parts of a shaped step
+SPANS = ("step", "dispatch", "verdict", "snapshot", "mirror", "record",
+         "recover", "retry", "escalate", "disk_restore", "abort",
+         "remesh", "admit", "retire", "evict",
+         "regrid", "tables", "tables.topo", "tables.halo", "tables.faces",
+         "tables.pad", "tables.flux", "tables.coarse", "tables.geom",
+         "dt", "kinematics", "shape_inputs", "enqueue", "pull", "bodies")
 
 # the step program's parts, as they read in a device trace: top-level
 # scopes in step order (the Heun substages nest in advect), then the
